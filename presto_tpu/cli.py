@@ -150,6 +150,8 @@ def main(argv=None) -> int:
                          "server only, like --history-out")
     args = ap.parse_args(argv)
 
+    from . import enable_compile_cache
+    enable_compile_cache()
     if args.trace_out or args.profile_out:
         from .obs.trace import TRACER
         TRACER.enable(True)

@@ -110,10 +110,12 @@ _sp("join_dense_path", "boolean", True,
     "stats-driven dense-key direct-address join builds: the planner "
     "attaches hard build-key bounds (JoinNode.key_bounds) and the "
     "executor answers bounded key tuples in two gathers")
-_sp("join_pallas_probe", "boolean", True,
+_sp("join_pallas_probe", "boolean", False,
     "fuse direct-join probe lookup + liveness + payload gathers into "
-    "the Pallas ragged-gather kernel on TPU backends (pure-XLA gather "
-    "fallback otherwise, and on any kernel compile failure)")
+    "the Pallas ragged-gather kernel on TPU backends. Off by default: "
+    "the kernel does not lower for a TPU v5e on the installed JAX "
+    "(ops/pallas_join docstring); switched on, a kernel compile "
+    "failure fails the query")
 
 
 def _valid_mesh_execution(v):

@@ -340,7 +340,15 @@ class LocalProcessProvider(NodeProvider):
     """Workers as real subprocesses (``python -m
     presto_tpu.server.worker``), announcing to the coordinator(s) over
     HTTP — the closest local stand-in for cloud instances: separate
-    address spaces, real process exit on drain, SIGKILL preemption."""
+    address spaces, real process exit on drain, SIGKILL preemption.
+
+    A worker gets the platform its CALLER gives it: the child inherits
+    this process's environment, overlaid with ``extra_env`` — nothing
+    here picks ``JAX_PLATFORMS`` for it. One chip takes ONE worker
+    process (a chip belongs to the process that first touched it, this
+    one included), so more than one device-holding worker per chip
+    needs ``JAX_PLATFORMS=cpu`` from the caller. Not run on real chips
+    yet (ROADMAP.md)."""
 
     def __init__(self, coordinator_urls: Sequence[str],
                  tpch_sf: float = 0.01, host: str = "127.0.0.1",
@@ -354,8 +362,9 @@ class LocalProcessProvider(NodeProvider):
         self.spool_dir = spool_dir
         self.etc_dir = etc_dir
         self.ready_timeout_s = float(ready_timeout_s)
-        #: worker-process environment overlay (e.g. the elasticity
-        #: bench's PRESTO_TPU_DEVICE_FLOOR_MS device model)
+        #: worker-process environment overlay (JAX_PLATFORMS for the
+        #: workers, the elasticity bench's PRESTO_TPU_DEVICE_FLOOR_MS
+        #: device model)
         self.extra_env = dict(extra_env or {})
         self._handles: List[NodeHandle] = []
         self._seq = 0
@@ -372,7 +381,6 @@ class LocalProcessProvider(NodeProvider):
         if self.etc_dir:
             argv += ["--etc-dir", self.etc_dir]
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self.extra_env)
         proc = subprocess.Popen(
             argv, stdout=subprocess.PIPE,

@@ -38,12 +38,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:                                    # jax >= 0.6: top-level export,
-    from jax import shard_map           # replication check is check_vma
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:                     # jax 0.4.x: experimental module,
-    from jax.experimental.shard_map import shard_map  # kwarg check_rep
-    _SHARD_MAP_CHECK_KW = "check_rep"
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import types as T
@@ -696,7 +691,7 @@ class DistributedExecutor(_Executor):
                 f"smap:{label.split('.<locals>.')[-1]}",
                 jax.jit(shard_map(
                     fn, mesh=self.mesh, in_specs=in_specs,
-                    out_specs=out_specs, **{_SHARD_MAP_CHECK_KW: False}),
+                    out_specs=out_specs, check_vma=False),
                     donate_argnums=donate),
                 (site, in_specs, out_specs, donate), donate=donate)
             if cache_key is not None:

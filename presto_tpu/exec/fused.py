@@ -48,8 +48,9 @@ class JoinStage:
     joins only) — values arrive as traced scalars so changing bounds
     never recompiles. ``pallas`` routes this stage's probe through the
     fused Pallas ragged-gather kernel (ops/pallas_join) — the executor
-    sets it only for direct-address prepared builds within the VMEM
-    budget, and strips it (strip_pallas) if the kernel fails to lower."""
+    sets it only with the ``join_pallas_probe`` session property on
+    (default off), for direct-address prepared builds within the VMEM
+    budget; a kernel that fails to lower fails the query."""
     lkeys: Tuple[int, ...]
     rkeys: Tuple[int, ...]
     payload: Tuple[int, ...]
@@ -58,14 +59,6 @@ class JoinStage:
     out_fields: Tuple[Tuple[str, object], ...]
     dyn_keys: Tuple[int, ...] = ()
     pallas: bool = False
-
-
-def strip_pallas(stages: Tuple[object, ...]) -> Tuple[object, ...]:
-    """The same chain with every JoinStage forced onto the XLA gather
-    path — the fused-pipeline fallback after a kernel compile failure."""
-    return tuple(dataclasses.replace(st, pallas=False)
-                 if isinstance(st, JoinStage) and st.pallas else st
-                 for st in stages)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -998,7 +998,9 @@ class PrestoTpuServer:
                  resource_groups: Optional[Dict] = None,
                  authenticator=None, jwt_authenticator=None,
                  discovery=None):
+        from .. import enable_compile_cache
         from .resource_groups import ResourceGroupManager
+        enable_compile_cache()
         self.authenticator = authenticator
         self.jwt_authenticator = jwt_authenticator
         if runner is None:

@@ -1038,6 +1038,8 @@ class WorkerServer:
                  host: str = "127.0.0.1", port: int = 0,
                  node_id: Optional[str] = None, tpch_sf: float = 0.01,
                  drain_grace_s: float = 5.0):
+        from .. import enable_compile_cache
+        enable_compile_cache()
         if catalogs is None:
             from ..connectors.memory import MemoryConnector
             from ..connectors.system import SystemConnector

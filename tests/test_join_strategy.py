@@ -120,7 +120,6 @@ def test_direct_keyed_plan_gates():
 @pytest.fixture
 def force_pallas(monkeypatch):
     monkeypatch.setattr(PJ, "FORCE_PALLAS_PROBE", True)
-    monkeypatch.setitem(PJ._STATE, "broken", False)
 
 
 def test_pallas_lookup_parity_dtypes(force_pallas):
@@ -165,68 +164,82 @@ def test_pallas_supports_join_gate():
     sortp = J.prepare_build(build, [0])
     assert not PJ.supports_join(sortp, build, [1])   # not direct
     prep = J.prepare_direct(build, [0], 1, 256)
-    PJ._STATE["broken"] = True
-    try:
-        assert not PJ.kernel_enabled()
-    finally:
-        PJ._STATE["broken"] = False
+    assert not PJ.kernel_enabled()                   # CPU backend
+    assert not PJ.supports_join(prep, build, [1])
 
 
-def test_pallas_engine_parity_and_breaker(force_pallas, monkeypatch):
-    """The 3-way tpch star chain runs the fused pipeline through the
-    kernel; flipping the session property (and tripping the breaker)
-    both land on the identical rows."""
+def test_pallas_supports_join_gate_forced(force_pallas):
+    build = Batch.from_pydict({
+        "k": (T.BIGINT, list(range(1, 200))),
+        "v": (T.BIGINT, list(range(199)))})
+    prep = J.prepare_direct(build, [0], 1, 256)
+    assert PJ.supports_join(prep, build, [1])
+    assert not PJ.supports_join(prep, build, list(range(32)))  # bits
+
+
+def _star_runner(sf, rows_per_batch):
     from presto_tpu.connectors.spi import CatalogManager
     from presto_tpu.connectors.tpch import TpchConnector
     from presto_tpu.exec.runner import LocalRunner
     catalogs = CatalogManager()
-    catalogs.register("tpch", TpchConnector(sf=0.01))
-    r = LocalRunner(catalogs=catalogs, catalog="tpch",
-                    rows_per_batch=1 << 14)
+    catalogs.register("tpch", TpchConnector(sf=sf))
+    return LocalRunner(catalogs=catalogs, catalog="tpch",
+                       rows_per_batch=rows_per_batch)
+
+
+def test_pallas_engine_parity(force_pallas):
+    """The 3-way tpch star chain runs the fused pipeline through the
+    kernel (interpret mode) with the session property on, and lands on
+    the rows of the default XLA probe."""
+    r = _star_runner(0.01, 1 << 14)
     q = ("select n_name, count(*) c from orders "
          "join customer on o_custkey = c_custkey "
          "join nation on c_nationkey = n_nationkey "
          "group by n_name order by n_name")
     before = _metric("join_strategy_selected_total.direct.replicated")
-    on = r.execute(q).rows
+    on = r.execute(q, properties={"join_pallas_probe": True}).rows
     after = _metric("join_strategy_selected_total.direct.replicated")
     assert after > before
-    off = r.execute(q, properties={"join_pallas_probe": False}).rows
+    off = r.execute(q).rows
     assert on == off
-    assert _metric("join_pallas_fallback_total") == 0.0
 
 
-def test_pallas_breaker_falls_back(monkeypatch):
-    """A kernel that fails to lower costs one fallback count, never a
-    query: dispatch transparently re-runs on XLA and the breaker stays
-    tripped for later dispatches."""
+def test_pallas_probe_off_by_default():
+    """The kernel does not lower for a TPU v5e (ops/pallas_join
+    docstring, tests/test_tpu_compile.py): the property defaults off in
+    the registry AND at the executor's read, even on a backend that
+    reports the kernel enabled."""
+    from presto_tpu.config import SESSION_PROPERTIES
+    assert SESSION_PROPERTIES["join_pallas_probe"].default is False
+    from presto_tpu.exec.local import _Executor
     from presto_tpu.connectors.spi import CatalogManager
-    from presto_tpu.connectors.tpch import TpchConnector
-    from presto_tpu.exec.runner import LocalRunner
-    monkeypatch.setattr(PJ, "FORCE_PALLAS_PROBE", False)
-    monkeypatch.setitem(PJ._STATE, "broken", False)
-    # backend reports capable, kernel explodes at dispatch
+    from presto_tpu.planner.planner import Session
+    cats = CatalogManager()
+    assert not _Executor(Session(cats), 1 << 13)._pallas_probe_on()
+    on = Session(cats, properties={"join_pallas_probe": True})
+    assert _Executor(on, 1 << 13)._pallas_probe_on()
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_pallas_kernel_failure_fails_query(monkeypatch, fused):
+    """With join_pallas_probe=true a kernel that fails to lower fails
+    the query with the compiler's message — nothing re-runs it on
+    another implementation. With the default (off) the same query
+    never reaches the kernel."""
     monkeypatch.setattr(PJ, "kernel_enabled", lambda: True)
 
     def boom(*a, **k):
-        raise RuntimeError("mosaic lowering failed")
-    monkeypatch.setattr(
-        "presto_tpu.ops.jitcache.lookup_join_pallas_jit", boom)
+        raise NotImplementedError("Only 2D gather is supported")
+    monkeypatch.setattr(PJ, "lookup_join_direct", boom)
     monkeypatch.setattr(
         "presto_tpu.exec.local.lookup_join_pallas_jit", boom)
-    catalogs = CatalogManager()
-    catalogs.register("tpch", TpchConnector(sf=0.002))
-    r = LocalRunner(catalogs=catalogs, catalog="tpch",
-                    rows_per_batch=1 << 13)
-    before = _metric("join_pallas_fallback_total")
-    rows = r.execute(
-        "select count(*) from orders join customer "
-        "on o_custkey = c_custkey where c_nationkey = 3",
-        properties={"fused_pipeline": False}).rows
-    assert rows[0][0] > 0
-    assert _metric("join_pallas_fallback_total") >= before + 1
-    assert PJ._STATE["broken"]
-    PJ._STATE["broken"] = False
+    r = _star_runner(0.002, 1 << 13)
+    q = ("select count(*) from orders join customer "
+         "on o_custkey = c_custkey where c_nationkey = 3")
+    props = {"fused_pipeline": fused}
+    assert r.execute(q, properties=props).rows[0][0] > 0
+    with pytest.raises(Exception, match="Only 2D gather"):
+        r.execute(q, properties={**props, "join_pallas_probe": True})
 
 
 # ---------------------------------------------------------------------------

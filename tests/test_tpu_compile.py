@@ -1,0 +1,109 @@
+"""Ahead-of-time compiles of the main path's kernels for a DESCRIBED
+TPU v5e (no chip attached): the TPU compiler is installed in the
+sandbox and refuses here what it would refuse on the chip, at no chip
+time. Sizes are the ones chip_smoke.py drives at TPC-H SF10.
+
+Nothing touches ``jax.experimental.topologies`` at import, in a skipif
+or in a parametrize: only one process may load libtpu, so the topology
+is described inside a module-scoped fixture (the xdist worker that is
+handed this file) and every test of it lives in this one file. A
+passing compile is not a chip run and is never reported as one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from presto_tpu.config import SESSION_PROPERTIES
+from presto_tpu.ops import pallas_join, pallas_scan
+
+N_ROWS = 1 << 23          # sort-path group-by rows (ops/aggregation.py)
+N_BATCH = 1 << 20         # rows_per_batch on the SQL path
+N_SLOTS = 1 << 17         # direct-join lookup tables
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any refusal means "no TPU compiler here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip — keep it off here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def test_cumsum_i32_compiles_for_v5e(one_chip):
+    x = jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip)
+    c = _compile(lambda a: pallas_scan.cumsum_i32(a, interpret=False), x)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_segment_sum_sorted_i64_compiles_for_v5e(one_chip):
+    v = jax.ShapeDtypeStruct((N_ROWS,), jnp.int64, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((N_BATCH,), jnp.int32, sharding=one_chip)
+    c = _compile(
+        lambda vals, starts: pallas_scan.segment_sum_sorted_i64(
+            vals, starts, N_BATCH, interpret=False), v, s)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_q6_step_compiles_for_v5e(one_chip):
+    """The jitted Q6 scan-filter-project-aggregate step of
+    ``__graft_entry__.entry()`` rebuilt at the SQL path's batch size."""
+    import __graft_entry__ as graft
+    step, (batch,) = graft.entry()
+
+    def widen(leaf):
+        assert leaf.shape[0] == batch.capacity, leaf.shape
+        return jax.ShapeDtypeStruct((N_BATCH,) + leaf.shape[1:],
+                                    leaf.dtype, sharding=one_chip)
+    big = jax.tree_util.tree_map(widen, batch)
+    c = _compile(step, big)
+    assert c.memory_analysis() is not None
+
+
+def _probe_shapes(one_chip):
+    i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,  # noqa: E731
+                                         sharding=one_chip)
+    return [i32(N_BATCH)] + [i32(N_SLOTS)] * 5
+
+
+def _probe(codes, lo, cnt, vb, p0, p1):
+    return pallas_join.direct_probe(codes, lo, cnt, vb, [p0, p1],
+                                    interpret=False)
+
+
+def test_direct_probe_is_refused_for_v5e_and_off_by_default(one_chip):
+    """The probe kernel's arbitrary-index gather from VMEM-resident
+    tables does not lower on the installed JAX/libtpu, so the session
+    property that routes joins through it must default to off. When a
+    later JAX lowers it, this test fails: flip the default then (ISSUE
+    23, ROADMAP 'decide the probe kernel from a trace')."""
+    with pytest.raises(NotImplementedError, match="gather"):
+        _compile(_probe, *_probe_shapes(one_chip))
+    assert SESSION_PROPERTIES["join_pallas_probe"].default is False

@@ -69,14 +69,9 @@ SERVING_GROUPS = {
 
 
 def _enable_compile_cache() -> None:
-    """Same persistent XLA cache bench.py uses (jax.config is
-    per-process — children must opt in themselves)."""
-    import jax
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(repo, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    """jax.config is per-process — children must opt in themselves."""
+    from presto_tpu import enable_compile_cache
+    enable_compile_cache()
 
 
 def _build_catalogs(sf: float, sqlite_path: Optional[str]):
@@ -391,6 +386,32 @@ def _await_ready(rec: dict, timeout_s: float) -> None:
     rec["url"] = doc["url"]
 
 
+def check_children_can_hold_devices(n_children: int) -> None:
+    """Refuse a spawn that could only mislead. Every fleet child
+    imports JAX and takes whatever devices its environment shows it,
+    and a chip belongs to ONE process: a parent that has initialised a
+    JAX backend holds the chip its children would need, and N
+    device-holding children need N chips, which this launcher does not
+    hand out. Either the caller puts the children on the CPU,
+    explicitly (``JAX_PLATFORMS=cpu`` in the environment they inherit),
+    or there is one child and a parent that stayed off JAX. The fleet
+    on real chips is a later issue (ROADMAP.md)."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "this process has initialised a JAX backend and holds its "
+            "devices; children that need them would fail or hang. "
+            "Launch from a parent that stays off JAX, or set "
+            "JAX_PLATFORMS=cpu for the children")
+    if n_children > 1:
+        raise RuntimeError(
+            f"{n_children} device-holding children need {n_children} "
+            "chips and this launcher assigns none: set "
+            "JAX_PLATFORMS=cpu to run the fleet's processes on the CPU")
+
+
 def launch_fleet(n_coordinators: int = 3, sf: float = 0.01,
                  workers: int = 1, sqlite_path: Optional[str] = None,
                  heartbeat_s: float = 0.5,
@@ -400,9 +421,14 @@ def launch_fleet(n_coordinators: int = 3, sf: float = 0.01,
     """Spawn the fleet: ``n_coordinators`` statement servers (each a
     fleet member, peered all-to-all) and ``workers`` worker processes
     announcing to every coordinator. Blocks until every child printed
-    its ready line."""
+    its ready line.
+
+    Every child is a process that imports JAX: see
+    :func:`check_children_can_hold_devices` — the caller sets
+    ``JAX_PLATFORMS=cpu``, or the spawn is refused."""
     if n_coordinators < 2:
         raise ValueError("a fleet needs >= 2 coordinators")
+    check_children_can_hold_devices(n_coordinators + workers)
     ports = _free_ports(n_coordinators + workers)
     coord_ports = ports[:n_coordinators]
     urls = [f"http://127.0.0.1:{p}" for p in coord_ports]
