@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .obs.trace import device_sync
 from .types import ArrayType, MapType, Type, VarcharType, CharType, parse_type
 
 
@@ -162,11 +163,12 @@ class Batch:
         """Number of live rows (device scalar)."""
         return jnp.sum(self.row_mask.astype(jnp.int32))
 
-    def host_count(self) -> int:
+    def host_count(self, what: str = "host-count") -> int:
         # explicit device_get: an int() on a device scalar is an IMPLICIT
         # transfer, which jax.transfer_guard("disallow") rejects — sizing
         # syncs are deliberate and should read as such
-        return int(jax.device_get(self.count()))
+        with device_sync(what):
+            return int(jax.device_get(self.count()))
 
     def column(self, name: str) -> Column:
         return self.columns[self.schema.index_of(name)]
@@ -273,15 +275,22 @@ class Batch:
 
     # -- export -------------------------------------------------------------
     def to_pylist(self) -> List[Tuple]:
-        """Decode live rows to python tuples (for tests / client results)."""
-        mask = np.asarray(jax.device_get(self.row_mask))
+        """Decode live rows to python tuples (for tests / client results):
+        the answer's fetch, one ``device-sync`` (``what="result"``) over
+        every column's transfer."""
+        with device_sync("result"):
+            host = jax.device_get(
+                (self.row_mask,
+                 [(c.data, c.validity) for c in self.columns]))
+        mask = np.asarray(host[0])
         out_cols = []
-        for col in self.columns:
+        for col, (data, valid) in zip(self.columns, host[1]):
             if isinstance(col.type, (ArrayType, MapType)):
-                out_cols.append(_composite_to_pylist(col, mask))
+                out_cols.append(_composite_to_pylist(
+                    Column(col.type, data, valid, col.dictionary), mask))
                 continue
-            data = np.asarray(jax.device_get(col.data))[mask]
-            valid = np.asarray(jax.device_get(col.validity))[mask]
+            data = np.asarray(data)[mask]
+            valid = np.asarray(valid)[mask]
             vals: List[Any] = []
             for d, v in zip(data, valid):
                 if not v:

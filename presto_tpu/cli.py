@@ -122,13 +122,15 @@ def main(argv=None) -> int:
                          "PRESTO_TPU_TRACE=1")
     ap.add_argument("--profile-out", default=None, metavar="DIR",
                     help="deep-profile mode: enable span tracing AND "
-                         "the `profile` session property (device-time "
-                         "attribution), capture a jax.profiler trace "
-                         "of the executed statements, and write "
-                         "DIR/merged_trace.json with host spans and "
-                         "XLA device tracks on one Perfetto timeline; "
-                         "embedded server only — with --server the "
-                         "device runs in the server process")
+                         "the `profile` session property (host-bracketed "
+                         "device-time attribution) and capture a "
+                         "jax.profiler trace of the executed statements "
+                         "under DIR; the engine's spans (query, op:*, "
+                         "dispatch, device-sync, ...) stand in the "
+                         ".xplane.pb host plane on the device trace's "
+                         "clock, and its path is printed; embedded "
+                         "server only — with --server the device runs "
+                         "in the server process")
     ap.add_argument("--history-out", default=None, metavar="PATH",
                     help="append one JSON line per completed query "
                          "(the system.runtime.completed_queries "
@@ -216,25 +218,23 @@ def main(argv=None) -> int:
         return 0
     finally:
         if profiling:
+            import glob
             import os
 
             import jax
-
-            from .obs.profiler import write_merged_trace
-            from .obs.trace import TRACER
             try:
                 jax.profiler.stop_trace()
-            except Exception:
-                pass
-            merged = os.path.join(args.profile_out, "merged_trace.json")
-            try:
-                write_merged_trace(merged, TRACER.export(),
-                                   args.profile_out)
-                print(f"wrote merged host+device trace to {merged} "
-                      "(open in ui.perfetto.dev)", file=sys.stderr)
             except Exception as e:   # must not mask the query outcome
-                print(f"merged-trace write failed: {e}",
+                print(f"device profiler stop failed: {e}",
                       file=sys.stderr)
+            planes = sorted(glob.glob(os.path.join(
+                args.profile_out, "plugins", "profile", "*",
+                "*.xplane.pb")), key=os.path.getmtime)
+            if planes:
+                print(f"wrote profile to {planes[-1]} (host spans and "
+                      "device ops on one clock; read it with "
+                      "jax.profiler.ProfileData or open it in "
+                      "xprof/TensorBoard)", file=sys.stderr)
         if args.trace_out:
             from .obs.trace import TRACER, write_chrome_trace
             write_chrome_trace(args.trace_out, TRACER.export())

@@ -47,7 +47,7 @@ from ..expr import ir
 from ..expr.compiler import compile_filter, compile_projection
 from ..obs import flight as _flight
 from ..obs.metrics import REGISTRY
-from ..obs.trace import TRACER
+from ..obs.trace import TRACER, device_sync
 from ..ops.aggregation import AggSpec, global_aggregate, grouped_aggregate
 from ..ops.join import (
     build_match_mask, expand_join, lookup_join, match_count_max,
@@ -150,7 +150,7 @@ def _sync_record(what: str, kind: str = "sync"):
     fl = _flight.current_flight()
     t0 = time.perf_counter() if fl is not None else 0.0
     try:
-        with TRACER.span("device-sync", what=what):
+        with device_sync(what):
             yield
     finally:
         if fl is not None:
@@ -169,7 +169,7 @@ def _drain_inputs(*values) -> None:
     fl = _flight.current_flight()
     t0 = time.perf_counter() if fl is not None else 0.0
     try:
-        with TRACER.span("device-sync", what="input-drain"):
+        with device_sync("input-drain"):
             jax.block_until_ready([v for v in values if v is not None])
     finally:
         if fl is not None:
@@ -661,7 +661,8 @@ class DistributedExecutor(_Executor):
         # operators' compiles/FLOPs into one executables row), while
         # re-builds of the same program share one record instead of
         # churning the registry query after query
-        from ..ops.jitcache import _TimedEntry, program_signature
+        from ..ops.jitcache import (program_name, program_signature,
+                                    timed_entry)
         label = getattr(fn, "__qualname__", None) \
             or getattr(fn, "__name__", "fn")
         code = getattr(fn, "__code__", None)
@@ -687,13 +688,15 @@ class DistributedExecutor(_Executor):
             (_PROGRAM_HITS if entry is not None
              else _PROGRAM_MISSES).inc()
         if entry is None:
-            entry = _TimedEntry(
+            entry = timed_entry(
                 f"smap:{label.split('.<locals>.')[-1]}",
-                jax.jit(shard_map(
+                shard_map(
                     fn, mesh=self.mesh, in_specs=in_specs,
                     out_specs=out_specs, check_vma=False),
-                    donate_argnums=donate),
-                (site, in_specs, out_specs, donate), donate=donate)
+                (site, in_specs, out_specs, donate), donate=donate,
+                program=program_name("smap", "_".join(
+                    [stage] + [c for c in label.split(".")
+                               if c != "<locals>"][-2:])))
             if cache_key is not None:
                 with _PROGRAM_CACHE_LOCK:
                     _PROGRAM_CACHE[cache_key] = entry
@@ -724,10 +727,10 @@ class DistributedExecutor(_Executor):
         here device-to-device only)."""
         fn = self._replicate_jit
         if fn is None:
-            from ..ops.jitcache import _TimedEntry
-            fn = self._replicate_jit = _FlightDispatch(_TimedEntry(
-                "replicate_device",
-                jax.jit(lambda b: b, out_shardings=self._replicated)),
+            from ..ops.jitcache import timed_entry
+            fn = self._replicate_jit = _FlightDispatch(timed_entry(
+                "replicate_device", lambda b: b,
+                out_shardings=self._replicated),
                 "dispatch", stage="exchange")
         return fn(batch)
 
@@ -908,7 +911,7 @@ class DistributedExecutor(_Executor):
         ncols = len(schema)
         fl = _flight.current_flight()
         t0 = time.perf_counter()
-        with TRACER.span("device-sync", what="scan-stage"):
+        with device_sync("scan-stage"):
             for p in parts:
                 if p is None:
                     for ci in range(ncols):

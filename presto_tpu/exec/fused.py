@@ -31,7 +31,6 @@ import dataclasses
 import functools
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from ..batch import Batch, Column, Schema
@@ -153,12 +152,12 @@ def fused_pipeline(stages: Tuple[object, ...]):
         cur = _apply_stages(probe, stages, preps, builds, dyns, errs)
         return cur, _merge_errs(errs)
 
-    # _TimedEntry: the fused chain is an executable like any jitcache
+    # timed_entry: the fused chain is an executable like any jitcache
     # entry — compile time, invocations, and (under a profile context)
     # device time land in obs.profiler.EXECUTABLES, attributed to the
     # join node whose frame dispatches the chain
-    from ..ops.jitcache import _TimedEntry
-    return _TimedEntry("fused_pipeline", jax.jit(run), stages)
+    from ..ops.jitcache import timed_entry
+    return timed_entry("fused_pipeline", run, stages)
 
 
 @functools.lru_cache(maxsize=None)
@@ -203,6 +202,6 @@ def fused_prefilter(stages: Tuple[object, ...],
         count = jnp.sum(cur.row_mask.astype(jnp.int32))
         return cur, _merge_errs(errs), count
 
-    from ..ops.jitcache import _TimedEntry
-    return _TimedEntry("fused_prefilter", jax.jit(run),
+    from ..ops.jitcache import timed_entry
+    return timed_entry("fused_prefilter", run,
                        (stages, pre_keys, semi_keys))

@@ -34,7 +34,7 @@ from ..ops.jitcache import (
     prepare_direct_keyed_jit, semi_join_mask_jit,
 )
 from ..obs.metrics import REGISTRY
-from ..obs.trace import TRACER
+from ..obs.trace import TRACER, device_sync
 
 #: grouped-aggregation kernel dispatch, per operator (first batch decides
 #: and the plan is shape-stable): dense composite-code path (broadcast or
@@ -355,9 +355,8 @@ def unnest_expand_fn(exprs, ordinality: bool, schema: Schema):
     # invocations and profiled device time land in obs.profiler's
     # EXECUTABLES like every jitcache kernel, and the trace-safety lint
     # (tools/analyze/tracing.py) holds the line on new bypasses
-    from ..ops.jitcache import _TimedEntry
-    return _TimedEntry("unnest_expand", jax.jit(expand),
-                       (exprs, ordinality))
+    from ..ops.jitcache import timed_entry
+    return timed_entry("unnest_expand", expand, (exprs, ordinality))
 
 
 class _Executor:
@@ -431,7 +430,7 @@ class _Executor:
         import numpy as np
 
         from ..errors import QueryError
-        with TRACER.span("device-sync", what="error-flags"):
+        with device_sync("error-flags"):
             codes = np.asarray(jnp.stack(self.error_flags))
         self.error_flags = []
         code = int(codes.max())
@@ -627,8 +626,7 @@ class _Executor:
             # is not measured on the v5e)
             if not state["check"] or b.capacity <= (1 << 17):
                 return b
-            with TRACER.span("device-sync", what="compaction-liveness"):
-                tgt = bucket_capacity(b.host_count())
+            tgt = bucket_capacity(b.host_count("compaction-liveness"))
             if tgt * 4 <= b.capacity:
                 return b.compact(tgt, check=False)
             state["check"] = False
@@ -661,7 +659,7 @@ class _Executor:
             if remaining <= 0:
                 return
             out = limit_kernel(b, remaining)
-            remaining -= out.host_count()
+            remaining -= out.host_count("limit-remaining")
             yield out
 
     def _UnionNode(self, node: UnionNode) -> Iterator[Batch]:
@@ -1177,8 +1175,7 @@ class _Executor:
         def drain_pend() -> List[Batch]:
             if not pend:
                 return []
-            with TRACER.span("device-sync", what="fused-liveness",
-                             batches=len(pend)):
+            with device_sync("fused-liveness", batches=len(pend)):
                 counts = np.asarray(jnp.stack([c for _, c in pend]))
             outs, shrunk = [], False
             for (b, _), live in zip(pend, counts):
@@ -1624,7 +1621,7 @@ class _Executor:
         from ..ops.jitcache import build_summary_jit
         int_flags = tuple(isinstance(build.columns[k].type, _DYN_TYPES)
                           for k in keys)
-        with TRACER.span("device-sync", what="build-summary"):
+        with device_sync("build-summary"):
             return np.asarray(
                 build_summary_jit(build, tuple(keys), int_flags))
 
@@ -1711,7 +1708,7 @@ class _Executor:
         the chunked skew path (most probe batches never touch the hot
         key), so those fall back to the per-batch match_count_max sync."""
         from ..ops.jitcache import max_multiplicity_jit
-        with TRACER.span("device-sync", what="build-multiplicity"):
+        with device_sync("build-multiplicity"):
             m = int(max_multiplicity_jit(prepared))
         return m if m <= self.SKEW_MATCH_LIMIT else None
 

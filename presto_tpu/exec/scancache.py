@@ -65,6 +65,7 @@ from ..connectors import spi
 from ..memory import QueryMemoryPool, batch_device_bytes
 from ..obs import flight as _flight
 from ..obs.metrics import REGISTRY
+from ..obs.trace import TRACER
 from .failpoints import FAILPOINTS
 
 _HITS = REGISTRY.counter("scan_cache_hit_total")
@@ -394,6 +395,9 @@ def scan_splits(conn, catalog: str, columns: Sequence[str],
     # peers (which wait on background threads, outside any quantum) can
     # ride their decode.
     inline_scan = not opts.prefetch or opts.threads <= 1
+    # the prefetch workers run on threads of their own, where no span
+    # is current: their scan-stage spans join this query's trace
+    trace_ctx = TRACER.context()
 
     def split_keys(split, pushdown):
         """[effective key, static-pushdown fallback key] (deduped);
@@ -514,15 +518,28 @@ def scan_splits(conn, catalog: str, columns: Sequence[str],
                                    rows_per_batch=rows_per_batch)
             acc = [] if keys else None
             nb = 0
-            for b in src.batches():
-                # failpoint: abort mid-decode (chaos tests prove a
-                # failed/aborted scan never reaches the put() below — a
-                # partial column set must not become a resident cache
-                # entry)
-                FAILPOINTS.hit("scan.decode",
-                               key=f"{catalog}.{split.table.table}.{i}",
-                               split=i, batch=nb)
-                b = stage(b)
+            batches = iter(src.batches())
+            while True:
+                # a miss: the connector generates or decodes the batch
+                # and device_put stages it; one span a batch (a span
+                # does not survive the yield below)
+                with TRACER.task_span(trace_ctx, "scan-stage",
+                                      table=split.table.table,
+                                      split=i) as span:
+                    b = next(batches, None)
+                    if b is None:
+                        break
+                    # failpoint: abort mid-decode (chaos tests prove a
+                    # failed/aborted scan never reaches the put() below
+                    # — a partial column set must not become a resident
+                    # cache entry)
+                    FAILPOINTS.hit(
+                        "scan.decode",
+                        key=f"{catalog}.{split.table.table}.{i}",
+                        split=i, batch=nb)
+                    b = stage(b)
+                    if TRACER.enabled:
+                        span.annotate(bytes=batch_device_bytes(b))
                 nb += 1
                 if acc is not None:
                     acc.append(b)

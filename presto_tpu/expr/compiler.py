@@ -14,6 +14,7 @@ mirroring Presto's SpecialForm handling in sql/gen/AndCodeGenerator etc.
 """
 from __future__ import annotations
 
+import hashlib
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -22,6 +23,9 @@ import numpy as np
 
 from .. import types as T
 from ..batch import Batch, Column, Schema
+from ..obs import profiler as _prof
+from ..obs.metrics import REGISTRY
+from ..obs.trace import TRACER
 from . import functions as F
 from . import array_functions  # noqa: F401  (registers array fns)
 from .functions import Val, cast_val, merge_err
@@ -351,7 +355,7 @@ import contextlib
 @contextlib.contextmanager
 def _param_trace(exprs, pargs):
     """Trace scope mapping each Param slot to its traced jit operand
-    (slot-order matches _jit_maybe_params/current_args). No-op for the
+    (slot-order matches _ExprProgram/current_args). No-op for the
     common parameter-free kernel."""
     if not len(pargs):
         yield
@@ -362,21 +366,52 @@ def _param_trace(exprs, pargs):
         yield
 
 
-def _jit_maybe_params(run, exprs):
-    """jit ``run``; parameterized expressions (plan templates) get their
-    bindings as an extra traced-operand tuple fetched from the query's
-    binding scope at call time, so the cached executable is shared by
-    every binding (value changes re-dispatch, never re-trace)."""
-    from .params import collect_params, current_args
-    slots = collect_params(exprs)
-    if not slots:
-        fn = jax.jit(lambda batch: run(batch))
-        return fn
-    inner = jax.jit(run)
+_EXPR_INVOCATIONS = REGISTRY.counter("expr_program_invocations_total")
 
-    def fn(batch: Batch):
-        return inner(batch, current_args(slots))
-    return fn
+
+class _ExprProgram:
+    """One compiled expression program: the jitted kernel under its own
+    name, ``expr_<kind>_<digest>`` (``kind`` the entry point, ``digest``
+    six hex digits of a SHA-1 of the expressions' text and the input
+    schema's: the same in every process, whatever ``PYTHONHASHSEED``),
+    with a ledger in ``obs.profiler.EXECUTABLES`` whose static key is
+    that text (``system.runtime.executables`` turns a digest seen in a
+    device trace back into SQL). Launches count in
+    ``expr_program_invocations_total`` and, while the tracer is on, are
+    ``dispatch`` spans. Parameterized expressions (plan templates) get
+    their bindings as an extra traced-operand tuple fetched from the
+    query's binding scope at call time, so the cached executable is
+    shared by every binding (value changes re-dispatch, never
+    re-trace)."""
+
+    __slots__ = ("program", "text", "fn", "slots", "bindings", "record")
+
+    def __init__(self, kind: str, key, run, exprs):
+        from ..ops.jitcache import named_jit
+        from .params import collect_params, current_args
+        self.text = repr(key)
+        name = (f"expr_{kind}_"
+                + hashlib.sha1(self.text.encode()).hexdigest()[:6])
+        self.program = "jit_" + name
+        self.slots = collect_params(exprs)
+        self.bindings = current_args
+        self.fn = named_jit(name, run)
+        self.record = _prof.EXECUTABLES.register(name, self.text,
+                                                 program=name)
+
+    def __call__(self, batch: Batch):
+        _prof.note_launch(self.record)
+        _EXPR_INVOCATIONS.inc()
+        if TRACER.enabled:
+            with TRACER.span("dispatch", program=self.program,
+                             expr=self.text[:200]):
+                return self._launch(batch)
+        return self._launch(batch)
+
+    def _launch(self, batch: Batch):
+        if self.slots:
+            return self.fn(batch, self.bindings(self.slots))
+        return self.fn(batch)
 
 
 class ExprCompiler:
@@ -425,8 +460,7 @@ class ExprCompiler:
                 return result
             return result, _err_scalar([o.err for o in outs], batch.row_mask)
 
-        fn = _jit_maybe_params(run, exprs)
-        self._cache[key] = fn
+        fn = self._cache[key] = _ExprProgram("project", key, run, exprs)
         return fn
 
     def filter(self, pred: Expr, schema: Schema,
@@ -453,8 +487,7 @@ class ExprCompiler:
                 return result
             return result, _err_scalar([p.err], batch.row_mask)
 
-        fn = _jit_maybe_params(run, [pred])
-        self._cache[key] = fn
+        fn = self._cache[key] = _ExprProgram("filter", key, run, [pred])
         return fn
 
 

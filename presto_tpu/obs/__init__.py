@@ -6,9 +6,11 @@ connector that turns engine metrics into SQL tables (reference
 presto-main/.../connector/jmx/) — reshaped for a device runtime:
 
 - ``obs.trace``   context-propagated spans (query -> stage -> task ->
-                  operator -> device-sync/compile) with a Chrome-trace
-                  (Perfetto) JSON exporter and wire-carriable span
-                  context for distributed stitching;
+                  operator -> dispatch/device-sync/compile), each also
+                  a ``jax.profiler.TraceAnnotation`` while the tracer
+                  is on, with a Chrome-trace (Perfetto) JSON exporter
+                  and wire-carriable span context for distributed
+                  stitching;
 - ``obs.metrics`` process-wide counters/gauges/histograms fed by direct
                   instrumentation and by an EventListenerManager sink,
                   queryable as ``system.runtime.metrics``;
@@ -24,8 +26,8 @@ presto-main/.../connector/jmx/) — reshaped for a device runtime:
                   compile/FLOPs/HBM introspection
                   (``system.runtime.executables``), per-operator
                   device-time attribution under the ``profile`` session
-                  property, HBM telemetry sampling, and host+device
-                  Chrome-trace merging for ``--profile-out``.
+                  property, the one listener on JAX's compile event,
+                  and HBM telemetry sampling.
 
 Everything is always importable and safe when idle: the tracer is OFF
 by default (a disabled ``span()`` returns a shared no-op and records

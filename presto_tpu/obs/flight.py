@@ -289,31 +289,6 @@ def round_rows(query_id: str,
     ]
 
 
-def chrome_events(flight: FlightRecorder, pid: int = 3) -> List[dict]:
-    """Chrome-trace ``X`` events for one flight — the mesh-rounds
-    track merged into ``write_merged_trace`` (one tid per bucket so
-    Perfetto groups the timeline by attribution)."""
-    tids = {b: i for i, b in enumerate(BUCKETS)}
-    events: List[dict] = [{
-        "ph": "M", "pid": pid, "name": "process_name",
-        "args": {"name": "mesh rounds"},
-    }]
-    for b, tid in tids.items():
-        events.append({"ph": "M", "pid": pid, "tid": tid,
-                       "name": "thread_name", "args": {"name": b}})
-    for r in flight.records():
-        bucket = KIND_BUCKET.get(r["kind"], "dispatch_overhead")
-        events.append({
-            "ph": "X", "pid": pid, "tid": tids[bucket],
-            "ts": r["t"] * 1e6, "dur": max(r["wall"], 1e-7) * 1e6,
-            "name": f"{r['kind']}#{r['round']}",
-            "args": {"stage": r["stage"], "rows": r["rows"],
-                     "bytes": r["bytes"],
-                     "loads": list(r["loads"] or ())},
-        })
-    return events
-
-
 def history_fields(attribution: Optional[dict]) -> dict:
     """Query-history fields (obs/history.py RECORD_COLUMNS tail +
     ``system.runtime.completed_queries``) from one attribution; empty

@@ -18,16 +18,17 @@ engine's *device-level* truth:
   measured interval is device time, and attributed to the plan operator
   whose iterator frame made the call (``operator_scope``, set by
   ``exec/stats.StatsCollector.wrap``). Off (the default) the only cost
-  per dispatch is one contextvar load and an int increment; an optional
-  process-wide ``EXECUTABLES.sample_every`` times every Nth call for
-  always-on sampling.
+  per dispatch is one contextvar load and an int increment.
+- **the compile listener** (``_on_compile``): ONE listener on JAX's
+  ``backend_compile_duration`` event counts every XLA compile of the
+  process (``xla_compile_total``/``xla_compile_seconds_total``: first
+  calls, retraces of later shape buckets, expression programs, eager
+  ops, persistent-cache loads) and charges the ones whose ``fun_name``
+  is the program being dispatched on that thread to its record
+  (``compiles``/``compile_seconds``, ``jit_compile_*``).
 - **HBM telemetry** (``sample_hbm``): ``device.memory_stats()`` gauges,
   sampled on worker heartbeats and by the local
   ``system.runtime.nodes`` fallback.
-- **device-trace merging** (``merge_profile_dir``): folds the Chrome
-  trace ``jax.profiler.trace`` wrote (XLA device tracks) into the span
-  tracer's Chrome-trace export so host spans and device kernels land on
-  one Perfetto timeline (the CLI's ``--profile-out``).
 
 Caveat worth stating once: bracketing with ``block_until_ready``
 serializes the dispatch pipeline — profile mode trades overlap for
@@ -39,15 +40,14 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import glob
-import gzip
-import json
-import os
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+import jax.monitoring
+
 from .metrics import REGISTRY
+from .trace import TRACER
 
 #: active profile session (None = off) — checked on every jit dispatch
 _ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
@@ -70,14 +70,17 @@ class ExecutableRecord:
     introspection is computed lazily from the first call's avals so a
     query never pays a second compile unless someone asks."""
 
-    __slots__ = ("name", "static_key", "compiles", "compile_seconds",
-                 "invocations", "device_time_s", "created_at", "evicted",
-                 "_key_repr", "_fn", "_avals", "_analysis", "_lock",
-                 "_alock")
+    __slots__ = ("name", "static_key", "fun_name", "compiles",
+                 "compile_seconds", "invocations", "device_time_s",
+                 "created_at", "evicted", "_key_repr", "_fn", "_avals",
+                 "_analysis", "_lock", "_alock")
 
     def __init__(self, name: str, static_key: str):
         self.name = name
         self.static_key = static_key
+        #: what JAX's compile event calls this record's program
+        #: (``jit(op_grouped_aggregate)``); set at registration
+        self.fun_name: Optional[str] = None
         self.compiles = 0
         self.compile_seconds = 0.0
         self.invocations = 0
@@ -108,15 +111,19 @@ class ExecutableRecord:
         with self._lock:
             self.device_time_s += seconds
 
-    def note_compile(self, seconds: float, fn, args) -> None:
-        """Record a (first-call) compile and capture the call's abstract
-        shapes for lazy analysis. jit retraces for later shape buckets
-        silently, so the analysis describes the first bucket — scan
-        padding keeps buckets stable within a query, and the numbers
-        are per-invocation estimates, not an audit."""
+    def note_compile(self, seconds: float) -> None:
+        """One XLA compile of this record's program, as JAX's own event
+        reported it (the compile listener below): first calls, retraces
+        for later shape buckets and persistent-cache loads alike."""
         with self._lock:
             self.compiles += 1
             self.compile_seconds += seconds
+
+    def capture_avals(self, fn, args) -> None:
+        """Keep the first call's abstract shapes for lazy analysis. The
+        analysis describes that first bucket — scan padding keeps
+        buckets stable within a query, and the numbers are
+        per-invocation estimates, not an audit."""
         if self._avals is None:
             try:
                 import jax
@@ -195,16 +202,17 @@ class ExecutableRegistry:
         self._records: Dict[Tuple[str, str], ExecutableRecord] = {}
         self._max = max_records
         self._lock = threading.Lock()
-        #: >0: time every Nth invocation of each entry even without a
-        #: profile context (always-on sampling; 0 = off, the default —
-        #: plain queries must pay nothing)
-        self.sample_every = 0
 
-    def register(self, name: str, static_key=()) -> ExecutableRecord:
+    def register(self, name: str, static_key=(),
+                 program: Optional[str] = None) -> ExecutableRecord:
+        """The record of ``(name, static_key)``; ``program`` is the name
+        its function was jitted under (``op_lookup_join``), by which
+        the compile listener knows the record's compiles."""
         # identity keys on the FULL repr — two fused chains sharing a
         # long prefix must stay distinct records; only the displayed
         # static_key column is truncated
-        key_repr = repr(static_key)
+        key_repr = (static_key if isinstance(static_key, str)
+                    else repr(static_key))
         k = (name, key_repr)
         rec = self._records.get(k)
         if rec is None:
@@ -218,6 +226,8 @@ class ExecutableRegistry:
                     rec = ExecutableRecord(name, shown)
                     rec._key_repr = key_repr
                     self._records[k] = rec
+        if program is not None:
+            rec.fun_name = f"jit({program})"
         return rec
 
     def _evict_one_locked(self) -> None:
@@ -314,31 +324,13 @@ def current_operator():
     return _OP.get()
 
 
-def should_profile_call(record: ExecutableRecord) -> bool:
-    """Hot-path gate: profile context active, or the always-on sampler
-    elected this invocation."""
-    if _ACTIVE.get() is not None:
-        return True
-    se = EXECUTABLES.sample_every
-    return bool(se) and record.invocations % se == 0
-
-
 def profiled_call(record: ExecutableRecord, fn, args):
-    """One bracketed dispatch: run, block until the device finishes,
-    charge the interval to the executable and to the operator whose
-    frame made the call. Under a profile context every call is
-    bracketed, so no queued async work can leak into the interval. In
-    sampling mode (``sample_every``) the neighbouring calls are NOT
-    bracketed, so drain the sampled call's input producers first —
-    otherwise the whole queued pipeline would be billed to this one
-    executable. (Unrelated queued kernels can still overlap; sampled
-    numbers are estimates, not an audit.)"""
+    """One bracketed dispatch under a profile context: run, block until
+    the device finishes, charge the interval (a host bracket around a
+    blocked call, not a device clock) to the executable and to the
+    operator whose frame made the call. Every call under the context
+    is bracketed, so no queued async work can leak into the interval."""
     import jax
-    if _ACTIVE.get() is None:
-        try:
-            jax.block_until_ready(args)
-        except Exception:
-            pass
     t0 = time.perf_counter()
     out = fn(*args)
     jax.block_until_ready(out)
@@ -433,81 +425,52 @@ def hbm_totals(devices=None, registry=None) -> Dict[str, int]:
     }
 
 
-# -- device-trace merging (--profile-out) -------------------------------------
+# -- the compile listener -----------------------------------------------------
 
-def find_device_traces(profile_dir: str) -> List[str]:
-    """Chrome-trace files from the NEWEST profiling session under a
-    profile dir (``plugins/profile/<ts>/*.trace.json[.gz]``).
-    ``jax.profiler`` leaves one ``<ts>`` subdir per ``start_trace``, so
-    a reused ``--profile-out`` DIR accumulates sessions — merging any
-    but the latest would interleave a past run's kernels (with that
-    run's absolute timestamps) onto the current host timeline."""
-    pats = [os.path.join(profile_dir, "plugins", "profile", "*",
-                         "*.trace.json.gz"),
-            os.path.join(profile_dir, "plugins", "profile", "*",
-                         "*.trace.json")]
-    found: List[str] = []
-    for p in pats:
-        found.extend(glob.glob(p))
-    if not found:
-        return []
-    found.sort(key=lambda p: os.path.getmtime(p), reverse=True)
-    newest_session = os.path.dirname(found[0])
-    return [p for p in found if os.path.dirname(p) == newest_session]
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_XLA_COMPILES = REGISTRY.counter("xla_compile_total")
+_XLA_COMPILE_S = REGISTRY.counter("xla_compile_seconds_total")
+_JIT_COMPILES = REGISTRY.counter("jit_compile_total")
+_JIT_COMPILE_S = REGISTRY.counter("jit_compile_seconds_total")
+#: fixed-bucket histogram alongside the counter: compile-time p95
+#: becomes visible on /v1/metrics (jit_compile_seconds_bucket/_sum/
+#: _count) while the long-standing _total sum keeps old scrapes working
+_JIT_COMPILE_HIST = REGISTRY.histogram("jit_compile_seconds")
+
+#: ``.record``: the ExecutableRecord whose program this thread launched
+#: last. JAX compiles inside the launching call, on its thread, so a
+#: compile event whose ``fun_name`` is that record's is that record's
+#: compile; any other name is an eager op (a ``jnp`` call outside every
+#: jit, or one made while a program traces) and is charged to no record.
+DISPATCHING = threading.local()
 
 
-def load_trace_events(path: str) -> List[Dict]:
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as f:
-        doc = json.load(f)
-    if isinstance(doc, dict):
-        return list(doc.get("traceEvents") or [])
-    return list(doc or [])
+def note_launch(rec: ExecutableRecord) -> None:
+    """The ledger's part of every launch of a record's program (a
+    jit-cache entry's or an expression program's)."""
+    if rec.evicted:
+        EXECUTABLES.readmit(rec)
+    rec.note_invocation()
+    DISPATCHING.record = rec
 
 
-def merge_chrome_traces(host: Dict, device_events: List[Dict]) -> Dict:
-    """One Chrome-trace object holding the span tracer's host events AND
-    the XLA profiler's device tracks. Device pids are remapped above the
-    host range so Perfetto shows them as separate processes instead of
-    colliding lanes."""
-    events = list(host.get("traceEvents") or [])
-    base = max([int(e.get("pid", 0)) for e in events] + [0]) + 1000
-    remap: Dict[int, int] = {}
-    for e in device_events:
-        e = dict(e)
-        pid = e.get("pid")
-        if isinstance(pid, int):
-            if pid not in remap:
-                remap[pid] = base + len(remap)
-            e["pid"] = remap[pid]
-        e.setdefault("cat", "device")
-        events.append(e)
-    out = dict(host)
-    out["traceEvents"] = events
-    return out
+def _on_compile(event: str, duration: float, **kw) -> None:
+    if event != COMPILE_EVENT:
+        return
+    _XLA_COMPILES.inc()
+    _XLA_COMPILE_S.inc(duration)
+    fun_name = kw.get("fun_name")
+    rec = getattr(DISPATCHING, "record", None)
+    if rec is not None and rec.fun_name == fun_name:
+        rec.note_compile(duration)
+        _JIT_COMPILES.inc()
+        _JIT_COMPILE_S.inc(duration)
+        _JIT_COMPILE_HIST.observe(duration)
+    if TRACER.enabled:
+        end = time.perf_counter()
+        TRACER.record_span("compile", end - duration, end,
+                           program=fun_name, seconds=round(duration, 6))
 
 
-def write_merged_trace(path: str, spans: List[Dict],
-                       profile_dir: str) -> str:
-    """Merge the span tracer's export with whatever device trace(s)
-    ``jax.profiler`` wrote under ``profile_dir`` and write one
-    Perfetto-loadable JSON file. Missing/unreadable device traces
-    degrade to a host-only trace — the file always lands. Mesh-path
-    queries additionally contribute a "mesh rounds" track (one lane
-    per attribution bucket) from the flight recorder, timestamped on
-    the same epoch-anchored clock as the host spans."""
-    from .flight import FLIGHTS, chrome_events
-    from .trace import chrome_trace
-    host = chrome_trace(spans)
-    device_events: List[Dict] = []
-    for p in find_device_traces(profile_dir):
-        try:
-            device_events.extend(load_trace_events(p))
-        except Exception:
-            continue
-    for fl in FLIGHTS.snapshot():
-        device_events.extend(chrome_events(fl))
-    merged = merge_chrome_traces(host, device_events)
-    with open(path, "w") as f:
-        json.dump(merged, f)
-    return path
+jax.monitoring.register_event_duration_secs_listener(_on_compile)

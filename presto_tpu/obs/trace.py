@@ -13,6 +13,12 @@ wire by trace id.
 Disabled (the default) the tracer must be invisible on hot paths:
 ``span()`` returns one shared no-op object and takes no lock; callers
 wrapping per-batch work may additionally guard with ``TRACER.enabled``.
+Enabled, a span entered with ``with`` also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so that whenever a
+``jax.profiler`` session runs the engine's spans stand in the
+``.xplane.pb`` host plane on the device trace's clock (nothing is
+constructed while the tracer is off, and an annotation outside a
+profiler session is a flag test).
 Finished spans land in a bounded ring; ``export()`` snapshots them and
 ``chrome_trace()`` renders the Chrome ``chrome://tracing`` / Perfetto
 JSON format (one "X" complete event per span, processes keyed by node,
@@ -20,6 +26,7 @@ threads keyed by task/query).
 """
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import itertools
 import json
@@ -29,6 +36,8 @@ import time
 import uuid
 from collections import deque
 from typing import Dict, Iterator, List, Optional
+
+from .metrics import REGISTRY
 
 #: the active span for the current thread/context (parent of new spans)
 _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
@@ -45,12 +54,29 @@ def _now() -> float:
     return _EPOCH_WALL + (time.perf_counter() - _EPOCH_PERF)
 
 
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str, attrs: Dict):
+    """The profiler-side twin of a span: a ``TraceAnnotation`` named
+    like it, its attributes as arguments. Only ever built while the
+    tracer is on (so this module imports no JAX until then)."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    # a TraceMe encodes its arguments as "#k=v,k=v#"
+    return _TRACE_ANNOTATION(
+        name, **{k: str(v).replace(",", ";").replace("#", "")
+                 for k, v in attrs.items()})
+
+
 class Span:
     """One finished-or-running interval. Mutable while open; after
     ``end`` is set it is only read."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "start",
-                 "end", "node", "attrs", "_tracer", "_token")
+                 "end", "node", "attrs", "_tracer", "_token", "_mark")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_id: Optional[str], attrs: Dict):
@@ -64,13 +90,19 @@ class Span:
         self.start = _now()
         self.end: Optional[float] = None
         self._token = None
+        self._mark = None
 
     # -- context-manager protocol --------------------------------------------
     def __enter__(self) -> "Span":
         self._token = _CURRENT.set(self)
+        self._mark = _annotation(self.name, self.attrs)
+        self._mark.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+            self._mark = None
         if self._token is not None:
             _CURRENT.reset(self._token)
             self._token = None
@@ -158,6 +190,18 @@ class Tracer:
             return Span(self, name, parent.trace_id, parent.span_id, attrs)
         return Span(self, name, uuid.uuid4().hex[:16], None, attrs)
 
+    def record_span(self, name: str, start: float, end: float,
+                    **attrs) -> None:
+        """A finished span of the current context from an interval
+        measured elsewhere (``time.perf_counter`` readings): JAX reports
+        a compile when it ends, with its duration."""
+        if not self.enabled:
+            return
+        span = self.span(name, **attrs)
+        span.start = _EPOCH_WALL + (start - _EPOCH_PERF)
+        span.end = _EPOCH_WALL + (end - _EPOCH_PERF)
+        self._record(span)
+
     def task_span(self, ctx: Optional[Dict], name: str, **attrs):
         """Span re-parented from a wire-carried context (a worker task
         resuming a coordinator trace). ``ctx`` is whatever ``context()``
@@ -184,7 +228,10 @@ class Tracer:
         """Span covering an iterator's lifetime (first ``next`` to
         exhaustion) — operator spans over streaming plan nodes. The
         parent is captured at call time, matching the plan structure
-        rather than whichever operator happens to be draining."""
+        rather than whichever operator happens to be draining. Each
+        PULL is the current span while it runs (what it launches and
+        waits for nests under the operator) and one profiler
+        annotation: a ``TraceMe`` does not survive a ``yield``."""
         if not self.enabled:
             return it
         parent = _CURRENT.get()
@@ -195,8 +242,17 @@ class Tracer:
         def gen():
             span = Span(self, name, trace_id, parent_id, attrs)
             batches = 0
+            done = object()
             try:
-                for item in it:
+                while True:
+                    token = _CURRENT.set(span)
+                    try:
+                        with _annotation(name, attrs):
+                            item = next(it, done)
+                    finally:
+                        _CURRENT.reset(token)
+                    if item is done:
+                        return
                     batches += 1
                     yield item
             finally:
@@ -232,6 +288,24 @@ class Tracer:
 
 #: the process-wide tracer
 TRACER = Tracer()
+
+_SYNCS = REGISTRY.counter("device_sync_total")
+_SYNC_SECONDS = REGISTRY.counter("device_sync_seconds_total")
+
+
+@contextlib.contextmanager
+def device_sync(what: str, **attrs):
+    """Bracket one place where the host reads a device value: always
+    counted (``device_sync_total``, and the host seconds blocked in
+    ``device_sync_seconds_total``: one ``perf_counter`` pair), and a
+    ``device-sync`` span while the tracer is on."""
+    t0 = time.perf_counter()
+    try:
+        with TRACER.span("device-sync", what=what, **attrs) as span:
+            yield span
+    finally:
+        _SYNCS.inc()
+        _SYNC_SECONDS.inc(time.perf_counter() - t0)
 
 
 def current_span_ids() -> Dict:

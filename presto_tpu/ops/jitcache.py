@@ -1,4 +1,4 @@
-"""Cached jax.jit entry points for the relational kernels.
+"""Cached jit entry points for the relational kernels.
 
 The analogue of the reference's compiled-operator caches (reference
 sql/gen/PageFunctionCompiler.java:121-136 caches generated classes per
@@ -15,8 +15,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 import threading
-import time
 import types as _pytypes
 from typing import Optional, Sequence
 
@@ -30,12 +30,37 @@ from .aggregation import AggSpec, global_aggregate, grouped_aggregate
 
 _JIT_HITS = REGISTRY.counter("jit_cache_hits_total")
 _JIT_MISSES = REGISTRY.counter("jit_cache_misses_total")
-_JIT_COMPILES = REGISTRY.counter("jit_compile_total")
-_JIT_COMPILE_S = REGISTRY.counter("jit_compile_seconds_total")
-#: fixed-bucket histogram alongside the counter: compile-time p95
-#: becomes visible on /v1/metrics (jit_compile_seconds_bucket/_sum/
-#: _count) while the long-standing _total sum keeps old scrapes working
-_JIT_COMPILE_HIST = REGISTRY.histogram("jit_compile_seconds")
+
+#: what a program of the engine may be called: one of three prefixes
+#: (``op_`` a jit-cache entry, ``expr_`` an expression program,
+#: ``smap_`` a mesh program), then ``[a-z0-9_]``. XLA names the module
+#: ``jit_<name>``, and so do the profiler's trace, JAX's compile event
+#: and the persistent compile cache's key: the name must be the same in
+#: every process and for every checkout. Whatever the device trace shows
+#: under another name (``jit_scatter-add``, ``jit__take``) is by that
+#: fact an eager op outside every jit of the engine.
+_PROGRAM_NAME = re.compile(r"(op|expr|smap)_[a-z0-9_]+")
+
+
+def program_name(prefix: str, label: str) -> str:
+    """``<prefix>_<label>`` with the label folded to ``[a-z0-9_]``."""
+    return f"{prefix}_" + re.sub(r"[^a-z0-9]+", "_", label.lower()).strip("_")
+
+
+def named_jit(name: str, fn, **jit_kw):
+    """``jax.jit`` of ``fn`` under the program name ``name``: the one
+    place the engine jits, so that no program reaches XLA as ``jit_run``
+    or ``jit__lambda_``. ``fn`` takes positional arguments only (the
+    wrapper that carries the name passes nothing else on) and is left
+    as it was."""
+    if not _PROGRAM_NAME.fullmatch(name):
+        raise ValueError(f"program name {name!r}: want op_/expr_/smap_ "
+                         f"then [a-z0-9_]")
+
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **jit_kw)
 
 
 #: sentinel: a closure captured something we cannot prove is
@@ -136,28 +161,32 @@ def program_signature(fn) -> Optional[object]:
 
 
 class _TimedEntry:
-    """Jitted callable whose FIRST invocation is timed as a compile
-    (jax.jit compiles lazily on first call; later shape buckets retrace
-    silently — this records the dominant first-trace cost without
-    touching every dispatch). Every entry owns an ExecutableRecord in
-    ``obs.profiler.EXECUTABLES``; under a profile context each dispatch
-    is additionally bracketed with block_until_ready and attributed to
-    the operator whose frame made the call."""
+    """Jitted callable with a ledger: every entry owns an
+    ExecutableRecord in ``obs.profiler.EXECUTABLES`` (invocations here;
+    compiles and compile seconds from the profiler's compile listener,
+    which knows the record by the program this thread launched last).
+    While the tracer is on each launch is a ``dispatch`` span; under a
+    profile context it is additionally bracketed with
+    block_until_ready and attributed to the operator whose frame made
+    the call. Build one with :func:`timed_entry`, which names the
+    program after the entry."""
 
-    __slots__ = ("name", "fn", "first", "_lock", "record", "donate")
+    __slots__ = ("name", "fn", "program", "first", "record", "donate")
 
     def __init__(self, name: str, fn, key=(), donate=()):
         self.name = name
         self.fn = fn
+        fun = getattr(fn, "__name__", name)
+        #: the XLA module's name, as the device trace shows it
+        self.program = "jit_" + re.sub(r"[^A-Za-z0-9_]", "_", fun)
         self.first = True
-        self._lock = threading.Lock()
         #: argument positions this executable DONATES (built with
         #: ``jax.jit(donate_argnums=...)``): callers must treat those
         #: inputs as consumed — the round-carried shard buffers of the
         #: fused exchange loops alias their outputs instead of churning
         #: HBM, and the donated arrays are deleted on dispatch
         self.donate = tuple(donate)
-        self.record = _prof.EXECUTABLES.register(name, key)
+        self.record = _prof.EXECUTABLES.register(name, key, program=fun)
 
     def __call__(self, *args):
         if _lockcheck.ENABLED:
@@ -165,36 +194,40 @@ class _TimedEntry:
             # every other query behind this one's kernels — the runtime
             # lock validator fails the suite on it
             _lockcheck.note_dispatch(self.name)
-        rec = self.record
-        if rec.evicted:
-            _prof.EXECUTABLES.readmit(rec)
-        rec.note_invocation()
+        _prof.note_launch(self.record)
         _prof.INVOCATIONS.inc()
         if self.first:
-            # one-shot flip under a lock: concurrent first calls (a
-            # fixed stage starts every task at once) must count ONE
-            # compile, not N
-            with self._lock:
-                timed, self.first = self.first, False
-            if timed:
-                t0 = time.perf_counter()
-                with TRACER.span(f"jit-compile:{self.name}"):
-                    out = self.fn(*args)
-                dt = time.perf_counter() - t0
-                _JIT_COMPILES.inc()
-                _JIT_COMPILE_S.inc(dt)
-                _JIT_COMPILE_HIST.observe(dt)
-                rec.note_compile(dt, self.fn, args)
-                return out
-        if _prof.should_profile_call(rec):
-            return _prof.profiled_call(rec, self.fn, args)
+            self.first = False
+            self.record.capture_avals(self.fn, args)
+        if TRACER.enabled:
+            with TRACER.span("dispatch", program=self.program):
+                return self._launch(args)
+        return self._launch(args)
+
+    def _launch(self, args):
+        if _prof.profiling_active():
+            return _prof.profiled_call(self.record, self.fn, args)
         return self.fn(*args)
+
+
+def timed_entry(name: str, fn, key=(), donate=(), program=None,
+                **jit_kw) -> _TimedEntry:
+    """The :class:`_TimedEntry` of the plain function ``fn``, jitted
+    under the program name ``op_<name>`` (or ``program``, for the mesh
+    programs' ``smap_`` names): the record's name and XLA's are given
+    in one place and cannot drift."""
+    if donate:
+        jit_kw["donate_argnums"] = tuple(donate)
+    return _TimedEntry(
+        name, named_jit(program or program_name("op", name), fn, **jit_kw),
+        key, donate)
 
 
 def _entry_cache(name: str, factory):
     """lru_cache replacement for the jit entry points: per-(static-args)
-    memo plus cache-hit/miss counters and compile spans — the metrics
-    feed the reference exposes from PageFunctionCompiler's cache stats."""
+    memo plus cache-hit/miss counters — the metrics feed the reference
+    exposes from PageFunctionCompiler's cache stats. ``factory`` returns
+    the plain function; it is jitted here, as ``op_<name>``."""
     cache = {}
     lock = threading.Lock()
 
@@ -205,7 +238,7 @@ def _entry_cache(name: str, factory):
                 fn = cache.get(key)
                 if fn is None:
                     _JIT_MISSES.inc()
-                    fn = cache[key] = _TimedEntry(name, factory(*key),
+                    fn = cache[key] = timed_entry(name, factory(*key),
                                                   key)
                     return fn
         _JIT_HITS.inc()
@@ -219,7 +252,7 @@ def _grouped_factory(group_indices, aggs, mode, output_capacity,
         return grouped_aggregate(batch, group_indices, aggs, mode,
                                  output_capacity, allow_dense=allow_dense,
                                  key_bounds=key_bounds)
-    return jax.jit(run)
+    return run
 
 
 _grouped = _entry_cache("grouped_aggregate", _grouped_factory)
@@ -252,7 +285,7 @@ def _bounds_violation_factory(group_indices, key_bounds):
             bad = bad | jnp.any(out)
         return jnp.where(bad, jnp.int32(STATS_BOUND_VIOLATION),
                          jnp.int32(0))
-    return jax.jit(run)
+    return run
 
 
 _bounds_violation = _entry_cache("key_bounds_violation",
@@ -273,7 +306,7 @@ def key_bounds_violation_jit(batch, group_indices, key_bounds):
 def _global_factory(aggs, mode):
     def run(batch):
         return global_aggregate(batch, aggs, mode)
-    return jax.jit(run)
+    return run
 
 
 _global = _entry_cache("global_aggregate", _global_factory)
@@ -297,7 +330,7 @@ from .join import (  # noqa: E402
 
 _prepare = _entry_cache(
     "prepare_build",
-    lambda key_cols: jax.jit(lambda b: prepare_build(b, key_cols)))
+    lambda key_cols: lambda b: prepare_build(b, key_cols))
 
 
 def prepare_build_jit(build, key_cols):
@@ -306,7 +339,7 @@ def prepare_build_jit(build, key_cols):
 
 _lookup = _entry_cache(
     "lookup_join",
-    lambda pkeys, bkeys, payload, names, jt: jax.jit(
+    lambda pkeys, bkeys, payload, names, jt: (
         lambda p, b, prep: lookup_join(
             p, b, pkeys, bkeys, payload, names, jt, prepared=prep)))
 
@@ -319,7 +352,7 @@ def lookup_join_jit(probe, build, probe_keys, build_keys, payload,
 
 _expand = _entry_cache(
     "expand_join",
-    lambda pkeys, bkeys, payload, names, jt, max_matches: jax.jit(
+    lambda pkeys, bkeys, payload, names, jt, max_matches: (
         lambda p, b, prep: expand_join(
             p, b, pkeys, bkeys, payload, names, jt, max_matches,
             prepared=prep)))
@@ -334,8 +367,8 @@ def expand_join_jit(probe, build, probe_keys, build_keys, payload,
 
 _match_count = _entry_cache(
     "match_count_max",
-    lambda pkeys, bkeys: jax.jit(lambda p, b, prep: match_count_max(
-        p, b, pkeys, bkeys, prepared=prep)))
+    lambda pkeys, bkeys: lambda p, b, prep: match_count_max(
+        p, b, pkeys, bkeys, prepared=prep))
 
 
 def match_count_max_jit(probe, build, probe_keys, build_keys, prepared):
@@ -349,14 +382,13 @@ from .join import max_multiplicity  # noqa: E402
 #: build, replacing the per-probe-batch match_count_max syncs for
 #: non-skewed builds (jit retraces per prepared-pytree structure, so one
 #: wrapper covers both the direct and sorted layouts)
-max_multiplicity_jit = _TimedEntry("max_multiplicity",
-                                   jax.jit(max_multiplicity))
+max_multiplicity_jit = timed_entry("max_multiplicity", max_multiplicity)
 
 
 _match_mask = _entry_cache(
     "build_match_mask",
-    lambda pkeys, bkeys: jax.jit(lambda p, b, prep: build_match_mask(
-        p, b, pkeys, bkeys, prepared=prep)))
+    lambda pkeys, bkeys: lambda p, b, prep: build_match_mask(
+        p, b, pkeys, bkeys, prepared=prep))
 
 
 def build_match_mask_jit(probe, build, probe_keys, build_keys, prepared):
@@ -366,8 +398,8 @@ def build_match_mask_jit(probe, build, probe_keys, build_keys, prepared):
 
 _key_ranks = _entry_cache(
     "build_key_ranks",
-    lambda key_cols: jax.jit(lambda b, prep: build_key_ranks(
-        b, key_cols, prepared=prep)))
+    lambda key_cols: lambda b, prep: build_key_ranks(
+        b, key_cols, prepared=prep))
 
 
 def build_key_ranks_jit(build, key_cols, prepared):
@@ -376,7 +408,7 @@ def build_key_ranks_jit(build, key_cols, prepared):
 
 _semi = _entry_cache(
     "semi_join_mask",
-    lambda skeys, fkeys, negated, null_aware: jax.jit(
+    lambda skeys, fkeys, negated, null_aware: (
         lambda p, b, prep: semi_join_mask(
             p, b, skeys, fkeys, negated, null_aware, prepared=prep)))
 
@@ -389,7 +421,7 @@ def semi_join_mask_jit(probe, build, probe_keys, build_keys,
 
 _compact = _entry_cache(
     "compact",
-    lambda capacity: jax.jit(lambda b: b.compact(capacity, check=False)))
+    lambda capacity: lambda b: b.compact(capacity, check=False))
 
 
 def compact_jit(batch, capacity: int):
@@ -400,7 +432,7 @@ def compact_jit(batch, capacity: int):
 
 _pad = _entry_cache(
     "pad_capacity",
-    lambda capacity: jax.jit(lambda b: b.pad(capacity)))
+    lambda capacity: lambda b: b.pad(capacity))
 
 
 def pad_capacity_jit(batch, capacity: int):
@@ -416,7 +448,7 @@ from .join import prepare_direct  # noqa: E402
 
 _prepare_direct = _entry_cache(
     "prepare_direct",
-    lambda key_cols, size: jax.jit(
+    lambda key_cols, size: (
         lambda b, lo0: prepare_direct(b, key_cols, lo0, size)))
 
 
@@ -429,7 +461,7 @@ from .join import prepare_direct_keyed  # noqa: E402
 
 _prepare_direct_keyed = _entry_cache(
     "prepare_direct_keyed",
-    lambda key_cols, los, sizes, size: jax.jit(
+    lambda key_cols, los, sizes, size: (
         lambda b: prepare_direct_keyed(b, key_cols, los, sizes, size)))
 
 
@@ -447,7 +479,7 @@ def _lookup_pallas_factory(pkeys, bkeys, payload, names, jt):
     def run(p, b, prep):
         return lookup_join_direct(p, b, pkeys, bkeys, payload, names,
                                   jt, prep)
-    return jax.jit(run)
+    return run
 
 
 _lookup_pallas = _entry_cache("lookup_join_pallas", _lookup_pallas_factory)
@@ -481,7 +513,7 @@ def _build_summary_factory(key_cols, int_flags):
             out.append(jnp.max(jnp.where(ok, data,
                                          jnp.iinfo(jnp.int64).min)))
         return jnp.stack(out)
-    return jax.jit(run)
+    return run
 
 
 _build_summary = _entry_cache("build_summary", _build_summary_factory)
@@ -502,7 +534,7 @@ from .join import expand_match_origins, unique_match_build_mask  # noqa: E402
 
 _unique_match_build = _entry_cache(
     "unique_match_build_mask",
-    lambda pkeys, bkeys: jax.jit(
+    lambda pkeys, bkeys: (
         lambda p, b, s, prep: unique_match_build_mask(
             p, b, pkeys, bkeys, s, prepared=prep)))
 
@@ -515,7 +547,7 @@ def unique_match_build_mask_jit(probe, build, probe_keys, build_keys,
 
 _expand_origins = _entry_cache(
     "expand_match_origins",
-    lambda pkeys, bkeys, k: jax.jit(
+    lambda pkeys, bkeys, k: (
         lambda p, b, prep: expand_match_origins(
             p, b, pkeys, bkeys, k, prepared=prep)))
 

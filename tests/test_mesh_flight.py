@@ -29,7 +29,7 @@ from presto_tpu.exec.failpoints import FAILPOINTS
 from presto_tpu.exec.runner import LocalRunner
 from presto_tpu.obs import flight
 from presto_tpu.obs.flight import (BUCKETS, FLIGHTS, KIND_BUCKET,
-                                   FlightRecorder, chrome_events)
+                                   FlightRecorder)
 from presto_tpu.obs.metrics import REGISTRY
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -354,17 +354,6 @@ def test_buckets_agree_with_mesh_report_tool():
     assert tuple(mesh_report.BUCKETS) == tuple(BUCKETS)
     assert set(mesh_report.BUCKET_BUDGET_PCT) == \
         set(BUCKETS) - {"device_compute"}
-
-
-def test_chrome_trace_track(tpch):
-    _, fl = _fly(tpch, Q1, 2, warm=False)
-    events = chrome_events(fl)
-    names = [e for e in events if e["ph"] == "M"]
-    slices = [e for e in events if e["ph"] == "X"]
-    # one named thread per bucket + the process name
-    assert len(names) == len(BUCKETS) + 1
-    assert len(slices) == fl.attribution["rounds"]
-    assert all(e["dur"] > 0 for e in slices)
 
 
 def test_history_fields_shape():

@@ -298,7 +298,7 @@ def test_chrome_trace_schema(tmp_path, tracing):
         with TRACER.span("op:Scan"):
             pass
     path = write_chrome_trace(
-        str(tmp_path / "trace.json"), TRACER.export(q.trace_id))
+        str(tmp_path / "spans.json"), TRACER.export(q.trace_id))
     doc = json.load(open(path))
     assert isinstance(doc["traceEvents"], list)
     xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
@@ -322,7 +322,7 @@ def test_chrome_trace_empty():
 
 def test_cli_trace_out(tmp_path):
     from presto_tpu.cli import main
-    out = tmp_path / "cli_trace.json"
+    out = tmp_path / "cli_spans.json"
     rc = main(["--execute", "select count(*) from nation",
                "--sf", "0.001", "--trace-out", str(out)])
     try:

@@ -5,11 +5,9 @@ analysis, invocation + device-time ledger) through real jit-cache
 entries and the `system.runtime.executables` SQL surface; per-operator
 device-time attribution and the EXPLAIN ANALYZE Executables/Verdict
 sections; HBM gauge sampling with a fake device (XLA:CPU has no
-memory_stats); Chrome-trace merge round-trip with device tracks;
-history-sink rotation; and the bench regression gate's smoke mode
+memory_stats); history-sink rotation; and the bench regression gate's smoke mode
 (tier-1 keeps the gate itself from rotting).
 """
-import gzip
 import json
 import os
 import subprocess
@@ -22,8 +20,8 @@ from presto_tpu.exec.runner import LocalRunner
 from presto_tpu.obs import profiler
 from presto_tpu.obs.metrics import REGISTRY, MetricsRegistry
 from presto_tpu.obs.profiler import (
-    EXECUTABLES, cost_verdict, hbm_totals, merge_chrome_traces,
-    operator_scope, profiled, sample_hbm, write_merged_trace,
+    EXECUTABLES, cost_verdict, hbm_totals, operator_scope, profiled,
+    sample_hbm,
 )
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -260,61 +258,6 @@ def test_nodes_table_has_hbm_columns(runner):
         assert in_use >= 0 and peak >= 0   # CPU backend: zeros
 
 
-# -- Chrome-trace merge (--profile-out) ---------------------------------------
-
-def test_merge_device_trace_roundtrip(tmp_path):
-    from presto_tpu.obs.trace import Tracer
-    t = Tracer(node="merge-test")
-    t.enable(True)
-    with t.span("query", query_id="q1"):
-        with t.span("op:Join"):
-            pass
-    # a fake jax.profiler output tree with a gzipped Chrome trace
-    sess = tmp_path / "plugins" / "profile" / "2026_08_03_00_00_00"
-    sess.mkdir(parents=True)
-    device_events = [
-        {"ph": "M", "name": "process_name", "pid": 1, "tid": 0,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "X", "name": "fusion.123", "pid": 1, "tid": 1,
-         "ts": 100.0, "dur": 42.0, "cat": "kernel"},
-    ]
-    with gzip.open(sess / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": device_events}, f)
-
-    out = tmp_path / "merged_trace.json"
-    write_merged_trace(str(out), t.export(), str(tmp_path))
-    with open(out) as f:
-        merged = json.load(f)
-    names = [e.get("name") for e in merged["traceEvents"]]
-    assert "op:Join" in names and "query" in names     # host spans
-    assert "fusion.123" in names                       # device track
-    host_pids = {e["pid"] for e in merged["traceEvents"]
-                 if e.get("name") in ("op:Join", "query")}
-    dev_pids = {e["pid"] for e in merged["traceEvents"]
-                if e.get("name") == "fusion.123"}
-    assert host_pids.isdisjoint(dev_pids)   # remapped, no collision
-
-
-def test_merge_ignores_stale_profile_sessions(tmp_path):
-    """A reused --profile-out DIR accumulates one plugins/profile/<ts>
-    subdir per run; only the NEWEST session's kernels may be merged."""
-    for i, (ts, name) in enumerate((("2026_08_03_00_00_00", "old.kern"),
-                                    ("2026_08_03_01_00_00", "new.kern"))):
-        sess = tmp_path / "plugins" / "profile" / ts
-        sess.mkdir(parents=True)
-        p = sess / "host.trace.json"
-        with open(p, "w") as f:
-            json.dump({"traceEvents": [
-                {"ph": "X", "name": name, "pid": 1, "tid": 1,
-                 "ts": 0.0, "dur": 1.0}]}, f)
-        os.utime(p, (1000.0 + i, 1000.0 + i))
-    out = tmp_path / "merged.json"
-    write_merged_trace(str(out), [], str(tmp_path))
-    with open(out) as f:
-        names = [e.get("name") for e in json.load(f)["traceEvents"]]
-    assert "new.kern" in names and "old.kern" not in names
-
-
 def test_registry_evicts_coldest_and_readmits():
     """The cap drops the least-invoked record, and a dropped record's
     live _TimedEntry readmits it on the next dispatch — hot kernels can
@@ -336,30 +279,6 @@ def test_registry_evicts_coldest_and_readmits():
     assert "hot" in rows
     reg.reset()                              # reset keeps the contract
     assert cold.evicted and hot.evicted
-
-
-def test_merge_survives_missing_device_trace(tmp_path):
-    # mesh flights from earlier suites would legitimately add their
-    # "mesh rounds" track to the merge — drain the process-global log
-    # so the missing-device-trace contract is what's measured
-    from presto_tpu.obs.flight import FLIGHTS
-    FLIGHTS.clear()
-    out = tmp_path / "merged.json"
-    write_merged_trace(str(out), [], str(tmp_path / "nowhere"))
-    with open(out) as f:
-        assert json.load(f)["traceEvents"] == []
-
-
-def test_merge_chrome_traces_pure():
-    host = {"traceEvents": [{"ph": "X", "name": "h", "pid": 1,
-                             "tid": 1, "ts": 0, "dur": 1}],
-            "displayTimeUnit": "ms"}
-    merged = merge_chrome_traces(host, [
-        {"ph": "X", "name": "d", "pid": 1, "tid": 1, "ts": 0, "dur": 1}])
-    assert len(merged["traceEvents"]) == 2
-    pids = [e["pid"] for e in merged["traceEvents"]]
-    assert len(set(pids)) == 2
-    assert merged["displayTimeUnit"] == "ms"
 
 
 # -- jit compile histogram (satellite) ----------------------------------------

@@ -107,3 +107,30 @@ def test_direct_probe_is_refused_for_v5e_and_off_by_default(one_chip):
     with pytest.raises(NotImplementedError, match="gather"):
         _compile(_probe, *_probe_shapes(one_chip))
     assert SESSION_PROPERTIES["join_pallas_probe"].default is False
+
+
+def test_named_mesh_program_compiles_for_four_v5e_chips(topo):
+    """A ``shard_map`` program built the way ``exec/distributed.py``
+    builds its own (``ops/jitcache.named_jit`` around the mapped
+    function) compiles for the 2x2 host with a cross-chip reduction in
+    it, under the name the device trace will show."""
+    import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from presto_tpu.ops.jitcache import named_jit
+    mesh = Mesh(np.array(topo.devices), ("shards",))
+
+    def partial_then_exchange(price, discount):
+        local = jnp.sum(price * (1.0 - discount), keepdims=True)
+        return jax.lax.psum(local, "shards")
+
+    fn = named_jit("smap_agg_probe", shard_map(
+        partial_then_exchange, mesh=mesh, in_specs=(P("shards"),) * 2,
+        out_specs=P(), check_vma=False))
+    rows = jax.ShapeDtypeStruct((N_BATCH,), jnp.float64,
+                                sharding=NamedSharding(mesh, P("shards")))
+    compiled = fn.lower(rows, rows).compile()
+    text = compiled.as_text()
+    assert "jit_smap_agg_probe" in text
+    assert "all-reduce" in text

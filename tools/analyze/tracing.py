@@ -13,14 +13,17 @@ until the shapes change:
 - ``raw-jit`` — a ``jax.jit``/``pjit`` call site that is not wrapped in
   an ``ops/jitcache._TimedEntry``. Raw entries are invisible to the
   PR 6 profiler (no compile seconds, no device-time attribution, absent
-  from system.runtime.executables) and their recompiles are uncapped
-  and unobservable.
+  from system.runtime.executables), their recompiles are uncapped and
+  unobservable, and XLA, the device trace and the compile cache know
+  them as ``jit_run`` or ``jit__lambda_``. The engine jits through
+  ``ops/jitcache.timed_entry``/``named_jit``, which name the program.
 - ``nondeterminism`` — ``time.*`` / ``random.*`` / ``np.random*``
   calls inside a traced body: they run ONCE at trace time and freeze
   their value into the executable, so "random" is constant per shape
   bucket and replays differ from first runs.
 - ``unbracketed-sync`` — ``jax.device_get`` / ``.block_until_ready``
-  outside a ``TRACER.span("device-sync", ...)`` (or profiler) scope.
+  outside a ``device_sync(...)`` (``obs/trace.py``: the ``device-sync``
+  span and its counters) or profiler scope.
   Async dispatch makes an unbracketed sync a stall nobody can see in
   the trace viewer; the engine's rule since PR 1 is that every
   deliberate device round-trip is a span.
@@ -87,6 +90,14 @@ def _is_jit_call(node: ast.Call) -> bool:
                     "jax.experimental.pjit.pjit")
 
 
+def _is_named_jit_call(node: ast.Call) -> bool:
+    """``named_jit(name, fn)`` / ``timed_entry(name, fn, ...)``
+    (ops/jitcache): the engine's own way to a jitted program. Not a
+    raw jit, but what it is handed IS traced."""
+    name = dotted(node.func) or ""
+    return name.split(".")[-1] in ("named_jit", "timed_entry")
+
+
 def _is_partial_jit(node: ast.Call) -> bool:
     """functools.partial(jax.jit, ...) used as a decorator."""
     name = dotted(node.func)
@@ -147,7 +158,8 @@ def _find_jitted_functions(tree: ast.Module
                 elif isinstance(dec, ast.Call) and (
                         _is_jit_call(dec) or _is_partial_jit(dec)):
                     add(node, _jit_static_names(dec, node))
-        elif isinstance(node, ast.Call) and _is_jit_call(node):
+        elif isinstance(node, ast.Call) and (
+                _is_jit_call(node) or _is_named_jit_call(node)):
             for arg in node.args:
                 if isinstance(arg, ast.Name) and arg.id in defs:
                     fn = defs[arg.id]
@@ -321,12 +333,13 @@ def _inside_timed_entry(node: ast.AST) -> bool:
 
 
 def _inside_sync_span(node: ast.AST) -> bool:
-    """Lexically under ``with TRACER.span("device-sync"|"jit-compile",
-    ...)``, under exec/distributed's ``_sync_record(...)`` (a wrapper
-    that opens that exact span AND feeds the mesh flight recorder's
-    control_sync bucket — the bracketing contract holds by
-    construction), or any ``with`` whose context manager comes from
-    the profiler (obs.profiler brackets its own syncs)."""
+    """Lexically under ``with device_sync(...)`` (or the span it opens,
+    ``TRACER.span("device-sync", ...)``), under exec/distributed's
+    ``_sync_record(...)`` (a wrapper that opens that exact span AND
+    feeds the mesh flight recorder's control_sync bucket — the
+    bracketing contract holds by construction), or any ``with`` whose
+    context manager comes from the profiler (obs.profiler brackets its
+    own syncs)."""
     for anc in ancestors(node):
         if not isinstance(anc, ast.With):
             continue
@@ -335,12 +348,11 @@ def _inside_sync_span(node: ast.AST) -> bool:
             if not isinstance(ctx, ast.Call):
                 continue
             name = dotted(ctx.func) or ""
-            if name.split(".")[-1] == "_sync_record":
+            if name.split(".")[-1] in ("_sync_record", "device_sync"):
                 return True
             if name.endswith(".span") and ctx.args:
                 s = str_const(ctx.args[0])
-                if s and (s.startswith("device-sync")
-                          or s.startswith("jit-compile")):
+                if s and s.startswith("device-sync"):
                     return True
             if "_prof" in name or "profiler" in name:
                 return True
@@ -367,9 +379,9 @@ def _file_findings(path: str, rpath: str,
                 out.append(Finding(
                     CHECKER, "raw-jit", rpath, node.lineno, sym,
                     f"direct {dotted(node.func)} call bypasses "
-                    f"ops/jitcache — wrap in _TimedEntry (or an "
-                    f"_entry_cache) so compiles/invocations/device "
-                    f"time are profiled and recompiles are capped"))
+                    f"ops/jitcache — build it with timed_entry (or an "
+                    f"_entry_cache) so the program has a name and "
+                    f"compiles/invocations/device time are profiled"))
             elif isinstance(node, ast.Attribute) \
                     and dotted(node) == "jax.jit" \
                     and isinstance(getattr(node, "parent", None),
@@ -380,7 +392,7 @@ def _file_findings(path: str, rpath: str,
                 out.append(Finding(
                     CHECKER, "raw-jit", rpath, node.lineno, sym,
                     "bare @jax.jit decorator bypasses ops/jitcache — "
-                    "wrap in _TimedEntry"))
+                    "build it with timed_entry"))
 
     # rule: unbracketed-sync
     for node in ast.walk(tree):
