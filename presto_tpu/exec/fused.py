@@ -1,4 +1,5 @@
-"""Whole-pipeline fusion: one jitted program per join probe pipeline.
+"""Whole-pipeline fusion: one jitted program per join probe pipeline, and
+one per scan batch under a small dense group-by (the aggregation sink).
 
 The reference compiles each operator to bytecode but still moves data
 between operators one Page at a time through the Driver loop (reference
@@ -24,6 +25,27 @@ identical; only materialization boundaries change. The executor decides
 WHAT to fuse (exec/local.py _try_fused_chain) and keeps the generic
 per-operator path for everything else (skewed builds, residual filters,
 outer tails, shared subtrees).
+
+The aggregation sink (``agg_step``) is the join-probe fusion's twin on
+the other end of a pipeline: a grouped aggregation whose grouping takes
+the small dense path folds its input as ``step(state, batch) ->
+(state', err)``, ONE program a scan batch. The step traces the
+filter/project chain directly under the aggregation, the partial
+group-by of the result and the merge of that partial into the running
+state; the per-operator path launches each of these on its own and
+merges every sixteenth partial through ``concat_batches``, which is
+eager ``jnp`` code (some 870 launches a TPC-H Q1 at 58 batches). The
+state has the dense code's slot layout at the fixed capacity
+``bucket_capacity(K + 1)``, so under jit the concat before the merge
+needs no dictionary remap; between launches it travels packed, one
+array a dtype (``StateLayout``), because the host pays a launch by the
+buffer. The executor selects it per operator from
+what it can observe (exec/local.py _agg_step_chain, _agg_step_states):
+a ``single`` or ``partial`` step, no drain or wide-state aggregate,
+string or boolean keys whose ``dense_group_plan`` on the batch at hand
+has ``scatter`` false (K <= 4096), ``task_concurrency`` 1, not the mesh
+executor, no plan-template parameter in the chain, no shared interior
+node. Everything else runs the per-operator path.
 """
 from __future__ import annotations
 
@@ -31,12 +53,15 @@ import dataclasses
 import functools
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..batch import Batch, Column, Schema
+from ..batch import Batch, Column, Schema, bucket_capacity
 from ..expr import ir
 from ..expr.compiler import Val, eval_expr, merge_err
 from .. import types as T  # noqa: F401  (type objects live in stage fields)
+from ..ops.aggregation import dense_group_plan, grouped_aggregate
 from ..ops.join import lookup_join, semi_join_mask
 
 
@@ -205,3 +230,129 @@ def fused_prefilter(stages: Tuple[object, ...],
     from ..ops.jitcache import timed_entry
     return timed_entry("fused_prefilter", run,
                        (stages, pre_keys, semi_keys))
+
+
+def _partial_of(batch: Batch, stages, group, aggs, key_bounds, errs):
+    """The aggregation's input (``stages`` over ``batch``) and its
+    partial group-by: what the filter, projection and partial launches
+    of the per-operator path compute, in one trace."""
+    cur = _apply_stages(batch, stages, (), (), (), errs)
+    return cur, grouped_aggregate(cur, group, aggs, mode="partial",
+                                  key_bounds=key_bounds)
+
+
+def _concat_rows(a: Batch, b: Batch) -> Batch:
+    """Row-wise concat of two traced state batches of ONE layout (the
+    dictionaries are static and equal, so codes mean the same on both
+    sides: the remap ``concat_batches`` does between vocabularies has
+    nothing to do here)."""
+    cols = []
+    for x, y in zip(a.columns, b.columns):
+        if x.dictionary != y.dictionary:
+            raise ValueError("agg_step: state and partial dictionaries "
+                             "differ (the executor flushes first)")
+        cols.append(Column(x.type, jnp.concatenate([x.data, y.data]),
+                           jnp.concatenate([x.validity, y.validity]),
+                           x.dictionary))
+    return Batch(a.schema, cols,
+                 jnp.concatenate([a.row_mask, b.row_mask]))
+
+
+@dataclasses.dataclass(frozen=True)
+class StateLayout:
+    """The running state of the aggregation sink between two launches:
+    the state batch's leaves (every one a ``[capacity]`` array) stacked
+    into ONE 2-D array a dtype. A launch costs the host by the buffer
+    (TPU v5e, PERF.md section 5: 0.19 ms and some 0.036 ms for each
+    array in or out), and a state batch is two arrays a column: Q1's 19
+    columns were 78 of a step's 93 buffers. ``treedef`` is the state
+    batch's pytree structure (schema and dictionaries: its layout);
+    ``groups`` the leaf indices stacked into each array, by dtype."""
+    treedef: object
+    capacity: int
+    groups: Tuple[Tuple[str, Tuple[int, ...]], ...]
+
+    def pack(self, state: Batch):
+        leaves = jax.tree_util.tree_leaves(state)
+        return tuple(jnp.stack([leaves[i] for i in idx])
+                     for _, idx in self.groups)
+
+    def unpack(self, packed) -> Batch:
+        leaves = [None] * self.treedef.num_leaves
+        for arr, (_, idx) in zip(packed, self.groups):
+            for row, i in enumerate(idx):
+                leaves[i] = arr[row]
+        return jax.tree_util.tree_unflatten(self.treedef, leaves)
+
+
+@functools.lru_cache(maxsize=256)
+def agg_step(stages: Tuple[object, ...], group: Tuple[int, ...],
+             aggs: Tuple[object, ...], key_bounds, layout: StateLayout):
+    """jitted fn(state, batch) -> (state', err_or_None): the aggregation
+    sink's program, ``op_agg_step``. ``stages`` are the Filter/Project
+    stages under the aggregation (bottom-up, possibly none); ``state``
+    is a partial-state batch in the dense slot layout, packed by
+    ``layout`` (start from :func:`agg_step_start`'s empty one, end with
+    :func:`agg_step_finish`) and comes back at its own capacity.
+    ``batch`` is a scan batch, which the scan cache keeps: it is never
+    donated. ``err`` merges the stages' row errors and the dense path's
+    key-bounds violation."""
+    from ..ops.jitcache import _bounds_violation_factory, timed_entry
+    key_idx = tuple(range(len(group)))
+    violation = (_bounds_violation_factory(group, key_bounds)
+                 if key_bounds is not None else None)
+
+    def run(packed, batch: Batch):
+        errs = []
+        cur, part = _partial_of(batch, stages, group, aggs, key_bounds,
+                                errs)
+        if violation is not None:
+            errs.append(violation(cur))
+        merged = grouped_aggregate(
+            _concat_rows(layout.unpack(packed), part), key_idx, aggs,
+            mode="merge", output_capacity=layout.capacity,
+            key_bounds=key_bounds)
+        return layout.pack(merged), _merge_errs(errs)
+
+    return timed_entry("agg_step", run,
+                       (stages, group, aggs, key_bounds, layout))
+
+
+@functools.lru_cache(maxsize=256)
+def agg_step_finish(layout: StateLayout):
+    """jitted fn(state) -> Batch: the packed state as the partial-state
+    batch the merge buffer takes (``op_agg_step_finish``; one launch a
+    state, so one a query)."""
+    from ..ops.jitcache import timed_entry
+    return timed_entry("agg_step_finish", layout.unpack, layout)
+
+
+@functools.lru_cache(maxsize=256)
+def agg_step_start(stages: Tuple[object, ...], group: Tuple[int, ...],
+                   aggs: Tuple[object, ...], key_bounds, treedef, avals):
+    """(layout, empty packed state) for scan batches of one signature
+    (``treedef``: schema and dictionaries; ``avals``: each leaf's shape
+    and dtype), or None where the grouping of such a batch does not
+    take the small dense path. Host-only: one abstract trace a
+    signature, kept across queries with the state it gives (all dead
+    rows, never written: the step does not donate it)."""
+    batch = jax.tree_util.tree_unflatten(
+        treedef, [jax.ShapeDtypeStruct(s, d) for s, d in avals])
+    cur, part = jax.eval_shape(
+        lambda b: _partial_of(b, stages, group, aggs, key_bounds, []),
+        batch)
+    plan = dense_group_plan(cur, group, cur.capacity, key_bounds)
+    if plan is None or plan.scatter:
+        return None
+    cap = bucket_capacity(plan.K + 1)
+    leaves, state_def = jax.tree_util.tree_flatten(part)
+    if any(x.ndim != 1 for x in leaves):
+        return None
+    by_dtype = {}
+    for i, x in enumerate(leaves):
+        by_dtype.setdefault(str(x.dtype), []).append(i)
+    layout = StateLayout(state_def, cap, tuple(
+        (d, tuple(idx)) for d, idx in sorted(by_dtype.items())))
+    empty = tuple(jnp.asarray(np.zeros((len(idx), cap), dtype=d))
+                  for d, idx in layout.groups)
+    return layout, empty

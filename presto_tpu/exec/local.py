@@ -16,8 +16,10 @@ ExchangeClient-fed init semantics without a network hop.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from .. import types as T
@@ -42,6 +44,16 @@ from ..obs.trace import TRACER, device_sync
 #: the stats-bounded grouping tests assert on.
 _AGG_DENSE_SELECTED = REGISTRY.counter("agg_dense_path_selected_total")
 _AGG_SORT_SELECTED = REGISTRY.counter("agg_sort_path_selected_total")
+
+#: the aggregation sink (exec/fused.py agg_step), per group-by operator
+#: that computes partials: selected when its first batch folds through
+#: the one-program-a-batch step, declined when it takes the per-operator
+#: path; batches folded; states flushed into the merge buffer because a
+#: batch's dictionaries gave the state another layout
+_AGG_STEP_SELECTED = REGISTRY.counter("agg_step_selected_total")
+_AGG_STEP_DECLINED = REGISTRY.counter("agg_step_declined_total")
+_AGG_STEP_BATCHES = REGISTRY.counter("agg_step_batches_total")
+_AGG_STEP_FLUSHES = REGISTRY.counter("agg_step_flushes_total")
 
 #: fused-chain lane accounting: capacities entering the chain (source)
 #: vs entering the tail's payload gathers (post mask + compaction). The
@@ -379,6 +391,10 @@ class _Executor:
         self._shared: set = set()
         self._ever_shared: set = set()
         self._materialized: Dict[PlanNode, List[Batch]] = {}
+        # node -> its already started iterator, handed to the next
+        # run(node) in place of a second execution (_agg_step_states
+        # pulls a source itself and gives the rest back when it declines)
+        self._resumed: Dict[PlanNode, Iterator[Batch]] = {}
         # runtime (dynamic-filter) scan bounds: scan node -> [(col, lo, hi)]
         self.dynamic_pushdown: Dict[PlanNode, List[Tuple]] = {}
         # grouped (lifespan) execution: scan node -> the split list it
@@ -471,6 +487,8 @@ class _Executor:
 
     # -- dispatch -------------------------------------------------------------
     def run(self, node: PlanNode) -> Iterator[Batch]:
+        if self._resumed and node in self._resumed:
+            return self._resumed.pop(node)
         if node in self._materialized:
             # cache replay: the node's stats already recorded the one real
             # execution — don't re-wrap or double-count
@@ -493,8 +511,6 @@ class _Executor:
         budget: each cached batch reserves from the query pool, and if the
         pool can't hold the next batch the cache is abandoned (repeat
         consumers re-execute instead of OOMing device memory)."""
-        import itertools
-
         from .spill import batch_device_bytes
         ctx = self.pool.context(f"memo-{type(node).__name__}")
         it = m(node)
@@ -787,6 +803,99 @@ class _Executor:
                                      key_bounds=kb, allow_dense=allow)
         return partial
 
+    def _expr_stage(self, nd: PlanNode):
+        """The fused stage (exec/fused.py) of a Filter or Project node,
+        init-plan references resolved."""
+        from .fused import FilterStage, ProjectStage
+        if isinstance(nd, FilterNode):
+            return FilterStage(self._resolve(nd.predicate))
+        return ProjectStage(tuple(self._resolve(e) for e in nd.exprs),
+                            tuple(f.name for f in nd.fields))
+
+    def _agg_step_chain(self, node: AggregationNode, aggs):
+        """What the aggregation sink (exec/fused.py agg_step) would fuse
+        under this grouped aggregation that computes partials (a single
+        or partial step): (stages bottom-up, source node),
+        or None where the operator cannot take it whatever its batches
+        hold. The chain is the Filter/Project nodes directly under the
+        aggregation; the rest of the rule waits for a batch
+        (_agg_step_states)."""
+        from ..ops.aggregation import _wide_state_aggs
+        if (not self.compact_streams or _wide_state_aggs(aggs)
+                or not bool_property(self.session, "dense_grouping", True)
+                or int(self.session.properties.get(
+                    "task_concurrency", 1)) > 1):
+            return None
+        child = _plan_schema(node.child)
+        if not all(child.types[g].is_string or child.types[g] == T.BOOLEAN
+                   for g in node.group_indices):
+            # an integer key groups densely only by its stats bounds,
+            # which is the scatter path: not the sink's
+            return None
+        stages: List[object] = []
+        cur = node.child
+        while isinstance(cur, (FilterNode, ProjectNode)):
+            if cur in self._shared or cur in self._materialized:
+                return None              # memoized: it runs standalone
+            stages.append(self._expr_stage(cur))
+            cur = cur.child
+        from ..expr.params import has_params
+        if has_params(stages):
+            # plan-template parameters: the step traces expressions
+            # inside its own jit with no operand channel for runtime
+            # bindings (the rule of _try_fused_chain)
+            return None
+        return tuple(reversed(stages)), cur
+
+    def _agg_step_states(self, node: AggregationNode, chain, group, aggs,
+                         kb) -> Iterator[Batch]:
+        """Partial states of a grouped aggregation through the sink: the
+        source's batches fold into ONE running state, a launch a batch,
+        and the state comes out at the end (and before a batch whose
+        dictionaries give it another layout). A batch whose grouping
+        does not take the small dense path hands it and the rest of the
+        source back to the per-operator path. The interior Filter/Project
+        nodes never run standalone: EXPLAIN ANALYZE shows their work on
+        the aggregation, as the join fusion does."""
+        from .fused import agg_step, agg_step_finish, agg_step_start
+        stages, source = chain
+        key = (stages, tuple(group), tuple(aggs), kb)
+        it = iter(self.run(source))
+        state = layout = fn = sig = None
+        folded = False
+        for b in it:
+            leaves, treedef = jax.tree_util.tree_flatten(b)
+            bsig = (treedef, tuple((x.shape, x.dtype) for x in leaves))
+            if bsig != sig:
+                start = agg_step_start(*key, *bsig)
+                if start is None:
+                    self._resumed[source] = itertools.chain([b], it)
+                    break
+                sig = bsig
+                if state is not None and start[0] != layout:
+                    _AGG_STEP_FLUSHES.inc()
+                    yield agg_step_finish(layout)(state)
+                    state = None
+                if state is None:
+                    layout, state = start
+                    fn = agg_step(*key, layout)
+            if not folded:
+                folded = True
+                _AGG_STEP_SELECTED.inc()
+                _AGG_DENSE_SELECTED.inc()
+            state, err = fn(state, b)
+            if err is not None:
+                self.error_flags.append(err)
+            _AGG_STEP_BATCHES.inc()
+        if state is not None:
+            yield agg_step_finish(layout)(state)
+        if not folded:
+            _AGG_STEP_DECLINED.inc()     # no batch at all counts here too
+        if source in self._resumed:
+            partial = self._grouped_partial_fn(group, aggs, kb)
+            for b in self.run(node.child):
+                yield partial(b)
+
     def _DistinctNode(self, node: DistinctNode) -> Iterator[Batch]:
         from .spill import AggSpillBuffer
         cols = list(range(len(node.fields)))
@@ -894,9 +1003,15 @@ class _Executor:
         concurrency = int(self.session.properties.get(
             "task_concurrency", 1))
         try:
+            chain = (None if step == "final"
+                     else self._agg_step_chain(node, aggs))
             if step == "final":
                 partials = self.run(node.child)
+            elif chain is not None:
+                partials = self._agg_step_states(node, chain, group, aggs,
+                                                 kb)
             else:
+                _AGG_STEP_DECLINED.inc()
                 partials = parallel_drivers(
                     self.run(node.child),
                     self._grouped_partial_fn(group, aggs, kb),
@@ -1232,19 +1347,17 @@ class _Executor:
         dynamic-filter bound that maps to a raw source column —
         (source index, lo, hi) — for the head program's
         before-any-gathers mask."""
-        from .fused import FilterStage, JoinStage, ProjectStage
+        from .fused import JoinStage
         from .spill import HostPartitionStore, SpillableBuildBuffer
 
         for nd in order:
             if isinstance(nd, FilterNode):
-                stages.append(FilterStage(self._resolve(nd.predicate)))
+                stages.append(self._expr_stage(nd))
                 continue
             if isinstance(nd, ProjectNode):
-                exprs = tuple(self._resolve(e) for e in nd.exprs)
-                stages.append(ProjectStage(
-                    exprs, tuple(f.name for f in nd.fields)))
+                stages.append(self._expr_stage(nd))
                 new_map = {}
-                for out_i, e in enumerate(exprs):
+                for out_i, e in enumerate(stages[-1].exprs):
                     if isinstance(e, ir.InputRef) and e.index in src_map:
                         new_map[out_i] = src_map[e.index]
                 src_map = new_map

@@ -87,6 +87,49 @@ def test_q6_step_compiles_for_v5e(one_chip):
     assert c.memory_analysis() is not None
 
 
+def test_q1_agg_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The aggregation sink's program (``exec/fused.py`` ``agg_step``:
+    TPC-H Q1's filter, projections, partial group-by and merge in one
+    launch) at the SQL path's batch size, with the stages, state and
+    scan-batch layout a real Q1 gives it."""
+    from presto_tpu.exec import fused
+    from presto_tpu.exec import local as local_exec
+    from presto_tpu.exec.runner import LocalRunner
+    seen = {}
+    real = local_exec._Executor._agg_step_states
+
+    def capture(self, node, chain, group, aggs, kb):
+        seen["key"] = (chain[0], tuple(group), tuple(aggs), kb)
+        seen["batch"] = next(iter(self.run(chain[1])))
+        return real(self, node, chain, group, aggs, kb)
+    monkeypatch.setattr(local_exec._Executor, "_agg_step_states", capture)
+    LocalRunner(tpch_sf=0.002).execute("""
+        select l_returnflag, l_linestatus, sum(l_quantity),
+          sum(l_extendedprice), sum(l_extendedprice * (1 - l_discount)),
+          sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)),
+          avg(l_quantity), avg(l_extendedprice), avg(l_discount), count(*)
+        from lineitem where l_shipdate <= date '1998-09-02'
+        group by l_returnflag, l_linestatus""")
+    key, batch = seen["key"], seen["batch"]
+    leaves, treedef = jax.tree_util.tree_flatten(batch)
+    layout, state = fused.agg_step_start(
+        *key, treedef, tuple((x.shape, x.dtype) for x in leaves))
+    assert layout.capacity == 128 and len(state) <= 4
+
+    def on_chip(leaf):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                    sharding=one_chip)
+
+    def widened(leaf):
+        return jax.ShapeDtypeStruct((N_BATCH,) + leaf.shape[1:],
+                                    leaf.dtype, sharding=one_chip)
+    c = fused.agg_step(*key, layout).fn.lower(
+        jax.tree_util.tree_map(on_chip, state),
+        jax.tree_util.tree_map(widened, batch)).compile()
+    assert "jit_op_agg_step" in c.as_text()
+    assert c.memory_analysis() is not None
+
+
 def _probe_shapes(one_chip):
     i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,  # noqa: E731
                                          sharding=one_chip)
