@@ -55,6 +55,7 @@ class MemoryConnector(Connector):
     by however they were inserted)."""
 
     name = "memory"
+    applies_pushdown = False    # page_source drops it
 
     def __init__(self):
         self.tables: Dict[str, List[Batch]] = {}

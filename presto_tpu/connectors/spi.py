@@ -101,6 +101,13 @@ class Connector:
 
     name: str = "connector"
 
+    #: whether ``page_source`` APPLIES the pushdown it is given (prunes
+    #: files, stripes or rows by it), so that two pushdowns may give two
+    #: different streams of one split. A connector that ignores it (the
+    #: generators, the memory tables) says False: its splits are cached
+    #: once for every pushdown (exec/scancache.py), not once a literal.
+    applies_pushdown: bool = True
+
     @property
     def metadata(self) -> ConnectorMetadata:
         raise NotImplementedError

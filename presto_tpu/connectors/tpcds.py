@@ -789,6 +789,7 @@ class _SplitManager(ConnectorSplitManager):
 
 class TpcdsConnector(Connector):
     name = "tpcds"
+    applies_pushdown = False    # page_source drops it
 
     def __init__(self, sf: float = 0.01):
         self.sf = sf

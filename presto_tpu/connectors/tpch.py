@@ -554,14 +554,15 @@ def tpch_schema(table: str) -> Schema:
 
 
 class _Metadata(ConnectorMetadata):
-    def __init__(self, sf: float):
+    def __init__(self, sf: float, tables: Sequence[str]):
         self.sf = sf
+        self.tables = tuple(tables)
 
     def list_tables(self, schema: Optional[str] = None) -> List[str]:
-        return list(TABLES)
+        return list(self.tables)
 
     def table_schema(self, table: TableHandle) -> Schema:
-        if table.table not in _SCHEMAS:
+        if table.table not in self.tables:
             raise KeyError(f"unknown tpch table {table.table!r}")
         return tpch_schema(table.table)
 
@@ -654,10 +655,16 @@ class TpchConnector(Connector):
     """catalog 'tpch', schema names are scale factors ('sf1', 'tiny'...)."""
 
     name = "tpch"
+    applies_pushdown = False    # page_source drops it
 
-    def __init__(self, sf: float = 0.01):
+    def __init__(self, sf: float = 0.01, tables: Sequence[str] = TABLES):
+        """``tables``: the tables this catalog holds (a deployment of
+        one fact table lists it alone); every TPC-H table by default."""
+        unknown = sorted(set(tables) - set(TABLES))
+        if unknown:
+            raise ValueError(f"unknown tpch tables {unknown}")
         self.sf = sf
-        self._metadata = _Metadata(sf)
+        self._metadata = _Metadata(sf, tables)
         self._splits = _SplitManager(sf)
         self._gen = _Gen(sf)
 

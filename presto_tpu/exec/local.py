@@ -80,7 +80,7 @@ from ..planner.plan import (
     TableScanNode, TopNNode, UnionNode, ValuesNode,
 )
 from ..planner.planner import InitPlanRef, LogicalPlan, Session
-
+from ..planner.fold import fold_expr
 
 from ..planner.planner import bool_property  # noqa: F401 (re-export)
 
@@ -483,7 +483,7 @@ class _Executor:
                 return ir.Literal(type=n.type,
                                   value=self.init_values[n.value.index])
             return n
-        return ir_rewrite(e, fn)
+        return fold_expr(ir_rewrite(e, fn)) if self.init_values else e
 
     # -- dispatch -------------------------------------------------------------
     def run(self, node: PlanNode) -> Iterator[Batch]:
@@ -639,14 +639,14 @@ class _Executor:
             # the 2^17 floor: below it, downstream kernels over the
             # uncompacted capacity are taken to cost less than the
             # host sync of the liveness readback (the break-even size
-            # is not measured on the v5e)
+            # is not measured on the v5e). Counted from the count read
+            # here: lanes before and after (_note_compaction, below)
             if not state["check"] or b.capacity <= (1 << 17):
                 return b
             tgt = bucket_capacity(b.host_count("compaction-liveness"))
-            if tgt * 4 <= b.capacity:
-                return b.compact(tgt, check=False)
-            state["check"] = False
-            return b
+            state["check"] = shrink = tgt * 4 <= b.capacity
+            _note_compaction(b.capacity, tgt if shrink else b.capacity)
+            return b.compact(tgt, check=False) if shrink else b
         return maybe_compact
 
     def _FilterNode(self, node: FilterNode) -> Iterator[Batch]:
@@ -963,10 +963,10 @@ class _Executor:
                                 for a in aggs) else 64
             parts: List[Batch] = []
             for b in self.run(node.child):
-                parts.append(global_aggregate(b, aggs, mode="partial")
+                parts.append(_global_partial(b, aggs)
                              if step != "final" else b)
                 if len(parts) >= merge_at:
-                    parts = [global_aggregate(concat_batches(parts), aggs,
+                    parts = [global_aggregate(_global_states(parts), aggs,
                                               mode="merge")]
             if not parts:
                 # no input still finalizes to one row (count=0): final
@@ -978,7 +978,7 @@ class _Executor:
                 parts = [empty if step == "final"
                          else global_aggregate(empty, aggs,
                                                mode="partial")]
-            states = (concat_batches(parts) if len(parts) > 1 else parts[0])
+            states = _global_states(parts)
             if step == "partial":
                 yield global_aggregate(states, aggs, mode="merge") \
                     if len(parts) > 1 else states
@@ -2036,3 +2036,45 @@ class _Executor:
                     b, build, skeys, fkeys, node.residual, node.negated,
                     bucket_capacity(max(maxk, 1), minimum=1), ex=self)
             yield Batch(b.schema, b.columns, mask)
+
+
+# -- counters on the selective-filter path (TPC-H Q6) -------------------------
+# Down here, and their call sites rewritten line for line, because a
+# line that MOVES in this file re-keys the Pallas kernels traced below
+# its frames: `op_grouped_aggregate` then compiles cold for a quarter of
+# an hour (PERF.md section 7, row 3).
+
+#: `_compactor.maybe_compact`: batches whose liveness was read back,
+#: batches shrunk, and their capacities before and after (equal where a
+#: batch was left as it was): out over in is what the compaction saves
+#: the kernels downstream
+_COMPACT_CHECKED = REGISTRY.counter("compact_checked_total")
+_COMPACT_APPLIED = REGISTRY.counter("compact_applied_total")
+_COMPACT_LANES_IN = REGISTRY.counter("compact_lanes_in_total")
+_COMPACT_LANES_OUT = REGISTRY.counter("compact_lanes_out_total")
+
+#: the ungrouped branch of `_AggregationNode`: a partial state a batch,
+#: and each time several states are stacked into one to be merged
+_GLOBAL_PARTIALS = REGISTRY.counter("global_agg_partials_total")
+_GLOBAL_MERGES = REGISTRY.counter("global_agg_merges_total")
+
+
+def _note_compaction(lanes_in: int, lanes_out: int) -> None:
+    _COMPACT_CHECKED.inc()
+    _COMPACT_APPLIED.inc(lanes_out < lanes_in)
+    _COMPACT_LANES_IN.inc(lanes_in)
+    _COMPACT_LANES_OUT.inc(lanes_out)
+
+
+def _global_partial(b: Batch, aggs) -> Batch:
+    _GLOBAL_PARTIALS.inc()
+    return global_aggregate(b, aggs, mode="partial")
+
+
+def _global_states(parts: List[Batch]) -> Batch:
+    """The partial states as one batch, for the merge or the final
+    reduction."""
+    if len(parts) == 1:
+        return parts[0]
+    _GLOBAL_MERGES.inc()
+    return concat_batches(parts)

@@ -5,9 +5,10 @@ The input side of the engine, shared by the local executor
 
 - **ScanCache** — a memory-accounted, LRU, cross-query cache of decoded
   device column sets keyed by (connector instance, catalog, table,
-  split, column set, pushdown, table data-version). tf.data (PAPERS.md)
-  and "Accelerating Presto with GPUs" both found that the accelerator
-  starves unless decoded input is cached and pipelined; here a warm
+  split, column set, table data-version, and the pushdown where the
+  connector applies it: ``spi.Connector.applies_pushdown``). tf.data
+  (PAPERS.md) and "Accelerating Presto with GPUs" both found that the
+  accelerator starves unless decoded input is cached and pipelined; here a warm
   re-run of a scan-heavy query replays device-resident batches instead
   of re-generating/decoding/transferring every split. Entries are
   accounted against a dedicated ``memory.QueryMemoryPool`` (so the
@@ -385,6 +386,8 @@ def scan_splits(conn, catalog: str, columns: Sequence[str],
         ver_fn = getattr(conn, "data_version", None)
         version = ver_fn(splits[0].table.table) if ver_fn else None
         cacheable = version is not None
+    # getattr: duck-typed connector doubles predate the SPI attribute
+    applies_pushdown = getattr(conn, "applies_pushdown", True)
     pad = _PadTracker(bucket_capacity(max(int(rows_per_batch), 1))) \
         if opts.pad else None
     # inline (no prefetch threads): split_batches runs inside the
@@ -401,15 +404,21 @@ def scan_splits(conn, catalog: str, columns: Sequence[str],
 
     def split_keys(split, pushdown):
         """[effective key, static-pushdown fallback key] (deduped);
-        empty when uncacheable."""
+        empty when uncacheable. A connector that does not apply the
+        pushdown gives every pushdown the same batches: ONE key, so
+        that bindings that differ only in a literal bound share one
+        resident copy of the split."""
         if not cacheable:
             return []
+        static = static_pushdown
+        if not applies_pushdown:
+            pushdown = static = None
         try:
             keys = [ScanCache.key(conn, catalog, split, columns,
                                   pushdown, version, rows_per_batch)]
-            if _freeze(static_pushdown) != _freeze(pushdown):
+            if _freeze(static) != _freeze(pushdown):
                 keys.append(ScanCache.key(conn, catalog, split, columns,
-                                          static_pushdown, version,
+                                          static, version,
                                           rows_per_batch))
             return keys
         except TypeError:

@@ -11,6 +11,21 @@ compiler.
 
 Three-valued logic (AND/OR/IN/COALESCE/IF) follows ANSI SQL semantics,
 mirroring Presto's SpecialForm handling in sql/gen/AndCodeGenerator etc.
+
+Invariant: a program's constants are ``Literal`` (or ``Param``) leaves
+compared or combined WITH a column. No ``Call``, ``Cast`` or
+``SpecialForm`` all of whose leaves are literals or params is traced
+into a device program: the device's arithmetic is not the host's (the
+TPU v5e's emulated f64 division is not correctly rounded, so
+``cast(0.05 as double)`` computed there is 0.04999999999999982 and a
+row whose value EQUALS a bound of BETWEEN falls out). The planner folds
+such subtrees on the host (planner/fold.py, through :func:`host_value`
+below); a plan template punches no literal under arithmetic
+(serving/template.py) and a parameter is coerced by retyping it
+(sql/analyzer.coerce), its value converted on the host when a binding
+is bound (expr/params.current_args). What reaches :func:`eval_expr` all the
+same (a fold that declined: the evaluation raised, or the value has no
+literal form) is counted in ``expr_device_constant_total`` and logged.
 """
 from __future__ import annotations
 
@@ -24,6 +39,7 @@ import numpy as np
 from .. import types as T
 from ..batch import Batch, Column, Schema
 from ..obs import profiler as _prof
+from ..obs.log import LOG
 from ..obs.metrics import REGISTRY
 from ..obs.trace import TRACER
 from . import functions as F
@@ -34,6 +50,7 @@ from .ir import (
     Call, Cast, Expr, Form, InputRef, LambdaExpr, LambdaRef, Literal,
     SpecialForm,
 )
+from .rewrite import constant_subtrees, node_name
 
 #: functions whose lambda arguments are evaluated over array elements
 HIGHER_ORDER = frozenset(
@@ -51,8 +68,47 @@ def _masked_err(cond, err):
     return jnp.where(cond, err, jnp.int32(0))
 
 
+_DEVICE_CONSTANTS = REGISTRY.counter("expr_device_constant_total")
+
+
 def eval_expr(expr: Expr, inputs: List[Val]) -> Val:
-    """Evaluate an expression against input column Vals (traceable)."""
+    """Evaluate an expression against input column Vals (traceable):
+    the entry every device program traces through. Constant subtrees
+    that the planner's fold left in ``expr`` are counted here, at trace
+    time (the module's invariant)."""
+    for sub in constant_subtrees(expr):
+        _DEVICE_CONSTANTS.inc()
+        LOG.log("expr_device_constant", node=node_name(sub),
+                expr=repr(sub)[:200])
+    return _eval(expr, inputs)
+
+
+def host_value(expr: Expr):
+    """The python value of a constant expression (no column below it),
+    evaluated ON THE HOST: the engine's own semantics (decimal precision
+    and scale, dates, NULLs, three-valued logic) through :func:`_eval`
+    over one row, pinned to the CPU device, whose f64 is IEEE's. Raises
+    what the evaluation raises, and ``QueryError`` for a row error
+    (division by zero, a cast out of range). Arrays come back as
+    tuples."""
+    from ..errors import QueryError
+    # no CPU backend (JAX_PLATFORMS names the accelerator alone) is a
+    # RuntimeError here, and the query's: nothing may take it for a
+    # fold that declined and send the literals back to the device
+    with jax.default_device(jax.devices("cpu")[0]):
+        v = _eval(expr, [])
+        if v.err is not None:
+            code = int(jnp.max(v.err))
+            if code:
+                raise QueryError(code)
+        b = Batch(Schema([("c", expr.type)]),
+                  [Column(expr.type, v.data, v.valid, v.dictionary)],
+                  jnp.ones(v.valid.shape[0], dtype=bool))
+        out = b.to_pylist()[0][0]
+    return tuple(out) if isinstance(out, list) else out
+
+
+def _eval(expr: Expr, inputs: List[Val]) -> Val:
     if isinstance(expr, InputRef):
         return inputs[expr.index]
     if isinstance(expr, Literal):
@@ -67,7 +123,7 @@ def eval_expr(expr: Expr, inputs: List[Val]) -> Val:
         n = inputs[0].valid.shape[0] if inputs else 1
         return traced_val(expr, n)
     if isinstance(expr, Cast):
-        arg = eval_expr(expr.arg, inputs)
+        arg = _eval(expr.arg, inputs)
         out = cast_val(arg, expr.type)
         out.err = merge_err(out.err, arg.err)
         return out
@@ -76,7 +132,7 @@ def eval_expr(expr: Expr, inputs: List[Val]) -> Val:
     if isinstance(expr, Call):
         if expr.name in HIGHER_ORDER:
             return _eval_higher_order(expr, inputs)
-        args = [eval_expr(a, inputs) for a in expr.args]
+        args = [_eval(a, inputs) for a in expr.args]
         out = F.lookup(expr.name)(args, expr.type)
         # strict calls: an argument's row error poisons the call's row
         # (the argument was evaluated to produce the error, like Java
@@ -105,7 +161,7 @@ def _eval_higher_order(expr: Call, inputs: List[Val]) -> Val:
     from .array_functions import _compact_rows, in_length
 
     name = expr.name
-    arr = eval_expr(expr.args[0], inputs)
+    arr = _eval(expr.args[0], inputs)
     values, lengths, elem_valid = arr.data
     cap, L = values.shape
     inl = in_length(values, lengths)
@@ -114,7 +170,7 @@ def _eval_higher_order(expr: Call, inputs: List[Val]) -> Val:
     if name == "reduce":
         # reduce(arr, init, (s, x) -> step, s -> out): sequential fold over
         # the static width L — the loop unrolls at trace time
-        init = eval_expr(expr.args[1], inputs)
+        init = _eval(expr.args[1], inputs)
         step_lam: LambdaExpr = expr.args[2]
         out_lam: LambdaExpr = expr.args[3]
         state = init
@@ -123,7 +179,7 @@ def _eval_higher_order(expr: Call, inputs: List[Val]) -> Val:
                      arr.dictionary)
             _LAMBDA_STACK.append([state, xj])
             try:
-                new_state = eval_expr(step_lam.body, inputs)
+                new_state = _eval(step_lam.body, inputs)
             finally:
                 _LAMBDA_STACK.pop()
             active = inl[:, j]
@@ -134,7 +190,7 @@ def _eval_higher_order(expr: Call, inputs: List[Val]) -> Val:
                             active, new_state.err)))
         _LAMBDA_STACK.append([state])
         try:
-            out = eval_expr(out_lam.body, inputs)
+            out = _eval(out_lam.body, inputs)
         finally:
             _LAMBDA_STACK.pop()
         out.valid = out.valid & arr.valid
@@ -151,7 +207,7 @@ def _eval_higher_order(expr: Call, inputs: List[Val]) -> Val:
     _LAMBDA_STACK[:] = [[_repeat_val(v, L) for v in frame]
                         for frame in old_stack] + [[elem]]
     try:
-        body = eval_expr(lam.body, rep_inputs)
+        body = _eval(lam.body, rep_inputs)
     finally:
         _LAMBDA_STACK[:] = old_stack
     body_err = None
@@ -188,35 +244,35 @@ def _eval_higher_order(expr: Call, inputs: List[Val]) -> Val:
 def _eval_special(expr: SpecialForm, inputs: List[Val]) -> Val:
     form = expr.form
     if form == Form.AND or form == Form.OR:
-        vals = [eval_expr(a, inputs) for a in expr.args]
+        vals = [_eval(a, inputs) for a in expr.args]
         return _logical(vals, is_and=(form == Form.AND))
     if form == Form.IS_NULL:
-        v = eval_expr(expr.args[0], inputs)
+        v = _eval(expr.args[0], inputs)
         return Val(~v.valid, jnp.ones_like(v.valid), T.BOOLEAN, err=v.err)
     if form == Form.IF:
-        cond = eval_expr(expr.args[0], inputs)
-        then = eval_expr(expr.args[1], inputs)
-        els = eval_expr(expr.args[2], inputs)
+        cond = _eval(expr.args[0], inputs)
+        then = _eval(expr.args[1], inputs)
+        els = _eval(expr.args[2], inputs)
         take_then = cond.valid & cond.data
         out = _merge_branch(take_then, then, els, expr.type)
         out.err = merge_err(out.err, cond.err)
         return out
     if form == Form.SWITCH:
         # SWITCH(c1, v1, c2, v2, ..., default)
-        default = eval_expr(expr.args[-1], inputs)
+        default = _eval(expr.args[-1], inputs)
         out = default
         # fold right-to-left so earlier WHENs win; an earlier match also
         # masks later branches'/conditions' errors (they are unevaluated
         # in the row-at-a-time reference)
         pairs = list(zip(expr.args[:-1:2], expr.args[1::2]))
         for cond_e, val_e in reversed(pairs):
-            cond = eval_expr(cond_e, inputs)
-            val = eval_expr(val_e, inputs)
+            cond = _eval(cond_e, inputs)
+            val = _eval(val_e, inputs)
             out = _merge_branch(cond.valid & cond.data, val, out, expr.type)
             out.err = merge_err(out.err, cond.err)
         return out
     if form == Form.COALESCE:
-        vals = [eval_expr(a, inputs) for a in expr.args]
+        vals = [_eval(a, inputs) for a in expr.args]
         out = vals[-1]
         for v in reversed(vals[:-1]):
             nxt = _merge_branch(v.valid, v, out, expr.type)
@@ -226,7 +282,7 @@ def _eval_special(expr: SpecialForm, inputs: List[Val]) -> Val:
             out = nxt
         return out
     if form == Form.BETWEEN:
-        v, lo, hi = (eval_expr(a, inputs) for a in expr.args)
+        v, lo, hi = (_eval(a, inputs) for a in expr.args)
         ge = F.lookup("ge")([v, lo], T.BOOLEAN)
         le = F.lookup("le")([v, hi], T.BOOLEAN)
         out = _logical([ge, le], is_and=True)
@@ -235,13 +291,13 @@ def _eval_special(expr: SpecialForm, inputs: List[Val]) -> Val:
     if form == Form.IN:
         return _eval_in(expr, inputs)
     if form == Form.NULL_IF:
-        a = eval_expr(expr.args[0], inputs)
-        b = eval_expr(expr.args[1], inputs)
+        a = _eval(expr.args[0], inputs)
+        b = _eval(expr.args[1], inputs)
         eq = F.lookup("eq")([a, b], T.BOOLEAN)
         return Val(a.data, a.valid & ~(eq.valid & eq.data), a.type,
                    a.dictionary, err=merge_err(a.err, b.err))
     if form == Form.TRY:
-        v = eval_expr(expr.args[0], inputs)
+        v = _eval(expr.args[0], inputs)
         if v.err is None:
             return v
         return Val(v.data, v.valid & (v.err == 0), v.type, v.dictionary)
@@ -324,8 +380,8 @@ def _merge_branch(take_a: jnp.ndarray, a: Val, b: Val, out_type: T.Type) -> Val:
 
 
 def _eval_in(expr: SpecialForm, inputs: List[Val]) -> Val:
-    v = eval_expr(expr.args[0], inputs)
-    items = [eval_expr(a, inputs) for a in expr.args[1:]]
+    v = _eval(expr.args[0], inputs)
+    items = [_eval(a, inputs) for a in expr.args[1:]]
     if v.type.is_string and v.dictionary is not None:
         targets = set()
         any_null = False

@@ -24,6 +24,7 @@ import math
 import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -137,6 +138,17 @@ def rescale_decimal(data: jnp.ndarray, from_scale: int, to_scale: int) -> jnp.nd
     return sign * ((jnp.abs(data) + half) // div)
 
 
+def _divisor(scale: int, dtype=jnp.float64):
+    """``10**scale`` as ``cast(decimal as double)`` divides by it: behind
+    an optimization barrier, or XLA sees a division by a constant and
+    multiplies by the reciprocal instead, which is not the quotient
+    (``35 * 0.01`` is 0.35000000000000003: a column's 0.35 then differs
+    from the literal 0.35 on every backend). The CPU's division is
+    IEEE's; the TPU v5e's is not correctly rounded (README.md, DOUBLE
+    on the chip)."""
+    return jax.lax.optimization_barrier(jnp.asarray(10.0 ** scale, dtype))
+
+
 def _cast_long_decimal(v: Val, to: Type) -> Val:
     """Casts where the source or target is a long decimal (p > 18):
     limb rescales with range checks (reference DecimalCasts.java +
@@ -164,7 +176,7 @@ def _cast_long_decimal(v: Val, to: Type) -> Val:
         return Val(I.lo(x), v.valid & fits, to, err=err)
     # source is long decimal
     if isinstance(to, T.DoubleType) or isinstance(to, T.RealType):
-        out = (I.to_f64(v.data) / (10.0 ** f.scale)).astype(to.storage_dtype)
+        out = (I.to_f64(v.data) / _divisor(f.scale)).astype(to.storage_dtype)
         return Val(out, v.valid, to)
     if T.is_integral(to) or isinstance(to, T.BigintType):
         x, _ = I.rescale(v.data, -f.scale)
@@ -215,7 +227,8 @@ def cast_val(v: Val, to: Type) -> Val:
         return Val(rescale_decimal(data, f.scale, to.scale), v.valid, to)
     if isinstance(to, T.DoubleType) or isinstance(to, T.RealType):
         if isinstance(f, T.DecimalType):
-            out = data.astype(to.storage_dtype) / (10.0 ** f.scale)
+            out = data.astype(to.storage_dtype) / _divisor(
+                f.scale, to.storage_dtype)
         else:
             out = data.astype(to.storage_dtype)
         return Val(out, v.valid, to)
@@ -897,9 +910,22 @@ for _name, _jfn in [
         ("sin", jnp.sin), ("cos", jnp.cos), ("tan", jnp.tan),
         ("asin", jnp.arcsin), ("acos", jnp.arccos), ("atan", jnp.arctan),
         ("sinh", jnp.sinh), ("cosh", jnp.cosh), ("tanh", jnp.tanh),
-        ("log2", jnp.log2), ("log10", jnp.log10), ("cbrt", jnp.cbrt),
+        ("log10", jnp.log10), ("cbrt", jnp.cbrt),
         ("degrees", jnp.degrees), ("radians", jnp.radians)]:
     register(_name)(_dbl_fn(_jfn))
+
+
+def _log2(x):
+    """``ln(x) / ln(2)``, a true division as the reference's
+    (MathFunctions.log2): ``jnp.log2`` multiplies by a reciprocal where
+    its operand is not a constant of the program, so ``log2(8e0)`` was
+    3.0 as a literal (XLA folded it) and 2.9999999999999996 from a
+    column, or from the planner's fold on the host."""
+    return jnp.log(x) / jax.lax.optimization_barrier(
+        jnp.asarray(math.log(2.0), x.dtype))
+
+
+register("log2")(_dbl_fn(_log2))
 
 
 @register("atan2")

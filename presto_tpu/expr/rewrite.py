@@ -62,6 +62,45 @@ def substitute_literals(e: ir.Expr,
     return rewrite(e, fn)
 
 
+#: calls that give another value each time they run: never constant,
+#: whatever their arguments (the engine registers none of its own; a
+#: plugin may, expr/functions.register_external)
+NON_DETERMINISTIC = frozenset(["random", "rand", "uuid", "now", "shuffle"])
+
+
+def is_constant(e: ir.Expr, params: bool = False) -> bool:
+    """True when nothing below ``e`` reads a row: no column, no lambda
+    parameter, no non-deterministic call, and (unless ``params``) no
+    plan-template parameter. Its value is one value for the query."""
+    if isinstance(e, (ir.InputRef, ir.LambdaRef, ir.LambdaExpr)):
+        return False
+    if isinstance(e, ir.Param):
+        return params
+    if isinstance(e, ir.Call) and e.name in NON_DETERMINISTIC:
+        return False
+    return all(is_constant(c, params) for c in e.children())
+
+
+def constant_subtrees(e: ir.Expr) -> Sequence[ir.Expr]:
+    """The maximal ``Call``/``Cast``/``SpecialForm`` subtrees of ``e``
+    all of whose leaves are literals or params: what a device program
+    must not compute (expr/compiler.py, header)."""
+    if isinstance(e, (ir.Literal, ir.Param, ir.InputRef, ir.LambdaRef)):
+        return []
+    if is_constant(e, params=True):
+        return [e]
+    return [s for c in e.children() for s in constant_subtrees(c)]
+
+
+def node_name(e: ir.Expr) -> str:
+    """What a log line calls a node: the function, the form, ``cast``."""
+    if isinstance(e, ir.Call):
+        return e.name
+    if isinstance(e, ir.SpecialForm):
+        return e.form.value
+    return type(e).__name__.lower()
+
+
 def conjuncts(e: ir.Expr) -> Sequence[ir.Expr]:
     if isinstance(e, ir.SpecialForm) and e.form == ir.Form.AND:
         out = []

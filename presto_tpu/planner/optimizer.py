@@ -39,14 +39,26 @@ BROADCAST_ROW_LIMIT = 2_000_000
 
 
 def optimize(plan: LogicalPlan, session: Session) -> LogicalPlan:
+    from ..obs.trace import TRACER
+    from .fold import Folder
     from .rules import iterative_optimize
     from .stats import StatsCalculator
 
+    folder = Folder()
+
+    def fold(node: PlanNode) -> PlanNode:
+        with TRACER.span("fold"):
+            return folder.plan(node)
+
     def pipeline(node: PlanNode) -> PlanNode:
-        # iterative simplify/merge/push rules to a fixpoint (reference
-        # IterativeOptimizer over the rule catalog), then the structural
-        # visitor passes (reference PlanOptimizers.java:252-412 ordering)
-        node = iterative_optimize(node)
+        # literal expressions first, on the host (planner/fold.py): the
+        # rules then see `where 1 = 0` as a trivial filter, and the scan
+        # pushdown `date '1994-01-01' + interval '1' year` as a literal.
+        # Then iterative simplify/merge/push rules to a fixpoint
+        # (reference IterativeOptimizer over the rule catalog), then the
+        # structural visitor passes (reference
+        # PlanOptimizers.java:252-412 ordering)
+        node = iterative_optimize(fold(node))
         node = _rewrite_joins(node, session)
         node, _ = _prune(node, list(range(len(node.fields))))
         node = _implement_joins(node, session)
@@ -58,7 +70,9 @@ def optimize(plan: LogicalPlan, session: Session) -> LogicalPlan:
         node = _attach_join_strategy(
             node, session,
             dense=bool_property(session, "join_dense_path", True))
-        return _attach_scan_pushdown(node)
+        # again: inlined projections and join residuals put literals
+        # together that the first pass saw apart
+        return _attach_scan_pushdown(fold(node))
     # one memoized StatsCalculator for the whole pass: join ordering,
     # distribution choice, and the eager-agg gate all estimate the same
     # subtrees, and connector table_stats can be full-scan priced

@@ -101,34 +101,17 @@ def bool_property(session: "Session", name: str, default: bool) -> bool:
 def _const_value(e: ir.Expr):
     """Evaluate a constant expression to its python value (VALUES cells,
     which may be arbitrary constant expressions: casts, arithmetic,
-    ARRAY[...] constructors — reference ExpressionInterpreter's role)."""
+    ARRAY[...] constructors — reference ExpressionInterpreter's role),
+    on the host like every constant (expr/compiler.host_value)."""
     if isinstance(e, ir.Literal):
         return e.value
-    import jax.numpy as jnp
-
-    from ..batch import Batch, Column, Schema
-    from ..errors import QueryError
-    from ..expr.compiler import eval_expr
-    from ..expr.functions import Val
-
-    carrier = Val(jnp.ones(1, dtype=bool), jnp.ones(1, dtype=bool),
-                  T.BOOLEAN)
+    from ..expr.compiler import host_value
     try:
-        v = eval_expr(e, [carrier])
+        return host_value(e)
     except NotImplementedError as exc:
         # an engine limitation, not a user error — say so
         raise NotImplementedError(
             f"cannot evaluate VALUES cell {e!r}: {exc}")
-    if v.err is not None:
-        code = int(jnp.max(v.err))
-        if code:
-            raise QueryError(code)
-    mask = jnp.ones(v.valid.shape[0], dtype=bool)
-    b = Batch(Schema([("c", e.type)]),
-              [Column(e.type, v.data, v.valid, v.dictionary)], mask)
-    out = b.to_pylist()[0][0]
-    # plan nodes are hashable dataclasses: array values ride as tuples
-    return tuple(out) if isinstance(out, list) else out
 
 
 def plan_query(query: A.Query, session: Session) -> LogicalPlan:

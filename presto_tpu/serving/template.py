@@ -70,9 +70,12 @@ class Template:
 # -- parameterization ---------------------------------------------------------
 
 #: predicate-context nodes the hole-punch walk recurses THROUGH;
-#: entering any other node type ends eligibility (its literals bake)
+#: entering any other node type ends eligibility (its literals bake).
+#: Arithmetic is none: a literal under it bakes, joins the template key
+#: and is folded on the host (planner/fold.py), because a parameter's
+#: arithmetic would run on the device (expr/compiler.py's invariant)
 _PUNCH_CONTEXTS = (A.LogicalBinary, A.Not, A.Comparison, A.Between,
-                   A.InList, A.ArithmeticBinary, A.ArithmeticUnary)
+                   A.InList)
 
 _SLOT_FORMS = {
     A.LongLiteral: (A.SlotLongLiteral, lambda e: "bigint"),

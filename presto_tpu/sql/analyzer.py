@@ -186,6 +186,16 @@ def coerce(e: ir.Expr, to: T.Type) -> ir.Expr:
             return ir.cast(e, to)
         if isinstance(to, (T.VarcharType, T.CharType)):
             return ir.lit(str(v), to)
+    if isinstance(e, ir.Param) and T.is_numeric(e.type) and (
+            T.is_floating(to)
+            or isinstance(to, T.DecimalType) and not to.is_long):
+        # a plan-template parameter takes the type itself: its value is
+        # converted ON THE HOST when a binding is bound (``to_storage``
+        # in expr/params.current_args), as a literal's is above. A cast
+        # of it would be traced into the device program, where
+        # decimal -> double is a division the TPU rounds wrongly
+        # (expr/compiler.py's invariant)
+        return ir.param(e.slot, e.bound, to)
     return ir.cast(e, to)
 
 

@@ -131,6 +131,11 @@ class DoubleType(Type):
     def storage_dtype(self):
         return jnp.float64
 
+    def to_storage(self, value: Any):
+        # an int or a Decimal (a coerced plan-template parameter's
+        # binding) converts here, on the host, correctly rounded
+        return float(value)
+
     def null_storage(self):
         return 0.0
 
@@ -142,6 +147,9 @@ class RealType(Type):
     @property
     def storage_dtype(self):
         return jnp.float32
+
+    def to_storage(self, value: Any):
+        return float(value)
 
     def null_storage(self):
         return 0.0

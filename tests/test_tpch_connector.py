@@ -106,3 +106,23 @@ def test_stats(conn):
     st = conn.metadata.table_stats(th)
     assert st.row_count == 1500
     assert st.columns["o_orderkey"].max_value == 1500
+
+
+@pytest.mark.parametrize("tables", [("lineitem",), ("orders", "customer")])
+def test_a_catalog_of_some_tables_holds_those_alone(tables):
+    conn = TpchConnector(sf=0.001, tables=list(tables))
+    assert conn.metadata.list_tables() == list(tables)
+    for t in TABLES:
+        th = TableHandle("tpch", "tiny", t)
+        if t in tables:
+            assert conn.metadata.table_schema(th).names == tpch_schema(t).names
+            assert len(_scan(conn, t, tpch_schema(t).names[:1])) > 0
+        else:
+            with pytest.raises(KeyError, match=t):
+                conn.metadata.table_schema(th)
+
+
+def test_a_catalog_of_an_unknown_table_is_refused():
+    with pytest.raises(ValueError, match="nope"):
+        TpchConnector(sf=0.001, tables=["lineitem", "nope"])
+    assert TpchConnector(sf=0.001).metadata.list_tables() == list(TABLES)

@@ -177,3 +177,61 @@ def test_named_mesh_program_compiles_for_four_v5e_chips(topo):
     text = compiled.as_text()
     assert "jit_smap_agg_probe" in text
     assert "all-reduce" in text
+
+
+Q6 = """select sum(l_extendedprice * l_discount) as revenue from lineitem
+    where l_shipdate >= date '1994-01-01'
+      and l_shipdate < date '1994-01-01' + interval '1' year
+      and l_discount between 0.06 - 0.01 and 0.06 + 0.01
+      and l_quantity < 24"""
+Q1 = """select l_returnflag, l_linestatus, sum(l_quantity), count(*)
+    from lineitem
+    where l_shipdate <= date '1998-12-01' - interval '90' day
+    group by l_returnflag, l_linestatus"""
+
+
+@pytest.mark.parametrize("sql,banned", [
+    # the discount's bounds were `cast(0.06 -+ 0.01 as double)`, a
+    # division by 10^scale that the v5e rounds wrongly
+    (Q6, ("divide",)),
+    # the one bound was `date_add_days(lit, lit)`: now a compare with a
+    # literal and nothing else
+    (Q1, ("divide", "add", "subtract", "multiply", "convert")),
+], ids=["q6", "q1"])
+def test_filter_program_holds_no_literal_arithmetic_for_v5e(
+        one_chip, monkeypatch, sql, banned):
+    """ISSUE 29: literal expressions are folded on the host at plan
+    time, so the filter program of TPC-H Q6 and of Q1, lowered for the
+    described v5e at the SQL path's batch size, computes nothing from
+    its literals (the lowered StableHLO text), and compiles."""
+    from presto_tpu.exec import local as local_exec
+    from presto_tpu.exec.runner import LocalRunner
+    from presto_tpu.expr.compiler import ExprCompiler
+    from presto_tpu.planner.plan import FilterNode
+    seen = {}
+    real = local_exec._Executor._TableScanNode
+
+    def capture(self, node):
+        for b in real(self, node):
+            seen.setdefault("batch", b)
+            yield b
+    monkeypatch.setattr(local_exec._Executor, "_TableScanNode", capture)
+    runner = LocalRunner(tpch_sf=0.002)
+    runner.execute(sql)
+    node = runner.plan(sql).root
+    while not isinstance(node, FilterNode):
+        [node] = node.children
+    program = ExprCompiler().filter(
+        node.predicate, local_exec._plan_schema(node.child), errors=True)
+
+    def widened(leaf):
+        return jax.ShapeDtypeStruct((N_BATCH,) + leaf.shape[1:],
+                                    leaf.dtype, sharding=one_chip)
+    lowered = program.fn.lower(
+        jax.tree_util.tree_map(widened, seen["batch"]))
+    ops = {line.split("stablehlo.")[1].split()[0].strip('"')
+           for line in lowered.as_text().splitlines()
+           if "stablehlo." in line}
+    assert "compare" in ops
+    assert not ops & set(banned), sorted(ops)
+    assert program.program in lowered.compile().as_text()
