@@ -28,7 +28,7 @@ from ..expr import ir
 from ..expr.compiler import compile_filter, compile_projection
 from ..expr.rewrite import rewrite as ir_rewrite
 from ..ops.aggregation import AggSpec
-from ..ops.jitcache import global_aggregate_jit as global_aggregate, grouped_aggregate_jit as grouped_aggregate
+from ..ops.jitcache import global_aggregate_jit as global_aggregate, grouped_aggregate_jit as grouped_aggregate, compact_jit
 from ..ops.jitcache import (
     build_key_ranks_jit, build_match_mask_jit, expand_join_jit,
     key_bounds_violation_jit, lookup_join_jit, lookup_join_pallas_jit,
@@ -636,17 +636,17 @@ class _Executor:
         state = {"check": self.compact_streams}
 
         def maybe_compact(b: Batch) -> Batch:
-            # the 2^17 floor: below it, downstream kernels over the
-            # uncompacted capacity are taken to cost less than the
-            # host sync of the liveness readback (the break-even size
-            # is not measured on the v5e). Counted from the count read
-            # here: lanes before and after (_note_compaction, below)
+            # the 2^17 floor and the 4x rule, on the v5e (PERF.md section
+            # 5, tools/compact_probe.py): the readback costs ~1.0 ms, the
+            # program 0.9 ms at 2^17 lanes, 3.1 (2^20 to 2^15) to 24 ms
+            # (to 2^18): ~11 ns a gathered lane a column. Counted from the
+            # count read here (_note_compaction, below)
             if not state["check"] or b.capacity <= (1 << 17):
                 return b
             tgt = bucket_capacity(b.host_count("compaction-liveness"))
             state["check"] = shrink = tgt * 4 <= b.capacity
             _note_compaction(b.capacity, tgt if shrink else b.capacity)
-            return b.compact(tgt, check=False) if shrink else b
+            return compact_jit(b, tgt) if shrink else b
         return maybe_compact
 
     def _FilterNode(self, node: FilterNode) -> Iterator[Batch]:
@@ -740,10 +740,10 @@ class _Executor:
                 for k in node.keys]
         state: Optional[Batch] = None
         for b in self.run(node.child):
-            cand = top_n(b, keys, node.count).compact(
-                bucket_capacity(node.count))
-            state = cand if state is None else top_n(
-                concat_batches([state, cand]), keys, node.count).compact(
+            cand = compact_jit(
+                top_n(b, keys, node.count), bucket_capacity(node.count))
+            state = cand if state is None else compact_jit(top_n(
+                concat_batches([state, cand]), keys, node.count),
                     bucket_capacity(node.count))
         if state is not None:
             yield sort_batch(state, keys)
@@ -1982,7 +1982,7 @@ class _Executor:
         """Cross join where one side is tiny (scalar subqueries, VALUES)."""
         if build is None:
             return
-        build = build.compact()
+        build = compact_jit(build, build.capacity)
         nb = build.host_count()
         if nb == 0:
             return

@@ -37,7 +37,7 @@ from ..memory import QueryMemoryPool, batch_device_bytes
 from ..obs.metrics import REGISTRY
 from ..obs.trace import TRACER
 from ..ops.aggregation import AggSpec
-from ..ops.jitcache import grouped_aggregate_jit as grouped_aggregate
+from ..ops.jitcache import grouped_aggregate_jit as grouped_aggregate, compact_jit
 from ..ops.sort import SortKey, sort_batch
 from ..parallel.exchange import hash_partition_ids
 
@@ -431,8 +431,8 @@ class AggSpillBuffer:
                                    self.key_idx, self.aggs, mode="merge",
                                    key_bounds=self.key_bounds,
                                    allow_dense=self.allow_dense)
-        state = merged.compact(
-            bucket_capacity(max(merged.host_count(), 1)))
+        state = compact_jit(
+            merged, bucket_capacity(max(merged.host_count(), 1)))
         with self.ctx.pool.lock:
             self.ctx.release_all()
             if not self.spilled and self.ctx.pool.try_reserve(

@@ -425,8 +425,8 @@ _compact = _entry_cache(
 
 
 def compact_jit(batch, capacity: int):
-    """Jitted Batch.compact — shrink a sparse batch to a bucketed
-    capacity (callers must know the live count fits)."""
+    """Jitted Batch.compact: the live count must fit ``capacity``."""
+    _COMPACT_PROGRAMS.inc()
     return _compact(capacity)(batch)
 
 
@@ -556,3 +556,14 @@ def expand_match_origins_jit(probe, build, probe_keys, build_keys,
                              max_matches, prepared):
     return _expand_origins(tuple(probe_keys), tuple(build_keys),
                            max_matches)(probe, build, prepared)
+
+
+# -- the compaction program's launches ---------------------------------------
+# Down here because a line that MOVES in this file above the Pallas
+# kernels' frames re-keys `op_grouped_aggregate` (PERF.md section 7,
+# row 3).
+
+#: every launch of `op_compact`, whatever the site (`compact_jit`): the
+#: executor's `_compactor`, the fused chain, TopN, the build sides, the
+#: merge buffer; `compact_applied_total` counts the `_compactor`'s alone
+_COMPACT_PROGRAMS = REGISTRY.counter("compact_programs_total")

@@ -130,6 +130,29 @@ def test_q1_agg_step_compiles_for_v5e(one_chip, monkeypatch):
     assert c.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("cap", [1 << 15, N_BATCH])
+def test_compact_compiles_for_v5e_without_a_scatter(one_chip, cap):
+    """``jit_op_compact`` over TPC-H Q6's filter output at the SQL
+    path's batch size (2^20 lanes to the 2^15 the 1.9 % filter leaves,
+    and to the batch's own capacity, the cross join's build side): the
+    TPU compiler's program holds no scatter, which ``jnp.nonzero``'s did
+    (~100 ms a batch on the v5e), and no sort."""
+    import re
+    import __graft_entry__ as graft
+    from presto_tpu.ops.jitcache import _compact
+    _, (batch,) = graft.entry()
+
+    def widen(leaf):
+        return jax.ShapeDtypeStruct((N_BATCH,) + leaf.shape[1:],
+                                    leaf.dtype, sharding=one_chip)
+    c = _compact(cap).fn.lower(
+        jax.tree_util.tree_map(widen, batch)).compile()
+    text = c.as_text()
+    assert "jit_op_compact" in text
+    assert not re.findall(r"\s(scatter|sort)\(", text)
+    assert c.memory_analysis() is not None
+
+
 def _probe_shapes(one_chip):
     i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,  # noqa: E731
                                          sharding=one_chip)
