@@ -35,8 +35,7 @@ import re
 import sys
 import time
 
-#: relative tolerance for DOUBLE results — the repo's own row comparison
-#: (bench.py ``_multichip_rows_match``): reduction order legitimately
+#: relative tolerance for DOUBLE results: reduction order legitimately
 #: shifts big float64 sums in the last places. Counts, integers, dates
 #: and strings compare exactly.
 REL_TOL = 1e-6
@@ -54,8 +53,8 @@ SCALE = 10.0
 #: lineitem is one 2^20-row batch; the same operators compile in ~480 s.
 Q3_SCALE = 0.1
 
-#: device scan-cache limit for the run, through the cache's own call
-#: (bench.py makes the same one): Q6's and Q1's column sets resident
+#: device scan-cache limit for the run, through the cache's own call:
+#: Q6's and Q1's column sets resident
 #: together, under the chip's 16GB
 SCAN_CACHE_BYTES = 8 << 30
 

@@ -20,7 +20,7 @@ jax.config.update("jax_enable_x64", True)
 
 def enable_compile_cache() -> None:
     """Persistent XLA compile cache for every entry point (CLI, both
-    servers, bench.py, tools/fleet.py, chip_smoke.py): the directory is
+    servers, benchmarks/, tools/fleet.py, chip_smoke.py): the directory is
     placed from OUTSIDE. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
     reads it itself and no directory is set in code; where it is not,
     the fixed ``<checkout>/.jax_cache`` (the path is part of the cache

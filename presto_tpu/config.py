@@ -408,52 +408,12 @@ ENV_VARS: Dict[str, str] = {
                               "(on/off; default on)",
     "PRESTO_TPU_FAILPOINTS": "failpoint arming spec applied at import "
                              "(exec/failpoints.py grammar)",
-    "PRESTO_TPU_DEVICE_FLOOR_MS": "modeled per-quantum/per-scanned-"
-                                  "batch device-service floor in ms "
-                                  "(exec/taskexec.py; 0 = off) — the "
-                                  "fixed-throughput device model the "
-                                  "elastic load-ramp bench uses on "
-                                  "hosts whose CPUs cannot show real "
-                                  "multi-process scaling",
     "PRESTO_TPU_TIMESERIES": "set to 'off' to disable the background "
                              "health-plane sampler (obs/timeseries.py)",
-    "BENCH_REPIN": "allow bench.py to overwrite pinned proxy seconds",
-    "BENCH_OUT": "write the bench summary JSON here (regression gate "
-                 "input)",
-    "BENCH_BUDGET_S": "wall-clock budget for a bench run (seconds)",
-    "BENCH_SF": "default TPC-H scale factor for bench configs",
-    "BENCH_SF_Q1": "scale-factor override for the q1 config",
-    "BENCH_SF_Q1SQL": "scale-factor override for the q1sql config",
-    "BENCH_SF_Q3": "scale-factor override for the q3 config",
-    "BENCH_SF_Q6": "scale-factor override for the q6 config",
-    "BENCH_SF_DS": "scale-factor override for the TPC-DS configs",
-    "BENCH_SF_ORC": "scale-factor for the ORC device-decode config",
-    "BENCH_ORC": "include the ORC device-decode config in the tuple",
-    "BENCH_SERVING": "run the serving bench axis",
-    "BENCH_SERVING_SF": "serving bench scale factor",
-    "BENCH_SERVING_CLIENTS": "legacy alias of SERVING_CLIENTS",
-    "BENCH_SERVING_QUERIES": "legacy alias of SERVING_QUERIES",
-    "BENCH_MULTICHIP": "run the multichip bench axis",
-    "BENCH_MULTICHIP_DEVICES": "max mesh width for the multichip axis",
-    "BENCH_MULTICHIP_FORCE_CPU": "self-provision a virtual CPU mesh "
-                                 "for the multichip axis (default 1)",
-    "BENCH_MULTICHIP_SF": "multichip bench scale factor",
-    "SERVING_CLIENTS": "serving bench concurrent client count",
-    "SERVING_QUERIES": "serving bench statements per client",
-    "SERVING_MIX": "comma-separated serving bench phases "
-                   "(mixed/execute/repeated)",
-    "SERVING_COORDINATORS": "serving bench fleet width: N>=2 spawns N "
-                            "coordinator subprocesses behind a "
-                            "FleetClient (tools/fleet.py); unset/0 = "
-                            "classic single-coordinator bench",
     "SERVING_INLINE_LANE": "set to 0 to disable the statement POST "
                            "inline lane (proven-fast statements "
                            "executing in the handler thread); default "
                            "on",
-    "SERVING_OUT": "write the serving bench pin JSON here",
-    "MULTICHIP_OUT": "write the multichip bench pin JSON here",
-    "ELASTIC_OUT": "write the chaos recovery-time summary here "
-                   "(tools/chaos_smoke.py)",
 }
 
 

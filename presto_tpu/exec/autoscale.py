@@ -343,7 +343,7 @@ class LocalProcessProvider(NodeProvider):
     address spaces, real process exit on drain, SIGKILL preemption.
 
     A worker gets the platform its CALLER gives it: the child inherits
-    this process's environment, overlaid with ``extra_env`` — nothing
+    this process's environment — nothing
     here picks ``JAX_PLATFORMS`` for it. One chip takes ONE worker
     process (a chip belongs to the process that first touched it, this
     one included), so more than one device-holding worker per chip
@@ -354,18 +354,13 @@ class LocalProcessProvider(NodeProvider):
                  tpch_sf: float = 0.01, host: str = "127.0.0.1",
                  spool_dir: Optional[str] = None,
                  etc_dir: Optional[str] = None,
-                 ready_timeout_s: float = 180.0,
-                 extra_env: Optional[Dict[str, str]] = None):
+                 ready_timeout_s: float = 180.0):
         self.coordinator_urls = list(coordinator_urls)
         self.tpch_sf = float(tpch_sf)
         self.host = host
         self.spool_dir = spool_dir
         self.etc_dir = etc_dir
         self.ready_timeout_s = float(ready_timeout_s)
-        #: worker-process environment overlay (JAX_PLATFORMS for the
-        #: workers, the elasticity bench's PRESTO_TPU_DEVICE_FLOOR_MS
-        #: device model)
-        self.extra_env = dict(extra_env or {})
         self._handles: List[NodeHandle] = []
         self._seq = 0
 
@@ -380,11 +375,9 @@ class LocalProcessProvider(NodeProvider):
             argv += ["--spool-dir", self.spool_dir]
         if self.etc_dir:
             argv += ["--etc-dir", self.etc_dir]
-        env = dict(os.environ)
-        env.update(self.extra_env)
         proc = subprocess.Popen(
             argv, stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, cwd=_REPO_ROOT, env=env,
+            stderr=subprocess.DEVNULL, cwd=_REPO_ROOT,
             start_new_session=True)
         ready: List[Optional[bytes]] = [None]
 

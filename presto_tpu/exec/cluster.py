@@ -45,6 +45,7 @@ the same way our ``retain=True`` output buffers do):
 """
 from __future__ import annotations
 
+import itertools
 import json
 import re
 import statistics
@@ -52,6 +53,7 @@ import threading
 import time
 import urllib.error
 import urllib.request
+import uuid
 from typing import Dict, List, Optional, Set, Tuple
 
 from ..connectors.spi import Split
@@ -973,7 +975,13 @@ class ClusterRunner:
                                  rows_per_batch=rows_per_batch)
         self.session = self.local.session
         self.rows_per_batch = rows_per_batch
-        self._seq = 0
+        #: query ids carry a token of this runner: a fleet's
+        #: coordinators number their queries alike and share one worker
+        #: pool, where tasks, spool directories and the end-of-query
+        #: DELETE are keyed by query id — two coordinators' n-th
+        #: queries in flight at once would own each other's tasks
+        self._qid_prefix = f"cq_{uuid.uuid4().hex[:8]}_"
+        self._seq = itertools.count(1)
         #: worker url -> node id learned from /v1/info (node federator)
         self._node_ids: Dict[str, str] = {}
         #: worker url -> last seen /v1/info state — the drain-aware
@@ -1378,8 +1386,7 @@ class ClusterRunner:
                        cancel_event=None, user: str = "") -> QueryResult:
         session = session if session is not None else self.session
         workers = self._schedulable_or_raise()
-        self._seq += 1
-        qid = f"cq_{self._seq:06d}"
+        qid = f"{self._qid_prefix}{next(self._seq):06d}"
         REGISTRY.counter("cluster_queries_total").inc()
         from ..connectors.system import QueryLogEntry
         from ..events import QueryCompletedEvent

@@ -190,7 +190,7 @@ def mesh_mode(session) -> str:
 def mesh_flight_on(session) -> bool:
     """Resolved ``mesh_flight`` switch: the session property when set,
     else the ``PRESTO_TPU_MESH_FLIGHT`` environment default, else on —
-    the recorder is cheap enough (asserted <1% in tests) to fly every
+    the recorder is cheap enough (a counted cost, in tests) to fly every
     mesh query."""
     v = session.properties.get("mesh_flight")
     if v is None:
@@ -1983,112 +1983,3 @@ def _host_col(typ, vocab):
 def _apply_remap(codes: np.ndarray, remap: np.ndarray) -> np.ndarray:
     idx = np.where(codes >= 0, codes, len(remap) - 1)
     return remap[idx]
-
-
-class DistributedRunner:
-    """LocalRunner's multi-shard sibling: same SQL surface, data sharded
-    over an n-device mesh (reference DistributedQueryRunner.java:76 boots N
-    servers; here N shards of SPMD programs — SURVEY.md §2d)."""
-
-    def __init__(self, catalogs=None, catalog: str = "tpch",
-                 schema: str = "default", tpch_sf: float = 0.01,
-                 n_devices: Optional[int] = None,
-                 rows_per_batch: int = 1 << 16):
-        from ..connectors.spi import CatalogManager
-        from ..connectors.tpch import TpchConnector
-        if catalogs is None:
-            from ..connectors.tpcds import TpcdsConnector
-            catalogs = CatalogManager()
-            catalogs.register("tpch", TpchConnector(sf=tpch_sf))
-            catalogs.register("tpcds", TpcdsConnector(sf=tpch_sf))
-        self.session = Session(catalogs=catalogs, catalog=catalog,
-                               schema=schema)
-        self.mesh = make_mesh(n_devices)
-        self.rows_per_batch = rows_per_batch
-        self._seq = 0
-
-    def execute(self, sql: str,
-                properties: Optional[Dict[str, object]] = None,
-                user: str = "", cancel_event=None) -> QueryResult:
-        """Run one query on the mesh. The keyword surface matches
-        ``ClusterRunner.execute``: ``properties`` overlays per-query
-        session properties — validated through the declared registry,
-        so an unknown or mistyped property fails the query instead of
-        silently doing nothing on the SPMD path — ``user`` scopes the
-        history record, and ``cancel_event`` interrupts between
-        batches. SELECTs ride the compiled-plan cache
-        (serving/plancache.py): a repeated statement skips
-        parse/plan/optimize straight onto warm shard_map executables."""
-        from ..serving.plancache import cached_plan, parse_cached
-        from ..sql import ast as A
-        stmt = parse_cached(sql)
-        if not isinstance(stmt, A.Query):
-            raise NotImplementedError(
-                "DistributedRunner serves queries; use LocalRunner for "
-                "session statements")
-        session = self.session
-        if properties:
-            from ..config import validate_session_property
-            overlay = {k: validate_session_property(k, v)
-                       for k, v in properties.items()}
-            session = dataclasses.replace(
-                session,
-                properties={**session.properties, **overlay})
-        self._seq += 1
-        qid = f"dq_{self._seq:06d}"
-        import time as _time
-        from ..obs.history import HISTORY
-        t0 = _time.perf_counter()
-        create_time = _time.time()
-        error: Optional[str] = None
-        rows = None
-        flight = None
-        fl_token = None
-        if mesh_flight_on(session):
-            flight = _flight.FlightRecorder(
-                qid, int(self.mesh.devices.size))
-            fl_token = _flight.CURRENT_FLIGHT.set(flight)
-        try:
-            with TRACER.span("query", query_id=qid, user=user,
-                             mode="spmd", shards=self.mesh.devices.size):
-                with TRACER.span("plan"):
-                    plan = cached_plan(stmt, session, user=user)
-                from .local import run_init_plans
-                ex = DistributedExecutor(session,
-                                         self.rows_per_batch, self.mesh)
-                ex.cancel_event = cancel_event
-                run_init_plans(ex, plan)
-                root = plan.root
-                batches = []
-                for b in ex.run(root.child):
-                    ex._check_cancel()
-                    batches.append(b)
-                ex.check_errors()
-                with _sync_record("result-gather", kind="drain"):
-                    rows = [r for b in batches for r in b.to_pylist()]
-            return QueryResult(names=[f.name for f in root.fields],
-                               types=[f.type for f in root.fields],
-                               rows=rows)
-        except Exception as e:
-            error = str(e)
-            raise
-        finally:
-            record = {
-                "query_id": qid, "query": sql.strip(), "user": user,
-                "state": "FAILED" if error is not None else "FINISHED",
-                "error": error, "create_time": create_time,
-                "elapsed_ms": round(
-                    (_time.perf_counter() - t0) * 1e3, 3),
-                "rows": None if rows is None else len(rows),
-                "mode": "spmd",
-            }
-            if flight is not None:
-                _flight.CURRENT_FLIGHT.reset(fl_token)
-                attr = flight.finish(_time.perf_counter() - t0)
-                record.update(_flight.history_fields(attr))
-            # the SPMD path has no EventListenerManager; feed the
-            # persistent query history directly so
-            # system.runtime.completed_queries covers all three
-            # executors (with the caller's user for audit attribution,
-            # like the cluster path)
-            HISTORY.add(record)

@@ -57,45 +57,6 @@ LEVEL_THRESHOLDS = (0.0, 1.0, 10.0, 60.0, 300.0)
 _MAX_SHARES = 256
 
 
-def _service_floor_s() -> float:
-    """Modeled per-quantum device-service floor, seconds
-    (``PRESTO_TPU_DEVICE_FLOOR_MS``). Zero (the default) is a no-op.
-
-    When set, every quantum holds the device for at least this long —
-    a fixed-throughput device model, the same spirit as the object
-    spool's modeled RTT/bandwidth. Elasticity benches set it on their
-    WORKER processes so per-worker capacity is the bottleneck even on
-    a single-core host, where real multi-process compute cannot
-    overlap and throughput could never track the worker count."""
-    import os
-    try:
-        return max(0.0, float(
-            os.environ.get("PRESTO_TPU_DEVICE_FLOOR_MS", "0") or 0)
-            / 1e3)
-    except ValueError:
-        return 0.0
-
-
-_SERVICE_FLOOR_S = _service_floor_s()
-
-
-def device_floor_pad(elapsed_s: float = 0.0) -> None:
-    """Pad one fused kernel chain up to the modeled device-service
-    floor (no-op unless ``PRESTO_TPU_DEVICE_FLOOR_MS`` is set).
-
-    ``run_quantum`` applies this to each task OUTPUT page, but a source
-    task's device work is proportional to the batches it SCANS, and the
-    output buffer coalesces those (filters and partial aggregates can
-    collapse a whole partition into one output page). Scan paths call
-    this per input batch, from inside the owning quantum, so modeled
-    per-worker capacity tracks the rows a worker actually processes —
-    which is what shrinks when the pool scales out."""
-    if _SERVICE_FLOOR_S > 0.0:
-        pad = _SERVICE_FLOOR_S - elapsed_s
-        if pad > 0.0:
-            time.sleep(pad)
-
-
 R = TypeVar("R")
 
 
@@ -274,12 +235,7 @@ class DeviceScheduler:
                             level=handle.level)
                 if TRACER.enabled else None)
         try:
-            result = fn()
-            # fixed-throughput device model: pad the quantum to the
-            # floor while HOLDING the device, so capacity is
-            # per-worker and additive across workers
-            device_floor_pad(time.perf_counter() - t0)
-            return result
+            return fn()
         finally:
             dt = time.perf_counter() - t0
             if span is not None:

@@ -13,8 +13,8 @@ plus a cross-round critical path per shard.
 Design constraints, in order:
 
 - **Cheap.** ``record()`` is one perf_counter read and one list
-  append under a lock; a query producing thousands of rounds must
-  stay under 1% of its wall (asserted in tests/test_mesh_flight.py).
+  append under a lock: a fixed count of calls however many records
+  the flight holds (asserted in tests/test_mesh_flight.py).
   No device work, no allocation beyond the record dict.
 - **Honest.** The buckets are *host-blocking wall* observed at each
   instrumentation site; async device time the host never waits for is

@@ -378,11 +378,8 @@ def slo_block(store: Optional[TimeSeriesStore] = None,
     burn/budget/state, every alert transition, and the per-evaluation
     burn timeline (windowed p95 alongside, for latency objectives).
 
-    One builder serves both consumers — ``bench.py serving`` pins it
-    into SERVING_r*.json and the coordinator serves it live on
-    ``GET /v1/slo`` (the fleet bench merges one block per coordinator).
-    Schema is owned by tools/slo_report.py — check_bench_regression
-    --kind serving validates every pin through it."""
+    The coordinator serves it live on ``GET /v1/slo``. Schema is owned
+    by tools/slo_report.py."""
     store = store if store is not None else TIMESERIES
     tracker = tracker if tracker is not None else SLO
     tracker.evaluate()  # flush a final point so the timeline ends "now"

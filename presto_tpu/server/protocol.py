@@ -1049,8 +1049,8 @@ class PrestoTpuServer:
             # overflows socketserver's default listen backlog of FIVE:
             # dropped SYNs retransmit on the kernel's 1s/3s timers and
             # every affected query's latency quantizes to whole
-            # seconds. Found load-testing SERVING_r02; sized well past
-            # any bench fleet.
+            # seconds. Found under a 100-client load; sized well past
+            # any fleet.
             request_queue_size = 1024
 
             # live client sockets, tracked so kill() can reset them:

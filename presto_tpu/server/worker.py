@@ -627,7 +627,7 @@ class _TaskExecutor(local_exec._Executor):
         # queries hit device memory on every node, and cold splits
         # decode/stage on background threads while this task's kernels
         # run (exec/scancache.py)
-        from ..exec import scancache, taskexec
+        from ..exec import scancache
         conn = self.session.catalogs.get(node.catalog)
         opts = scancache.options_from_session(self.session)
         it = scancache.scan_splits(
@@ -635,18 +635,7 @@ class _TaskExecutor(local_exec._Executor):
             list(self.assigned_splits), self._scan_pushdown_fn(node),
             self.rows_per_batch, opts, stats=self.stats,
             static_pushdown=node.pushdown or None)
-        # modeled device floor per SCANNED batch (no-op unless
-        # PRESTO_TPU_DEVICE_FLOOR_MS is set): the output buffer above
-        # this node coalesces pages, so the quantum-level floor alone
-        # would bill a worker by what it EMITS, not what it processes
-        sentinel = object()
-        while True:
-            t0 = time.perf_counter()
-            b = next(it, sentinel)
-            if b is sentinel:
-                return
-            taskexec.device_floor_pad(time.perf_counter() - t0)
-            yield b
+        yield from it
 
     def _RemoteSourceNode(self, node) -> Iterator[Batch]:
         locations: List[str] = []

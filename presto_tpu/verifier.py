@@ -4,9 +4,9 @@ The role of presto-verifier (reference
 presto-verifier/.../verifier/Verifier.java + Validator.java:68 — run
 each query on a control and a test cluster, normalize, diff, report
 MATCH / MISMATCH / failures). Runners are anything with
-``execute(sql) -> QueryResult`` (LocalRunner, DistributedRunner,
-ClusterRunner, StatementClient wrapper), so the same harness validates
-local-vs-SPMD, local-vs-cluster, or version-vs-version.
+``execute(sql) -> QueryResult`` (LocalRunner, ClusterRunner,
+StatementClient wrapper), so the same harness validates
+local-vs-mesh, local-vs-cluster, or version-vs-version.
 """
 from __future__ import annotations
 
@@ -98,11 +98,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     with open(args.queries, encoding="utf-8") as f:
         queries = [q.strip() for q in f.read().split(";") if q.strip()]
     control = LocalRunner(tpch_sf=args.tpch_sf)
+    test = LocalRunner(catalogs=control.session.catalogs)
     if args.test == "distributed":
-        from .exec.distributed import DistributedRunner
-        test = DistributedRunner(catalogs=control.session.catalogs)
-    else:
-        test = LocalRunner(tpch_sf=args.tpch_sf)
+        # the mesh over every visible device, through the same door
+        test.execute("SET SESSION mesh_execution = 'on'")
     results = Verifier(control, test).run(queries)
     for r in results:
         print(f"{r.status:15s} {r.control_ms:8.1f}ms {r.test_ms:8.1f}ms  "

@@ -46,3 +46,24 @@ os.environ.setdefault("PRESTO_TPU_MESH_EXECUTION", "off")
 import presto_tpu  # noqa: E402
 
 presto_tpu.enable_compile_cache()
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="session")
+def mesh_runner():
+    """Factory of runners whose every query runs on the mesh, through
+    the door the four-chip cell takes (``LocalRunner.execute`` ->
+    ``execute_plan`` -> ``select_mesh`` -> ``DistributedExecutor``).
+    Under ``mesh_execution = 'on'`` a plan the fragmenter refuses
+    raises, so a test that passes has been on the mesh.
+    ``n_devices`` 0 is every visible device (the 8 above)."""
+    from presto_tpu.exec.runner import LocalRunner
+
+    def make(catalogs=None, n_devices=0, rows_per_batch=1 << 16, **kw):
+        r = LocalRunner(catalogs=catalogs, rows_per_batch=rows_per_batch,
+                        **kw)
+        r.execute("SET SESSION mesh_execution = 'on'")
+        r.execute(f"SET SESSION mesh_devices = {int(n_devices)}")
+        return r
+    return make

@@ -212,9 +212,8 @@ def test_element_at_index_zero_errors(runner):
         runner.execute("select element_at(array[1, 2], 0)")
 
 
-def test_distributed_unnest():
-    from presto_tpu.exec.distributed import DistributedRunner
-    d = DistributedRunner(tpch_sf=0.001, n_devices=8)
+def test_distributed_unnest(mesh_runner):
+    d = mesh_runner(tpch_sf=0.001, n_devices=8)
     rows = d.execute(
         "select sum(x) from nation, "
         "unnest(array[n_nationkey, n_regionkey]) as u(x)").rows

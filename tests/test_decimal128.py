@@ -21,12 +21,9 @@ def runner():
 
 
 @pytest.fixture(scope="module")
-def dist():
-    from presto_tpu.exec.distributed import DistributedRunner
-    from presto_tpu.exec.runner import LocalRunner
-    r = LocalRunner(tpch_sf=0.001)
-    return DistributedRunner(catalogs=r.session.catalogs,
-                             n_devices=8, rows_per_batch=1 << 10)
+def dist(mesh_runner):
+    return mesh_runner(tpch_sf=0.001, n_devices=8,
+                       rows_per_batch=1 << 10)
 
 
 # -- kernel-level oracle ----------------------------------------------------

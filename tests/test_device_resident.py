@@ -17,7 +17,6 @@ import pytest
 # (see tools/check_tier1_time.py; ~77s)
 pytestmark = pytest.mark.slow
 
-from presto_tpu.exec.distributed import DistributedRunner
 from presto_tpu.exec.runner import LocalRunner
 
 SF = 0.01
@@ -51,9 +50,9 @@ def local():
 
 
 @pytest.fixture(scope="module")
-def dist(local):
-    return DistributedRunner(catalogs=local.session.catalogs,
-                             rows_per_batch=1 << 13)
+def dist(local, mesh_runner):
+    return mesh_runner(catalogs=local.session.catalogs,
+                       rows_per_batch=1 << 13)
 
 
 @pytest.mark.parametrize("sql", GUARDED_QUERIES)

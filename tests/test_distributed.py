@@ -6,7 +6,6 @@ path exactly.
 """
 import pytest
 
-from presto_tpu.exec.distributed import DistributedRunner
 from presto_tpu.exec.runner import LocalRunner
 
 from tpch_queries import Q as TPCH_QUERIES
@@ -29,9 +28,9 @@ def local():
 
 
 @pytest.fixture(scope="module")
-def dist(local):
-    return DistributedRunner(catalogs=local.session.catalogs,
-                             rows_per_batch=1 << 13)
+def dist(local, mesh_runner):
+    return mesh_runner(catalogs=local.session.catalogs,
+                       rows_per_batch=1 << 13)
 
 
 def _norm(rows, has_order):

@@ -9,7 +9,6 @@ benchto SQL). Parity with LocalRunner is the contract.
 """
 import pytest
 
-from presto_tpu.exec.distributed import DistributedRunner
 from presto_tpu.exec.runner import LocalRunner
 
 # minutes of shard_map compiles even with a warm persistent cache: out
@@ -32,9 +31,9 @@ def local():
 
 
 @pytest.fixture(scope="module")
-def dist(local):
-    return DistributedRunner(catalogs=local.session.catalogs,
-                             catalog="tpcds", rows_per_batch=1 << 13)
+def dist(local, mesh_runner):
+    return mesh_runner(catalogs=local.session.catalogs,
+                       catalog="tpcds", rows_per_batch=1 << 13)
 
 
 @pytest.mark.parametrize(

@@ -131,9 +131,8 @@ def test_join_residual_error(runner):
             "and l.l_partkey > o.o_orderkey / o.o_shippriority")
 
 
-def test_distributed_division_by_zero():
-    from presto_tpu.exec.distributed import DistributedRunner
-    r = DistributedRunner(tpch_sf=0.001, n_devices=8)
+def test_distributed_division_by_zero(mesh_runner):
+    r = mesh_runner(tpch_sf=0.001, n_devices=8)
     with pytest.raises(QueryError, match="DIVISION_BY_ZERO"):
         r.execute("select l_orderkey/(l_linenumber - l_linenumber) "
                   "from lineitem")

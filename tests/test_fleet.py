@@ -3,7 +3,7 @@
 Two statement servers ("coordinators") in one process, each over its
 OWN LocalRunner and OWN CatalogManager, sharing one writable sqlite
 catalog file — the in-process stand-in for a multi-process fleet (the
-subprocess version runs in bench.py's fleet mode and the chaos drill).
+subprocess version runs in the chaos drill).
 Connector identity keeps the stand-in honest: each coordinator's
 caches stamp deps against its own connector OBJECT, so a write through
 A can only reach B's template/result entries via the fleet bump
@@ -276,3 +276,27 @@ def test_explicit_deregister_beats_the_staleness_grace():
     assert st["lost"] == ["coord-kill"]
     assert survivor.remote_running("serving") == 0
     assert _metric("coordinator_lost_total") == lost0 + 1
+
+
+def test_coordinators_over_one_worker_pool_number_queries_apart():
+    """A fleet's coordinators share their workers, where tasks, spool
+    directories and the end-of-query DELETE are keyed by query id: two
+    ClusterRunners must never give their n-th queries one id, or one
+    coordinator's clean-up takes the other's rows
+    (``test_fleet_coordinator_kill``, about one run in ten)."""
+    from presto_tpu.exec.cluster import ClusterRunner
+    from presto_tpu.server.worker import WorkerServer
+    worker = WorkerServer(tpch_sf=0.001)
+    worker.start()
+    try:
+        url = f"http://127.0.0.1:{worker.port}"
+        sql = "select count(*) from nation"
+        ids = []
+        for _ in range(2):
+            r = ClusterRunner([url], tpch_sf=0.001, heartbeat=False)
+            assert [tuple(x) for x in r.execute(sql).rows] == [(25,)]
+            ids.append([e.query_id for e in r.local.query_log
+                        if e.query_id.startswith("cq_")][-1])
+        assert ids[0] != ids[1]
+    finally:
+        worker.stop()

@@ -10,10 +10,6 @@ these suites pay shard_map compiles; every test here opts back in per
 query through the session-property overlay, which is exactly the
 production surface.
 """
-import os
-import subprocess
-import sys
-
 import jax
 import pytest
 
@@ -21,7 +17,6 @@ from presto_tpu.exec.runner import LocalRunner
 from presto_tpu.obs.metrics import REGISTRY
 
 SF = 0.005
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 AUTO = {"mesh_execution": "auto"}
 OFF = {"mesh_execution": "off"}
@@ -248,30 +243,32 @@ def test_cluster_workerless_rides_mesh(runner):
     assert _metric("mesh_path_selected_total") == before + 1
 
 
-def test_distributed_runner_surface(runner):
-    """DistributedRunner.execute surface parity: properties validate
-    through the registry, user lands in the history record, a pre-set
-    cancel event interrupts."""
+def test_mesh_runner_surface(runner, mesh_runner):
+    """The statement surface holds on the mesh through the one front
+    door (``LocalRunner.execute`` under ``mesh_execution = 'on'``): the
+    shared fixture's first query takes the mesh path, per-query
+    properties overlay, user lands in the history record, a pre-set
+    cancel event interrupts. (An unknown property is refused where
+    LocalRunner's callers validate: test_analyze's SET SESSION and
+    test_protocol's header tests.)"""
     import threading
 
-    from presto_tpu.config import SessionPropertyError
     from presto_tpu.errors import QueryCancelledError
-    from presto_tpu.exec.distributed import DistributedRunner
     from presto_tpu.obs.history import HISTORY
-    dr = DistributedRunner(catalogs=runner.session.catalogs,
-                           n_devices=2, rows_per_batch=1 << 11)
-    res = dr.execute("select count(*) from nation",
+    mr = mesh_runner(catalogs=runner.session.catalogs,
+                     n_devices=2, rows_per_batch=1 << 11)
+    before = _metric("mesh_path_selected_total")
+    res = mr.execute("select count(*) from nation",
                      properties={"dense_grouping": True}, user="audit")
     assert _norm(res.rows) == [(25,)]
-    rec = [h for h in HISTORY.snapshot() if h.get("mode") == "spmd"][-1]
-    assert rec["user"] == "audit"
-    with pytest.raises(SessionPropertyError):
-        dr.execute("select count(*) from nation",
-                   properties={"not_a_property": 1})
+    assert _metric("mesh_path_selected_total") == before + 1
+    rec = [h for h in HISTORY.snapshot() if h.get("user") == "audit"][-1]
+    assert rec["query"] == "select count(*) from nation"
+    assert rec["state"] == "FINISHED"
     ev = threading.Event()
     ev.set()
     with pytest.raises(QueryCancelledError):
-        dr.execute("select count(*) from region", cancel_event=ev)
+        mr.execute("select count(*) from region", cancel_event=ev)
 
 
 def test_mesh_execution_property_validates():
@@ -317,17 +314,3 @@ def test_per_chip_billing(runner):
     dq = _metric("scheduler_quanta_total") - bq
     dchip = _metric("scheduler_chip_quanta_total") - before
     assert dq > 0 and dchip == 2 * dq
-
-
-def test_multichip_gate_smoke():
-    """check_bench_regression --kind multichip --smoke: the committed
-    MULTICHIP_r*.json pin parses, passes against itself, and a
-    degraded copy fails — the tier-1 guard that the mesh-scaling gate
-    cannot rot."""
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(_REPO, "tools", "check_bench_regression.py"),
-         "--kind", "multichip", "--smoke"],
-        capture_output=True, text=True, cwd=_REPO)
-    assert out.returncode == 0, out.stdout + out.stderr
-    assert '"verdict": "pass"' in out.stdout

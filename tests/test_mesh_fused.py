@@ -108,8 +108,7 @@ def test_fused_parity_under_forced_resplit(runner, monkeypatch):
 def test_fused_slashes_host_dispatches(small_runner):
     """The dispatch-tax claim at suite scale: the same grouped
     aggregation costs at most half the host dispatches fused vs
-    unfused (the bench pin MULTICHIP_r08 carries the >= 3x evidence at
-    bench scale; in-suite the guard is a conservative 2x). Warm runs
+    unfused (in-suite the guard is a conservative 2x). Warm runs
     are compared so plan/compile effects cancel."""
     sql = SHAPES[1][1]
     base = {**ON, "mesh_devices": 4}

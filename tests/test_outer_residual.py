@@ -50,10 +50,9 @@ def test_outer_residual_matches_oracle(runner, oracle, sql):
     compare(runner, oracle, sql, rel=1e-9)
 
 
-def test_outer_residual_distributed(runner):
-    from presto_tpu.exec.distributed import DistributedRunner
-    dist = DistributedRunner(catalogs=runner.session.catalogs,
-                             n_devices=8, rows_per_batch=1 << 12)
+def test_outer_residual_distributed(runner, mesh_runner):
+    dist = mesh_runner(catalogs=runner.session.catalogs,
+                       n_devices=8, rows_per_batch=1 << 12)
     for sql in (QUERIES[0], QUERIES[3]):
         want = runner.execute(sql).rows
         got = dist.execute(sql).rows

@@ -336,69 +336,6 @@ def test_history_sink_unbounded_when_disabled(tmp_path):
     assert len(sink.read_text().splitlines()) == 50
 
 
-# -- regression gate (satellite: --smoke runs inside tier-1) ------------------
-
-def test_check_bench_regression_smoke():
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(_TOOLS, "check_bench_regression.py"), "--smoke"],
-        capture_output=True, text=True)
-    assert out.returncode == 0, out.stdout + out.stderr
-    verdict = json.loads(out.stdout)
-    assert verdict["verdict"] == "pass"
-    assert verdict["self_comparison"] == "pass"
-    assert verdict["degraded_comparison"] == "fail"
-
-
-def test_check_bench_regression_catches_drop(tmp_path):
-    baseline = {"metric": "m_q1_x", "value": 100, "vs_baseline": 10.0,
-                "sub_metrics": [
-                    {"metric": "m_q3_x", "value": 50,
-                     "vs_baseline": 2.0}]}
-    run = {"metric": "m_q1_x", "value": 100, "vs_baseline": 10.0,
-           "sub_metrics": [
-               {"metric": "m_q3_x", "value": 20, "vs_baseline": 0.8}]}
-    bp, rp = tmp_path / "base.json", tmp_path / "run.json"
-    bp.write_text(json.dumps(baseline))
-    rp.write_text(json.dumps(run))
-    tool = os.path.join(_TOOLS, "check_bench_regression.py")
-    out = subprocess.run(
-        [sys.executable, tool, "--baseline", str(bp), "--run", str(rp)],
-        capture_output=True, text=True)
-    assert out.returncode == 1
-    verdict = json.loads(out.stdout)
-    assert verdict["failed"] == ["m_q3_x"]
-    # a generous per-query tolerance lets the same run pass
-    out2 = subprocess.run(
-        [sys.executable, tool, "--baseline", str(bp), "--run", str(rp),
-         "--tolerance-for", "q3=70"],
-        capture_output=True, text=True)
-    assert out2.returncode == 0, out2.stdout
-
-
-def test_check_bench_regression_log_mode(tmp_path):
-    """A captured stdout log (noise + several summary lines) parses to
-    the LAST summary."""
-    lines = [
-        "[bench] q6 starting",
-        json.dumps({"metric": "m_q1_x", "vs_baseline": 1.0,
-                    "sub_metrics": []}),
-        json.dumps({"metric": "m_q1_x", "vs_baseline": 10.0,
-                    "sub_metrics": [{"metric": "m_q6_x",
-                                     "vs_baseline": 5.0}]}),
-    ]
-    rp = tmp_path / "log.txt"
-    rp.write_text("\n".join(lines))
-    bp = tmp_path / "base.json"
-    bp.write_text(lines[-1])
-    out = subprocess.run(
-        [sys.executable,
-         os.path.join(_TOOLS, "check_bench_regression.py"),
-         "--baseline", str(bp), "--run", str(rp)],
-        capture_output=True, text=True)
-    assert out.returncode == 0, out.stdout
-
-
 # -- doc drift (satellite) ----------------------------------------------------
 
 def test_metric_doc_drift_check_green():

@@ -442,112 +442,6 @@ def test_shared_scan_owner_failure_recovers():
     CACHE.finish_inflight(key, None)
 
 
-# -- serving regression gate --------------------------------------------------
-
-def test_serving_regression_gate_smoke(capsys):
-    """ISSUE 9 satellite: the bench gate also covers the committed
-    SERVING_r*.json — self-comparison passes, a degraded copy fails."""
-    from tools.check_bench_regression import main
-    assert main(["--kind", "serving", "--smoke"]) == 0
-    import json
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["verdict"] == "pass"
-    assert doc["self_comparison"] == "pass"
-    assert doc["degraded_comparison"] == "fail"
-    assert any("qps" in m for m in doc["metrics"])
-    # ISSUE 13: the r02+ pins carry the template/result hit-rate keys —
-    # the gate must cover them (a halved hit rate fails the degraded
-    # comparison above)
-    assert any("template_hit_rate" in m for m in doc["metrics"])
-    assert any("result_hit_rate" in m for m in doc["metrics"])
-    # ISSUE 18: r03+ pins carry the health plane's slo block — smoke
-    # schema-validates it through tools/slo_report.py (objectives,
-    # burn timeline with windowed p95, alert transitions)
-    assert doc["slo"]["ok"], doc["slo"]["violations"]
-    assert doc["slo"]["blocks"] == 1
-    # ISSUE 19: r04+ pins carry the fleet block — smoke validates its
-    # invariants (balanced per-coordinator qps summing to the
-    # aggregate, zero failed queries through the kill drill, row-exact
-    # cross-coordinator coherence)
-    assert doc["fleet"]["ok"], doc["fleet"]["violations"]
-    assert doc["fleet"]["blocks"] == 1
-
-
-def test_serving_gate_latency_metrics_invert():
-    """p95 latency regresses by going UP: the gate inverts *_ms
-    ratios, so a doubled latency fails and a halved one passes."""
-    from tools.check_bench_regression import compare
-    base = {"serving_qps": {"metric": "serving_qps", "value": 100.0},
-            "serving_p95_latency_ms": {
-                "metric": "serving_p95_latency_ms", "value": 50.0}}
-    slower = {"serving_qps": {"metric": "serving_qps", "value": 100.0},
-              "serving_p95_latency_ms": {
-                  "metric": "serving_p95_latency_ms", "value": 100.0}}
-    faster = {"serving_qps": {"metric": "serving_qps", "value": 100.0},
-              "serving_p95_latency_ms": {
-                  "metric": "serving_p95_latency_ms", "value": 25.0}}
-    assert compare(base, slower)["verdict"] == "fail"
-    assert compare(base, faster)["verdict"] == "pass"
-
-
-def _good_fleet_block():
-    """A fleet block shaped exactly like bench_serving_fleet's."""
-    return {
-        "coordinators": 3,
-        "workers": 1,
-        "per_coordinator_qps": {"coord-0": 300.0, "coord-1": 310.0,
-                                "coord-2": 290.0},
-        "aggregate_qps": 900.0,
-        "client_failovers": 0,
-        "coherence": {"bump_fold_delta": 1.0,
-                      "remote_invalidation_observed": True,
-                      "xcoord_result_cache_hits": 1,
-                      "rows_before": [[1, 1]], "rows_after": [[2, 3]],
-                      "row_exact": True},
-        "kill": {"killed": "coord-2", "queries": 128,
-                 "failed_queries": 0, "client_failovers": 3,
-                 "client_retries": 3, "coordinator_lost_total": 2.0,
-                 "survivor_lost_view": ["coord-2"]},
-    }
-
-
-def test_fleet_gate_invariants():
-    """ISSUE 19: the serving gate's fleet block — the good block
-    passes, a pin without one passes vacuously, and every violated
-    invariant (too-small fleet, idle member, aggregate drift, missing
-    coherence proof, failed kill drill) fails."""
-    import copy
-
-    from tools.check_bench_regression import _fleet_gate
-
-    flat = {"serving_qps": {"metric": "serving_qps", "value": 900.0,
-                            "fleet": _good_fleet_block()}}
-    v = _fleet_gate(flat)
-    assert v["ok"] and v["blocks"] == 1, v
-    vac = _fleet_gate({"m": {"metric": "m", "value": 1.0}})
-    assert vac["ok"] and vac["blocks"] == 0
-
-    mutations = [
-        lambda fl: fl.update(coordinators=2),
-        lambda fl: fl["per_coordinator_qps"].update({"coord-1": 0.0}),
-        lambda fl: fl["per_coordinator_qps"].pop("coord-1"),
-        lambda fl: fl.update(aggregate_qps=2000.0),
-        lambda fl: fl["coherence"].update(
-            remote_invalidation_observed=False),
-        lambda fl: fl["coherence"].update(row_exact=False),
-        lambda fl: fl["coherence"].update(xcoord_result_cache_hits=0),
-        lambda fl: fl.pop("coherence"),
-        lambda fl: fl["kill"].update(failed_queries=2),
-        lambda fl: fl["kill"].update(coordinator_lost_total=0.0),
-        lambda fl: fl["kill"].update(survivor_lost_view=[]),
-        lambda fl: fl.pop("kill"),
-    ]
-    for mut in mutations:
-        f = copy.deepcopy(flat)
-        mut(f["serving_qps"]["fleet"])
-        assert not _fleet_gate(f)["ok"], mut
-
-
 # -- cluster path through admission + plan cache (ISSUE 10 satellite) ---------
 
 def test_cluster_runner_through_admission_and_plan_cache():

@@ -11,10 +11,9 @@ def runner():
 
 
 @pytest.fixture(scope="module")
-def dist(runner):
-    from presto_tpu.exec.distributed import DistributedRunner
-    return DistributedRunner(catalogs=runner.session.catalogs,
-                             n_devices=8, rows_per_batch=1 << 12)
+def dist(runner, mesh_runner):
+    return mesh_runner(catalogs=runner.session.catalogs,
+                       n_devices=8, rows_per_batch=1 << 12)
 
 
 def test_intersect(runner):

@@ -172,20 +172,3 @@ def test_stalled_without_held_quantum_is_a_noop():
     assert REGISTRY.counter(
         "device_stall_release_total").value == before
     assert s._running is None and s._running_depth == 0
-
-
-def test_device_floor_pad_models_fixed_throughput(monkeypatch):
-    """The modeled device-service floor pads a kernel chain up to the
-    floor and never double-bills work that already took longer."""
-    import presto_tpu.exec.taskexec as tx
-    monkeypatch.setattr(tx, "_SERVICE_FLOOR_S", 0.05)
-    t0 = time.perf_counter()
-    tx.device_floor_pad(0.0)
-    assert time.perf_counter() - t0 >= 0.045
-    t0 = time.perf_counter()
-    tx.device_floor_pad(10.0)         # chain already past the floor
-    assert time.perf_counter() - t0 < 0.02
-    monkeypatch.setattr(tx, "_SERVICE_FLOOR_S", 0.0)
-    t0 = time.perf_counter()
-    tx.device_floor_pad(0.0)          # disabled: free
-    assert time.perf_counter() - t0 < 0.02

@@ -35,10 +35,9 @@ def test_window(runner, oracle, sql):
     compare(runner, oracle, sql, rel=1e-9)
 
 
-def _window_distributed(runner, queries):
-    from presto_tpu.exec.distributed import DistributedRunner
-    dist = DistributedRunner(catalogs=runner.session.catalogs,
-                             rows_per_batch=1 << 13)
+def _window_distributed(runner, mesh_runner, queries):
+    dist = mesh_runner(catalogs=runner.session.catalogs,
+                       rows_per_batch=1 << 13)
     for sql in queries:
         want = runner.execute(sql)
         got = dist.execute(sql)
@@ -53,15 +52,15 @@ def _window_distributed(runner, queries):
         assert len(g) == len(w2)
 
 
-def test_window_distributed(runner):
+def test_window_distributed(runner, mesh_runner):
     # tier-1 smoke: two shapes through the distributed exchange; the
     # remaining sweep rides the slow lane (tier-1 wall budget)
-    _window_distributed(runner, WINDOW_QUERIES[:2])
+    _window_distributed(runner, mesh_runner, WINDOW_QUERIES[:2])
 
 
 @pytest.mark.slow
-def test_window_distributed_sweep(runner):
-    _window_distributed(runner, WINDOW_QUERIES[2:6])
+def test_window_distributed_sweep(runner, mesh_runner):
+    _window_distributed(runner, mesh_runner, WINDOW_QUERIES[2:6])
 
 
 # -- explicit frames (reference operator/window/FrameInfo.java) --------------
@@ -100,10 +99,9 @@ def test_window_frames(runner, oracle, sql):
     compare(runner, oracle, sql, rel=1e-9)
 
 
-def test_window_frames_distributed(runner):
-    from presto_tpu.exec.distributed import DistributedRunner
-    dist = DistributedRunner(catalogs=runner.session.catalogs,
-                             n_devices=8, rows_per_batch=1 << 12)
+def test_window_frames_distributed(runner, mesh_runner):
+    dist = mesh_runner(catalogs=runner.session.catalogs,
+                       n_devices=8, rows_per_batch=1 << 12)
     for sql in (FRAME_QUERIES[0], FRAME_QUERIES[11]):
         want = runner.execute(sql).rows
         got = dist.execute(sql).rows

@@ -115,8 +115,7 @@ def run_changed(files: List[str], root: Optional[str] = None,
     if caches.FLEET_MODULE in changed:
         findings.extend(caches.fleet_findings(root))
     # registries, use->declaration direction only
-    py = scoped(["presto_tpu", "tools", "bench.py",
-                 "__graft_entry__.py"])
+    py = scoped(["presto_tpu", "tools", "__graft_entry__.py"])
     if py:
         findings.extend(registries.metric_findings(
             [os.path.relpath(p, root) for p in py

@@ -8,8 +8,7 @@ Modes:
   working-tree delta only; registry rules one-way unless a declaring
   input changed; stale detection skipped) — the pre-commit loop;
 - ``--format json``: machine-readable verdict on stdout for CI
-  tooling, same shape as tools/check_bench_regression.py's output
-  discipline (one JSON document, ``ok`` is the gate).
+  tooling (one JSON document, ``ok`` is the gate).
 """
 from __future__ import annotations
 

@@ -30,8 +30,8 @@ round-trip against the code:
   supported).
 - **environment variables** (``presto_tpu/config.py`` ENV_VARS): every
   ``os.environ.get/[...]/setdefault`` / ``os.getenv`` read of a
-  ``PRESTO_TPU_*`` or ``BENCH_*`` name anywhere in the engine, tools,
-  or bench must resolve to a declared entry; declared entries must be
+  ``PRESTO_TPU_*`` or ``BENCH_*`` name anywhere in the engine or the
+  tools must resolve to a declared entry; declared entries must be
   read somewhere; and the table in docs/static_analysis.md round-trips
   two-way like the metric families. An undeclared env knob is the
   worst registry typo: it "works" on the machine that exports it and
@@ -626,7 +626,7 @@ ENV_ENFORCED_PREFIXES = ("PRESTO_TPU_", "BENCH_")
 
 #: where env vars are read (the production surface; tests may export
 #: whatever their harness needs)
-ENV_SCAN = ("presto_tpu", "tools", "bench.py", "__graft_entry__.py")
+ENV_SCAN = ("presto_tpu", "tools", "__graft_entry__.py")
 
 
 def declared_env_vars(config_path: str) -> Dict[str, int]:

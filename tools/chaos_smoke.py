@@ -45,11 +45,6 @@ Run directly (prints a JSON summary) or from the tier-1 suite
 (tests/test_chaos.py):
 
     JAX_PLATFORMS=cpu python tools/chaos_smoke.py [--sf 0.01]
-
-``--elastic-out PATH`` (or the ``ELASTIC_OUT`` env var) additionally
-writes a bench-style summary of per-scenario recovery times, gated by
-``tools/check_bench_regression.py --kind elastic`` against the
-committed ``ELASTIC_r*.json``.
 """
 from __future__ import annotations
 
@@ -67,15 +62,6 @@ QUERY = ("select l_returnflag, l_linestatus, count(*) c, "
          "sum(l_quantity) q, sum(l_extendedprice) e from lineitem "
          "where l_shipdate <= date '1998-09-02' "
          "group by 1, 2 order by 1, 2")
-
-# the load-ramp bench's query: a selective SCAN, not an aggregate. Its
-# device cost is the batches scanned (input-proportional — that's what
-# shrinks per worker as the pool grows), while its tiny result keeps
-# exchange/sort/client cost flat. An aggregate collapses each task to
-# ~one output page, so modeled per-worker cost would never scale.
-RAMP_QUERY = ("select l_orderkey, l_linenumber, l_extendedprice "
-              "from lineitem where l_extendedprice > 90000 "
-              "order by 1, 2")
 
 
 def _metric_sql(runner, name: str) -> float:
@@ -683,9 +669,7 @@ def run_chaos(sf: float = 0.01, query: str = QUERY,
         assert rejected, "typo'd failpoint spec was silently accepted"
         finish(rejected=True)
 
-        # bench-style recovery-time summary: the elastic axis pinned
-        # as ELASTIC_r*.json, gated by check_bench_regression
-        # --kind elastic (all *_ms => lower is better)
+        # per-scenario recovery times of the elastic scenarios
         elastic_scenarios = ("worker_death", "spool_replay",
                              "spool_corrupt", "worker_join",
                              "drain_exit", "preemption_storm",
@@ -914,198 +898,6 @@ def run_fleet_chaos(sf: float = 0.01, coordinators: int = 3,
             pass
 
 
-def run_elastic_ramp(sf: float = 0.02, phases=(1, 3, 1),
-                     phase_s: float = 8.0, clients: int = 4,
-                     device_floor_ms: float = 60.0,
-                     rows_per_batch: int = 16384,
-                     verbose: bool = False) -> dict:
-    """Load-ramp bench (ISSUE 20): sustained client load while the
-    worker pool scales 1 -> N -> 1 through the autoscaler's node
-    plane.
-
-    Workers are REAL subprocesses (``LocalProcessProvider`` — the same
-    provider the config-driven autoscaler boots), announcing to an
-    in-process coordinator over HTTP and sharing one spool directory;
-    scale-down is always the drain path (SHUTTING_DOWN -> spool commit
-    -> explicit deregister -> process exit), never a kill. The pinned
-    claims, gated by ``check_bench_regression --kind elastic``:
-
-    - throughput TRACKS the ramp: peak-N QPS >= 1.5x the 1-worker
-      floor (elasticity that doesn't move throughput is a no-op);
-    - ZERO failed queries across every transition, drains included;
-    - the pool really returns to 1 (the scale-down is exercised under
-      load, not just the scale-up).
-
-    ``device_floor_ms`` sets ``PRESTO_TPU_DEVICE_FLOOR_MS`` on the
-    WORKER processes: a fixed-throughput device model (each quantum —
-    and each SCANNED batch, ``taskexec.device_floor_pad`` — holds the
-    device at least that long), making per-worker capacity the
-    bottleneck. CI hosts offer a single core to the whole
-    multi-process cluster, so real compute cannot overlap across
-    workers there — the modeled floor is what makes "QPS tracks the
-    worker count" a property of the SYSTEM under test (scheduling,
-    drains, exchange) instead of the host's core count.
-    ``rows_per_batch`` is lowered so a query scans many batches and
-    the modeled work can actually spread across the pool; the query is
-    ``RAMP_QUERY`` (a selective scan) for the same reason."""
-    import shutil
-    import tempfile
-
-    from presto_tpu.client import StatementClient
-    from presto_tpu.exec.autoscale import LocalProcessProvider
-    from presto_tpu.exec.cluster import ClusterRunner
-    from presto_tpu.exec.discovery import DiscoveryNodeManager
-    from presto_tpu.exec.spool import SPOOL
-    from presto_tpu.server.protocol import PrestoTpuServer
-
-    def log(msg: str) -> None:
-        if verbose:
-            print(msg, file=sys.stderr, flush=True)
-
-    assert phases and phases[0] == 1 and phases[-1] == 1 \
-        and max(phases) > 1, \
-        "ramp must go 1 -> N -> 1 (the scale-DOWN is part of the claim)"
-
-    groups = {
-        "rootGroups": [
-            {"name": "ramp", "hardConcurrencyLimit": 8,
-             "maxQueued": 10000}],
-        "selectors": [{"group": "ramp"}]}
-
-    # one shared spool dir: drained workers' committed output must be
-    # replayable by the survivors (and probeable by the coordinator's
-    # preservation check) across process boundaries
-    spool_dir = tempfile.mkdtemp(prefix="ramp-spool-")
-    SPOOL.configure(directory=spool_dir)
-    discovery = DiscoveryNodeManager(ttl_s=3600.0)
-    runner = ClusterRunner(tpch_sf=sf, heartbeat=False,
-                           discovery=discovery,
-                           rows_per_batch=rows_per_batch)
-    srv = PrestoTpuServer(runner, resource_groups=groups,
-                          discovery=discovery)
-    srv.start()
-    url = f"http://127.0.0.1:{srv.port}"
-    provider = LocalProcessProvider(
-        [url], tpch_sf=sf, spool_dir=spool_dir,
-        extra_env={"PRESTO_TPU_DEVICE_FLOOR_MS":
-                   str(device_floor_ms)} if device_floor_ms else None)
-
-    stop_evt = threading.Event()
-    count_lock = threading.Lock()
-    completed = [0]
-    errors: list = []
-    warm = None
-
-    def set_workers(target: int, timeout_s: float = 120.0) -> None:
-        """Converge the pool to ``target`` — launches for scale-up,
-        the drain path for scale-down — then wait until the
-        coordinator's discovery view agrees (drained workers leave by
-        explicit GONE deregistration, so membership is prompt)."""
-        while len(provider.nodes()) < target:
-            h = provider.launch()
-            log(f"ramp: launched {h.node_id}")
-        while len(provider.nodes()) > target:
-            h = provider.nodes()[-1]
-            log(f"ramp: draining {h.node_id}")
-            assert provider.drain(h, timeout_s=timeout_s), \
-                f"worker {h.node_id} did not drain out"
-        deadline = time.time() + timeout_s
-        while time.time() < deadline:
-            if len(discovery.active_urls()) == target:
-                return
-            time.sleep(0.05)
-        raise AssertionError(
-            f"discovery never converged to {target} workers: "
-            f"{discovery.nodes()}")
-
-    def client_run(ci: int) -> None:
-        sc = StatementClient(url, user="ramp")
-        try:
-            while not stop_evt.is_set():
-                try:
-                    res = sc.execute(RAMP_QUERY)
-                    _assert_rows_equal(res.rows, want, "ramp")
-                except Exception as e:          # noqa: BLE001
-                    if stop_evt.is_set():
-                        return
-                    errors.append(f"client {ci}: {e!r}")
-                    return
-                with count_lock:
-                    completed[0] += 1
-        finally:
-            sc.close()
-
-    threads: list = []
-    try:
-        # floor worker + fault-free reference rows before any load
-        set_workers(1)
-        warm = StatementClient(url, user="ramp")
-        want = warm.execute(RAMP_QUERY).rows
-        log(f"ramp: reference {len(want)} rows via 1 worker")
-
-        threads = [threading.Thread(target=client_run, args=(ci,),
-                                    daemon=True)
-                   for ci in range(clients)]
-        for t in threads:
-            t.start()
-
-        phase_rows = []
-        for target in phases:
-            set_workers(target)        # transition happens UNDER load
-            # absorb cold compile on freshly launched workers BEFORE
-            # the measurement window opens: a new worker's first query
-            # JIT-compiles for ~seconds, which is provisioning latency,
-            # not steady-state throughput — the claim under test
-            for _ in range(2):
-                _assert_rows_equal(warm.execute(RAMP_QUERY).rows,
-                                   want, "ramp-warmup")
-            with count_lock:
-                c0, e0 = completed[0], len(errors)
-            t0 = time.perf_counter()
-            time.sleep(phase_s)
-            with count_lock:
-                c1, e1 = completed[0], len(errors)
-            w = time.perf_counter() - t0
-            phase_rows.append({
-                "workers": target,
-                "queries": c1 - c0,
-                "failed": e1 - e0,
-                "qps": round((c1 - c0) / w, 2),
-                "window_s": round(w, 2)})
-            log(f"ramp: phase {phase_rows[-1]}")
-
-        stop_evt.set()
-        for t in threads:
-            t.join(timeout=30)
-        assert not errors, f"queries failed across the ramp: {errors}"
-
-        floor = phase_rows[0]["qps"]
-        peak = max(r["qps"] for r in phase_rows
-                   if r["workers"] == max(phases))
-        ratio = round(peak / floor, 3) if floor > 0 else 0.0
-        ramp = {"sf": sf, "clients": clients,
-                "device_floor_ms": device_floor_ms,
-                "phases": phase_rows, "peak_over_floor": ratio}
-        assert ratio >= 1.5, \
-            (f"peak QPS {peak} is only {ratio}x the 1-worker floor "
-             f"{floor} (need >= 1.5x): {phase_rows}")
-        return ramp
-    finally:
-        stop_evt.set()
-        for t in threads:
-            t.join(timeout=10)
-        try:
-            warm.close()
-        except Exception:
-            pass
-        try:
-            srv.kill()
-        except Exception:
-            pass
-        provider.stop_all()
-        shutil.rmtree(spool_dir, ignore_errors=True)
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sf", type=float, default=0.01,
@@ -1114,29 +906,13 @@ def main(argv=None) -> int:
                     help="run the coordinator-fleet death drill "
                          "instead of the worker chaos suite")
     ap.add_argument("-q", "--quiet", action="store_true")
-    ap.add_argument("--ramp", action="store_true",
-                    help="additionally run the 1 -> N -> 1 load-ramp "
-                         "bench (subprocess workers) and attach its "
-                         "block to the elastic summary")
-    ap.add_argument("--elastic-out", default=os.environ.get(
-        "ELASTIC_OUT"), metavar="PATH",
-        help="write the elastic recovery-time summary (bench format) "
-             "for check_bench_regression --kind elastic")
     args = ap.parse_args(argv)
     if args.fleet:
         summary = run_fleet_chaos(sf=args.sf, verbose=not args.quiet)
         print(json.dumps(summary, indent=2))
         return 0 if summary.get("ok") else 1
     summary = run_chaos(sf=args.sf, verbose=not args.quiet)
-    if args.ramp and summary.get("elastic"):
-        summary["elastic"]["ramp"] = run_elastic_ramp(
-            verbose=not args.quiet)
     print(json.dumps(summary, indent=2))
-    if args.elastic_out and summary.get("elastic"):
-        tmp = args.elastic_out + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(summary["elastic"], f, indent=2)
-        os.replace(tmp, args.elastic_out)
     return 0 if summary.get("ok") else 1
 
 

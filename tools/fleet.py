@@ -32,8 +32,8 @@ Parent API::
     fleet.kill_coordinator(0)  # SIGKILL — chaos, no drain
     fleet.stop()
 
-Both the serving bench's fleet mode (``SERVING_COORDINATORS=N
-python bench.py serving``) and the fleet chaos drill ride this module.
+The fleet chaos drill (tools/chaos_smoke.py ``--fleet``) rides this
+module.
 """
 from __future__ import annotations
 
@@ -49,10 +49,8 @@ import urllib.error
 import urllib.request
 from typing import Dict, List, Optional
 
-#: the serving-bench resource-group config (two weighted tenants, both
-#: under SLO): fleet children default to the same shape bench_serving
-#: uses standalone, so a fleet bench measures topology — not config —
-#: against SERVING_r03
+#: fleet children's default resource-group config: two weighted
+#: tenants, both under SLO
 _SLO_SPEC = {"latencyTargetMs": 2000, "latencyObjective": 0.95,
              "availabilityObjective": 0.99}
 SERVING_GROUPS = {
@@ -122,9 +120,8 @@ def serve_coordinator(args) -> None:
                                       "result_cache": True})
     groups = (json.loads(args.groups_json) if args.groups_json
               else SERVING_GROUPS)
-    # dense sampling: fleet benches are short-walled; the SLO timeline
-    # needs real windowed points per phase (same rationale as
-    # bench_serving standalone)
+    # dense sampling: fleet runs are short-walled; the SLO timeline
+    # needs real windowed points per phase
     TIMESERIES.configure(sample_interval_s=0.2)
     srv = PrestoTpuServer(runner, port=args.port,
                           resource_groups=groups, discovery=discovery)
@@ -348,17 +345,12 @@ class FleetHandle:
 
 
 def _spawn(argv: List[str]) -> subprocess.Popen:
-    env = dict(os.environ)
-    # children must not recurse into fleet mode or inherit pins that
-    # redirect THEIR summaries
-    for k in ("SERVING_COORDINATORS", "SERVING_OUT"):
-        env.pop(k, None)
     return subprocess.Popen(
         [sys.executable, "-m", "tools.fleet"] + argv,
         stdin=subprocess.PIPE, stdout=subprocess.PIPE,
         stderr=subprocess.DEVNULL,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env, start_new_session=True)
+        start_new_session=True)
 
 
 def _await_ready(rec: dict, timeout_s: float) -> None:

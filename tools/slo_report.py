@@ -1,28 +1,23 @@
 #!/usr/bin/env python
 """Human verdict + schema gate for the SLO block in a SERVING pin.
 
-Serving rounds from r03 on (``SERVING_OUT=path python bench.py
-serving``) carry an ``slo`` block on the headline record: the
-per-resource-group objectives the bench declared (``latency`` /
+A SERVING pin is a summary record that carries an ``slo`` block
+(``obs/slo.slo_block``, what ``GET /v1/slo`` serves): the
+per-resource-group objectives declared (``latency`` /
 ``availability``), the burn rates and error-budget remainder the
 tracker (obs/slo.py) computed over the run, every alert transition it
 fired, and the sampled burn timeline with the windowed p95 alongside.
-This tool is how a serving PR proves the health plane still works:
-render the block as a per-group verdict ("dash latency: OK, budget
-100% left, worst burn 0.3x"), and schema-validate it so a re-pin that
-dropped the timeline or fired an unexplained PAGE cannot be committed.
+This tool renders the block as a per-group verdict ("dash latency: OK,
+budget 100% left, worst burn 0.3x") and schema-validates it
+(:func:`validate_slo_block`). A pin without an ``slo`` block passes
+vacuously. The repository commits no pin, so with no argument this
+finds none and says so.
 
-``check_bench_regression --kind serving`` imports
-:func:`validate_slo_block` so the schema travels with the gate: in
-``--smoke`` mode the pinned round itself must satisfy it, in run mode
-the candidate must. Pins without an ``slo`` block (r02 and older)
-pass vacuously — the gate never fails on history it cannot see.
-
-Fleet pins (r04 on, ``SERVING_COORDINATORS>=2``) carry the MERGED
-multi-coordinator form: a ``coordinators`` count plus a
-``coordinator`` tag on every objective, alert and timeline row; the
-windowed-p95 coverage check then applies per coordinator (every
-member's sampler must have fed its own latency histogram).
+Fleet pins carry the MERGED multi-coordinator form: a
+``coordinators`` count plus a ``coordinator`` tag on every objective,
+alert and timeline row; the windowed-p95 coverage check then applies
+per coordinator (every member's sampler must have fed its own latency
+histogram).
 
 Usage:
     python tools/slo_report.py                 # latest SERVING_r*.json
@@ -57,7 +52,7 @@ RULES = ("latency_burn", "availability_burn")
 #: ``_parse_slo``).
 OBJECTIVES = ("latency", "availability")
 
-#: schema of one slo block (bench.py ``_slo_block``)
+#: schema of one slo block (obs/slo.py ``slo_block``)
 _REQUIRED = ("sample_interval_s", "objectives", "alerts", "timeline")
 
 

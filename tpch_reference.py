@@ -1,8 +1,7 @@
 """Plain NumPy implementations of TPC-H Q6, Q1 and Q3 over the chunks
-``TpchConnector`` generates — the reference both ``bench.py`` (as its
-timed NumPy proxy) and ``chip_smoke.py`` (as the answer the chip must
-reproduce) call. They share no code with the engine beyond the
-generator that makes the data: no planner, no expression compiler, no
+``TpchConnector`` generates — the reference ``chip_smoke.py`` calls
+(as the answer the chip must reproduce). They share no code with the
+engine beyond the generator that makes the data: no planner, no expression compiler, no
 JAX. One chunk is a tuple of host column arrays in the order the
 function documents, followed by a bool row mask.
 """
