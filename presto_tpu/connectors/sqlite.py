@@ -184,6 +184,11 @@ class SqliteConnector(Connector):
         self.name = "sqlite"
         self.path = path
         self._local = threading.local()
+        # PRAGMA data_version is a setting of ONE connection (a fresh
+        # connection reads 1 whatever the file holds): every stamp and
+        # every revalidation reads it from this one, whatever thread asks
+        self._version_db: Optional[sqlite3.Connection] = None
+        self._version_lock = threading.Lock()
         self._meta = _Meta(self)
         self._split_mgr = _Splits(self)
         self._schema_cache: Dict[str, Schema] = {}
@@ -204,7 +209,12 @@ class SqliteConnector(Connector):
         # by this connection), so externally-modified tables miss
         # instead of serving stale cached splits
         try:
-            ext = self._db().execute("pragma data_version").fetchone()[0]
+            with self._version_lock:
+                if self._version_db is None:
+                    self._version_db = sqlite3.connect(
+                        self.path, check_same_thread=False)
+                ext = self._version_db.execute(
+                    "pragma data_version").fetchone()[0]
         except sqlite3.Error:
             ext = None
         return (self._versions.get(table, 0), ext)

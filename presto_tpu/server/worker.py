@@ -430,6 +430,23 @@ class ExchangeClient:
                 return
         # cancelled: the replay arm already won
 
+    @staticmethod
+    def _next_verdict(results: "_queue.Queue", arms) -> Optional[tuple]:
+        """The next arm's verdict, or None once every arm has ended and
+        left none: an arm that dies of an error it does not catch (the
+        spool's directory gone under a replay), or that leaves because
+        the client was stopped, puts nothing, and a thread that has
+        died is not waited for. Each arm ends by itself: the live arm
+        ``fail_fast_s`` and one 10 s read after its first failure, the
+        replay when the spool is read."""
+        while True:
+            alive = any(t.is_alive() for t in arms)
+            try:
+                return results.get(block=alive, timeout=1.0)
+            except _queue.Empty:
+                if not alive:
+                    return None
+
     def _race_spool(self, url: str, task_id: str,
                     token: int) -> Optional[bool]:
         """Speculative read: race the durable-spool replay against a
@@ -471,7 +488,12 @@ class ExchangeClient:
         errors: List[Exception] = []
         decisive: Optional[Exception] = None
         for _ in range(len(arms)):
-            who, buf, err, is_decisive = results.get()
+            verdict = self._next_verdict(results, arms)
+            if verdict is None:
+                errors.append(RuntimeError(
+                    "an arm ended without a verdict"))
+                break
+            who, buf, err, is_decisive = verdict
             if buf is not None:
                 cancel.set()           # first complete remainder wins
                 (_SPEC_REPLAY_WON if who == "replay"

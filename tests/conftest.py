@@ -7,8 +7,15 @@ we get N devices in one process via XLA's host platform device count.
 Tests run without a chip, so the CPU platform is forced here (the
 environment form for subprocesses tests spawn, the config form for this
 process); the chip is driven by chip_smoke.py, never by pytest.
+
+Every test runs under a time limit (``TIME_LIMIT_S``, or the test's own
+``@pytest.mark.time_limit(seconds)``): a test that waits fails with
+every thread's stack in its report, and its file goes on.
 """
+import faulthandler
 import os
+import signal
+import tempfile
 
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
@@ -48,6 +55,58 @@ import presto_tpu  # noqa: E402
 presto_tpu.enable_compile_cache()
 
 import pytest  # noqa: E402
+
+#: seconds a test may take, set-up and tear-down included. The suite's
+#: slowest test takes 62.5 s alone and about 90 s beside other load; the
+#: rehearsals' own LIMIT_S (300 s, tests/test_benchmark_rehearsals.py)
+#: plus their kill is below it, so the inner limit speaks first.
+TIME_LIMIT_S = 360
+#: seconds after the limit at which the timer fires again (a tear-down
+#: that waits too) and the watchdog ends a worker whose main thread is
+#: inside C and took no signal
+AFTER_S = 60
+
+#: where the watchdog writes: the terminal, duplicated while capture is off
+_STDERR_FD = pytest.StashKey[int]()
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "time_limit(seconds): this test's limit, in place of "
+                   "TIME_LIMIT_S of tests/conftest.py")
+    config.stash[_STDERR_FD] = os.dup(2)
+
+
+# trylast: innermost of the wrappers, so that of faulthandler's one
+# watchdog it is this one that stands while a test runs, not the
+# print-only one of pytest.ini's faulthandler_timeout (which is for the
+# processes that do not load this file: the rehearsals' children)
+@pytest.hookimpl(wrapper=True, trylast=True)
+def pytest_runtest_protocol(item):
+    marker = item.get_closest_marker("time_limit")
+    limit = float(marker.args[0]) if marker else TIME_LIMIT_S
+
+    def on_alarm(signum, frame):
+        # into a file, not onto the terminal: text in a row of dots
+        # makes the driver's count of dots drop the row
+        with tempfile.TemporaryFile("w+") as f:
+            faulthandler.dump_traceback(file=f, all_threads=True)
+            f.seek(0)
+            stacks = f.read()
+        pytest.fail(f"{item.nodeid} ran past its time limit of "
+                    f"{limit:g} s. Every thread's stack, the test's own "
+                    f"last:\n{stacks}")
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit, AFTER_S)
+    faulthandler.dump_traceback_later(limit + AFTER_S, exit=True,
+                                      file=item.config.stash[_STDERR_FD])
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 @pytest.fixture(scope="session")

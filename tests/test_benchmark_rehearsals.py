@@ -28,6 +28,9 @@ FAILS_SINCE_PR26 = ("benchmarks/tests/test_cells.py::"
 #: seconds a file may take; a hang fails its case, not the suite (the
 #: slowest, test_cells.py, takes about 40 s alone on a CPU)
 LIMIT_S = 300
+#: seconds more for the pipe to close once the file's process group is
+#: killed; whatever holds it after that is not worth waiting for
+KILL_WAIT_S = 5
 
 FILES = sorted(
     os.path.relpath(p, ROOT).replace(os.sep, "/")
@@ -51,20 +54,37 @@ def test_there_are_rehearsals():
     assert FILES, "benchmarks/tests holds no test_*.py"
 
 
+def run_limited(cmd, limit_s, what):
+    """Run ``cmd`` in a session of its own and return its exit code and
+    output; past ``limit_s`` seconds the case fails, and never waits
+    longer than ``KILL_WAIT_S`` more."""
+    p = subprocess.Popen(cmd, cwd=ROOT, env=_env(), text=True,
+                         stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+                         start_new_session=True)
+    try:
+        out, _ = p.communicate(timeout=limit_s)
+    except subprocess.TimeoutExpired:
+        # the rehearsals start processes of their own: end the group
+        os.killpg(p.pid, signal.SIGKILL)
+        try:
+            out, _ = p.communicate(timeout=KILL_WAIT_S)
+            held = ""
+        except subprocess.TimeoutExpired as e:
+            # a process outside the group still holds the pipe
+            out = (e.output or b"").decode(errors="replace")
+            p.stdout.close()
+            p.wait(timeout=KILL_WAIT_S)
+            held = (f" (and {KILL_WAIT_S} s after the group's SIGKILL "
+                    f"the pipe was still held open: not waited for)")
+        pytest.fail(f"{what} ran past {limit_s} s{held}:\n{out[-3000:]}")
+    return p.returncode, out
+
+
 @pytest.mark.parametrize("path", FILES)
 def test_rehearsal(path):
     cmd = [sys.executable, "-m", "pytest", path, "-q",
            "-p", "no:cacheprovider", "-p", "no:xdist"]
     if FAILS_SINCE_PR26.startswith(path + "::"):
         cmd += ["--deselect", FAILS_SINCE_PR26]
-    p = subprocess.Popen(cmd, cwd=ROOT, env=_env(), text=True,
-                         stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                         start_new_session=True)
-    try:
-        out, _ = p.communicate(timeout=LIMIT_S)
-    except subprocess.TimeoutExpired:
-        # the rehearsals start processes of their own: end the group
-        os.killpg(p.pid, signal.SIGKILL)
-        out, _ = p.communicate()
-        pytest.fail(f"{path} ran past {LIMIT_S} s:\n{out[-3000:]}")
-    assert p.returncode == 0, out[-3000:]
+    rc, out = run_limited(cmd, LIMIT_S, path)
+    assert rc == 0, out[-3000:]

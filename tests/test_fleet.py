@@ -56,11 +56,10 @@ def _make_runner(db_path: str) -> LocalRunner:
 
 
 @pytest.fixture()
-def fleet_pair():
+def fleet_pair(tmp_path):
     """Two HTTP coordinators, fleet-enabled, over one sqlite file."""
     from presto_tpu.server.protocol import PrestoTpuServer
-    db = os.path.join(tempfile.mkdtemp(prefix="fleet_test_"),
-                      "shared.db")
+    db = str(tmp_path / "shared.db")
     servers = []
     for i in range(2):
         srv = PrestoTpuServer(_make_runner(db))
@@ -158,10 +157,10 @@ def test_dropped_broadcast_still_serves_correct_rows(fleet_pair):
     assert b.execute(sql).rows == [[3, 60]]
 
 
-def test_fold_is_deduped_and_catalog_checked():
+def test_fold_is_deduped_and_catalog_checked(tmp_path):
     """fold_bump unit seams: per-origin monotonic dedupe, unknown
     catalogs counted and ignored, own-origin bumps refused."""
-    db = os.path.join(tempfile.mkdtemp(prefix="fleet_fold_"), "f.db")
+    db = str(tmp_path / "f.db")
     cats = CatalogManager()
     cats.register("fleetdb", SqliteConnector(db))
     m = FleetMember("coord-b", "http://127.0.0.1:0", catalogs=cats)
@@ -179,7 +178,7 @@ def test_fold_is_deduped_and_catalog_checked():
     assert m.fold_bump(dict(doc, origin="coord-b", seq=9)) is False
 
 
-def test_remote_bump_vs_local_insert_interleaving():
+def test_remote_bump_vs_local_insert_interleaving(tmp_path):
     """The cross-the-wire epoch-before-deps race, systematically
     explored: coordinator B runs a cacheable SELECT while a remote
     write (raw sqlite commit, then ``fold_bump``) lands at every
@@ -191,8 +190,7 @@ def test_remote_bump_vs_local_insert_interleaving():
                                                  point)
 
     def make():
-        db = os.path.join(tempfile.mkdtemp(prefix="fleet_race_"),
-                          "race.db")
+        db = os.path.join(tempfile.mkdtemp(dir=tmp_path), "race.db")
         r = _make_runner(db)
         member = FleetMember("coord-b", "http://127.0.0.1:0",
                              catalogs=r.session.catalogs)

@@ -7,7 +7,6 @@ result/subplan cache's hit / partial (append-only incremental
 maintenance) / invalidation / veto semantics, admission-slot release on
 the hit fast path, and the cross-session parse-cache regression.
 """
-import tempfile
 
 import pytest
 
@@ -35,12 +34,13 @@ def runner():
 
 
 @pytest.fixture()
-def file_runner():
-    tmp = tempfile.mkdtemp()
+def file_runner(tmp_path):
+    tmp = tmp_path / "orc"
+    tmp.mkdir()
     cats = CatalogManager()
     cats.register("tpch", TpchConnector(sf=0.01))
     cats.register("memory", MemoryConnector())
-    cats.register("orc", OrcConnector(tmp))
+    cats.register("orc", OrcConnector(str(tmp)))
     return LocalRunner(catalogs=cats, catalog="tpch")
 
 
@@ -190,15 +190,13 @@ def test_parse_cache_does_not_leak_across_sessions():
 
 # -- result cache -------------------------------------------------------------
 
-def test_result_cache_hit_and_write_invalidation(file_runner):
+def test_result_cache_hit_and_write_invalidation(file_runner, tmp_path):
     """Eager invalidation rides spi.notify_data_change for memory,
     sqlite and filebase writes — the same path the plan cache uses."""
-    import os
     from presto_tpu.connectors.sqlite import SqliteConnector
     r = file_runner
-    tmp = tempfile.mkdtemp()
     r.session.catalogs.register(
-        "sqlite", SqliteConnector(os.path.join(tmp, "db.sqlite")))
+        "sqlite", SqliteConnector(str(tmp_path / "db.sqlite")))
     cases = [
         ("memory", "select count(*) c, sum(q) s from memory.t"),
         ("sqlite", "select count(*) c, sum(q) s from sqlite.t"),

@@ -625,6 +625,44 @@ def test_speculative_live_wins_when_replay_is_slow(tmp_path,
         httpd.server_close()
 
 
+def test_speculative_read_does_not_wait_for_an_arm_that_died(
+        tmp_path, monkeypatch):
+    """The bucket goes away under a replay (the object store's
+    directory removed): the replay arm dies of an error it does not
+    catch and leaves no verdict. Once the live arm has lost too the
+    race is lost, with an error; it used to wait for the second
+    verdict for ever."""
+    import presto_tpu.exec.spool as spool_mod
+    from presto_tpu.exec.spool import ObjectSpoolStore
+    from presto_tpu.server.worker import ExchangeClient, ExchangeFailedError
+    store = ObjectSpoolStore(directory=str(tmp_path / "bucket"))
+    monkeypatch.setattr(spool_mod, "SPOOL", store)
+    _committed_page_store(store, "qd", "qd.0.0")
+
+    def bucket_gone(*a, **kw):
+        raise FileNotFoundError("the bucket is gone")
+    monkeypatch.setattr(store, "read_pages", bucket_gone)
+    monkeypatch.setattr(threading, "excepthook", lambda args: None)
+    FAILPOINTS.configure("exchange.spec_live", action="error",
+                         message="chaos: live pull down")
+    client = ExchangeClient(["http://127.0.0.1:1/v1/task/qd.0.0"], 0,
+                            fail_fast_s=5.0)
+    outcome = []
+
+    def race():
+        try:
+            outcome.append(client._race_spool(
+                "http://127.0.0.1:1/v1/task/qd.0.0", "qd.0.0", 0))
+        except ExchangeFailedError as e:
+            outcome.append(e)
+    t = threading.Thread(target=race, daemon=True)
+    t.start()
+    t.join(timeout=20)
+    assert not t.is_alive(), "the race still waits for a dead arm"
+    assert isinstance(outcome[0], ExchangeFailedError)
+    assert "an arm ended without a verdict" in str(outcome[0])
+
+
 def test_speculative_disabled_session_property_drains_serially(
         tmp_path, monkeypatch):
     """``speculative_spool_reads=false`` falls back to the plain

@@ -112,3 +112,35 @@ def test_plugin_factory_loads_from_properties(tmp_path):
             [("a", __import__("presto_tpu", fromlist=["types"])
               .types.BIGINT)]))
     assert conn.metadata.list_tables() == ["t"]
+
+
+def test_data_version_moves_on_a_foreign_commit_whatever_thread_asks(
+        tmp_path):
+    """A cache stamps ``data_version`` on one thread and revalidates on
+    another (the statement server's pool). PRAGMA data_version is a
+    setting of one connection, and a fresh one reads 1 whatever the
+    file holds: read from a connection a thread, the stamp and the
+    check agreed after a foreign commit and the stale entry was served
+    (``test_fleet.py::test_dropped_broadcast_still_serves_correct_rows``,
+    4 of 40 runs beside other load)."""
+    import sqlite3
+    import threading
+    db = str(tmp_path / "v.db")
+    raw = sqlite3.connect(db)
+    raw.execute("create table t (x)")
+    raw.commit()
+    conn = SqliteConnector(db)
+    seen = []
+
+    def ask():
+        seen.append(conn.data_version("t"))
+    for foreign_commit in (False, True, False):
+        if foreign_commit:
+            raw.execute("insert into t values (1)")
+            raw.commit()
+        t = threading.Thread(target=ask)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    assert seen[0] != seen[1], "a foreign commit went unseen"
+    assert seen[1] == seen[2], "the version moved with no commit"

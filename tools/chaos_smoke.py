@@ -64,6 +64,10 @@ QUERY = ("select l_returnflag, l_linestatus, count(*) c, "
          "group by 1, 2 order by 1, 2")
 
 
+#: seconds the fleet drill's clients get for their statements, all told
+CLIENTS_JOIN_S = 120.0
+
+
 def _metric_sql(runner, name: str) -> float:
     res = runner.local.execute(
         "select value from system.runtime.metrics "
@@ -566,10 +570,15 @@ def run_chaos(sf: float = 0.01, query: str = QUERY,
         # and the query completes row-exact — shuffle state outlived
         # the entire worker set because it lives in the object store,
         # not on any worker's disk
+        import atexit as _atexit
         import shutil as _shutil
         import tempfile as _tempfile
         finish = scenario("scale_to_zero")
         obj_dir = _tempfile.mkdtemp(prefix="chaos-objspool-")
+        # the object store makes its directory anew whenever it is
+        # touched, and every later query's release touches it: what the
+        # ``finally`` below empties is removed for good at exit
+        _atexit.register(_shutil.rmtree, obj_dir, ignore_errors=True)
         SPOOL.configure(backend="object", object_dir=obj_dir,
                         object_put_latency_s=0.002,
                         object_get_latency_s=0.002)
@@ -825,12 +834,22 @@ def run_fleet_chaos(sf: float = 0.01, coordinators: int = 3,
                 with count_lock:
                     done[0] += 1
 
-        threads = [threading.Thread(target=client_run, args=(ci,))
+        threads = [threading.Thread(target=client_run, args=(ci,),
+                                    name=f"fleet-client-{ci}",
+                                    daemon=True)
                    for ci in range(clients)]
         for t in threads:
             t.start()
+        # a client whose statement never comes back is an error with a
+        # name, not a wait (a warm statement takes well under a second)
+        join_by = time.monotonic() + CLIENTS_JOIN_S
         for t in threads:
-            t.join()
+            t.join(timeout=max(0.0, join_by - time.monotonic()))
+        waiting = [t.name for t in threads if t.is_alive()]
+        assert not waiting, \
+            f"clients still in a statement after {CLIENTS_JOIN_S} s: " \
+            f"{waiting} ({done[0]} of {total} statements done, " \
+            f"errors so far: {errors})"
         assert killed.is_set(), "the kill threshold was never reached"
         assert not errors, f"queries failed across the kill: {errors}"
 
