@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .obs.metrics import REGISTRY
 from .obs.trace import device_sync
 from .types import ArrayType, MapType, Type, VarcharType, CharType, parse_type
 
@@ -357,6 +358,23 @@ class Batch:
         ]
         return Batch(self.schema, cols, grow(self.row_mask))
 
+    def prefix(self, capacity: int) -> "Batch":
+        """The first ``capacity`` lanes: a slice, for a batch whose live
+        rows come first (what the sort-path group-by gives back); the
+        caller knows that they fit."""
+        if capacity >= self.capacity:
+            return self
+
+        def cut(a):
+            return a[:capacity]
+
+        cols = [
+            Column(c.type, jax.tree_util.tree_map(cut, c.data),
+                   cut(c.validity), c.dictionary)
+            for c in self.columns
+        ]
+        return Batch(self.schema, cols, cut(self.row_mask))
+
     def __repr__(self) -> str:
         return f"Batch({self.schema!r}, capacity={self.capacity})"
 
@@ -411,6 +429,54 @@ def live_indices(mask: jax.Array, size: int) -> Tuple[jax.Array, jax.Array]:
         return idx[:size], n
     return jnp.pad(idx, (0, size - capacity),
                    constant_values=capacity - 1), n
+
+
+def shift_lanes(x: jax.Array, by: int) -> jax.Array:
+    """``x`` moved ``by`` lanes towards lane 0 along its first axis
+    (``out[i] = x[i + by]``; towards the end for a negative ``by``),
+    zeros moving in: a concatenation of slices, no gather."""
+    if by == 0:
+        return x
+    fill = jnp.zeros_like(x[:abs(by)])
+    return (jnp.concatenate([x[by:], fill]) if by > 0
+            else jnp.concatenate([fill, x[:by]]))
+
+
+def compress_moves(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The compress network of :func:`live_indices` as a plan that moves
+    DATA: (moves, live count), ``moves`` one int32 a lane whose bit k
+    says that pass k takes the lane 2^k to the right. Applied to a
+    column by :func:`compress_lanes`, the live lanes end up at the
+    front in their order, with no gather: log2(capacity) elementwise
+    passes a column, where a gather by ``live_indices`` costs the v5e
+    ~11 ns a lane a column (PERF.md section 5)."""
+    capacity = mask.shape[0]
+    dead_before = _rows_cumsum((~mask).astype(jnp.int32))
+    word = jnp.where(mask, 2 * dead_before + 1, 0)
+    moves = jnp.zeros(capacity, jnp.int32)
+    for k in range((capacity - 1).bit_length()):
+        right = shift_lanes(word, 1 << k)
+        take = (right >> (k + 1)) & 1
+        moves = moves | (take << k)
+        word = jnp.where(take == 1, right,
+                         jnp.where((word >> (k + 1)) & 1 == 1, 0, word))
+    return moves, capacity - dead_before[-1]
+
+
+def per_lane(flag: jax.Array, like: jax.Array) -> jax.Array:
+    """A flag a lane shaped to broadcast against ``like``, whose lanes
+    run along its first axis (a state column may hold a row a lane)."""
+    return flag.reshape((-1,) + (1,) * (like.ndim - 1))
+
+
+def compress_lanes(moves: jax.Array, x: jax.Array) -> jax.Array:
+    """``x`` (lanes along its first axis) with the live lanes of
+    :func:`compress_moves`' mask at the front, in their order; what the
+    lanes behind them hold is not defined."""
+    for k in range((moves.shape[0] - 1).bit_length()):
+        x = jnp.where(per_lane((moves >> k) & 1 == 1, x),
+                      shift_lanes(x, 1 << k), x)
+    return x
 
 
 def _composite_to_pylist(col: Column, mask: np.ndarray) -> List[Any]:
@@ -526,17 +592,41 @@ def _concat_array_columns(cols: Sequence[Column], cap: int) -> Column:
                   cat_pad(rvs), dictionary)
 
 
+#: vocabularies merged entry by entry on the host
+#: (`unify_dictionaries`), and the entries they held: a Python loop over
+#: every string, so a text column with a value a row shows here
+_UNIFIED = REGISTRY.counter("dictionary_unify_total")
+_UNIFIED_ENTRIES = REGISTRY.counter("dictionary_unify_entries_total")
+
+
+#: the last few large unifications, by the IDENTITY of the vocabularies
+#: that went in (the entry holds them, so an id is not reused while it
+#: is here): a scan-cached text column brings the same tuples query
+#: after query, and TPC-H Q18's 1.5M customer names took the loop below
+#: a second or two of every query
+_UNIFY_MEMO: "Dict[Tuple[int, ...], tuple]" = {}
+_UNIFY_MEMO_ENTRIES = 8
+_UNIFY_MEMO_FLOOR = 1 << 12
+
+
 def unify_dictionaries(columns: Sequence[Column]) -> Tuple[Tuple[str, ...], List[np.ndarray]]:
     """Merge per-column vocabularies; return (vocab, remap arrays per column).
 
     remap[i] maps old codes of columns[i] to codes in the unified vocab; -1
     stays -1 via the sentinel slot appended at the end.
     """
+    sources = [col.dictionary or () for col in columns]
+    entries = sum(len(src) for src in sources)
+    key = tuple(id(src) for src in sources)
+    memo = _UNIFY_MEMO.get(key) if entries >= _UNIFY_MEMO_FLOOR else None
+    if memo is not None and all(a is b for a, b in zip(memo[0], sources)):
+        return memo[1], memo[2]
+    _UNIFIED.inc()
+    _UNIFIED_ENTRIES.inc(entries)
     vocab: List[str] = []
     lookup: Dict[str, int] = {}
     remaps: List[np.ndarray] = []
-    for col in columns:
-        src = col.dictionary or ()
+    for src in sources:
         remap = np.full(len(src) + 1, -1, dtype=np.int32)  # last slot: -1 sentinel
         for old_code, s in enumerate(src):
             code = lookup.get(s)
@@ -545,7 +635,12 @@ def unify_dictionaries(columns: Sequence[Column]) -> Tuple[Tuple[str, ...], List
                 vocab.append(s)
             remap[old_code] = code
         remaps.append(remap)
-    return tuple(vocab), remaps
+    unified = tuple(vocab)
+    if entries >= _UNIFY_MEMO_FLOOR:
+        while len(_UNIFY_MEMO) >= _UNIFY_MEMO_ENTRIES:
+            _UNIFY_MEMO.pop(next(iter(_UNIFY_MEMO)))
+        _UNIFY_MEMO[key] = (sources, unified, remaps)
+    return unified, remaps
 
 
 def apply_remap_np(codes: np.ndarray, remap: np.ndarray) -> np.ndarray:
@@ -586,7 +681,10 @@ def concat_batches(batches: Sequence[Batch], capacity: Optional[int] = None) -> 
             continue
         if isinstance(typ, MapType):
             raise NotImplementedError("concat of MAP columns")
-        if typ.is_string:
+        if typ.is_string and all(c.dictionary is cols[0].dictionary
+                                 for c in cols):
+            dictionary = cols[0].dictionary     # one vocabulary: as it is
+        elif typ.is_string:
             vocab, remaps = unify_dictionaries(cols)
             cols = [remap_codes(c, r, vocab) for c, r in zip(cols, remaps)]
             dictionary = vocab

@@ -484,12 +484,21 @@ class _Gen:
         return out
 
 
-def _to_batch(schema: Schema, cols: Sequence[str], data: Dict, n: int) -> Batch:
+def _to_batch(schema: Schema, cols: Sequence[str], data: Dict, n: int,
+              distinct_text: Sequence[str] = ()) -> Batch:
     arrays, dicts = [], []
     out_schema = schema.select(list(cols))
     for name in cols:
         arr, vocab = data[name]
-        if vocab == "text":
+        if vocab == "text" and name in distinct_text:
+            # a value a row, as the deployment states: the rows'
+            # positions are the codes, the strings the vocabulary
+            if len(set(arr)) != n:
+                raise ValueError(f"{name} is stated distinct row by row "
+                                 f"and holds a value twice")
+            arrays.append(np.arange(n, dtype=np.int32))
+            dicts.append(tuple(arr))
+        elif vocab == "text":
             # per-batch vocabulary for free-text columns
             uniq: Dict[str, int] = {}
             codes = np.empty(n, dtype=np.int32)
@@ -511,11 +520,12 @@ def _to_batch(schema: Schema, cols: Sequence[str], data: Dict, n: int) -> Batch:
 
 class TpchPageSource(PageSource):
     def __init__(self, gen: _Gen, split: Split, columns: Sequence[str],
-                 rows_per_batch: int):
+                 rows_per_batch: int, distinct_text: Sequence[str] = ()):
         self.gen = gen
         self.split = split
         self.columns = list(columns)
         self.rows_per_batch = rows_per_batch
+        self.distinct_text = tuple(distinct_text)
 
     def host_chunks(self):
         """(schema, generated column dict, row count) per chunk, host-side
@@ -546,7 +556,8 @@ class TpchPageSource(PageSource):
 
     def batches(self) -> Iterator[Batch]:
         for schema, data, n in self.host_chunks():
-            yield _to_batch(schema, self.columns, data, n)
+            yield _to_batch(schema, self.columns, data, n,
+                            self.distinct_text)
 
 
 def tpch_schema(table: str) -> Schema:
@@ -657,12 +668,23 @@ class TpchConnector(Connector):
     name = "tpch"
     applies_pushdown = False    # page_source drops it
 
-    def __init__(self, sf: float = 0.01, tables: Sequence[str] = TABLES):
+    def __init__(self, sf: float = 0.01, tables: Sequence[str] = TABLES,
+                 distinct_text: Sequence[str] = ()):
         """``tables``: the tables this catalog holds (a deployment of
-        one fact table lists it alone); every TPC-H table by default."""
+        one fact table lists it alone); every TPC-H table by default.
+        ``distinct_text``: text columns of which the deployment states
+        that every row holds another value (``c_name`` is
+        ``Customer#<c_custkey>``): a batch of such a column takes its
+        rows' positions for codes and its strings for vocabulary, and
+        no Python loop looks each string up for a repeat."""
         unknown = sorted(set(tables) - set(TABLES))
         if unknown:
             raise ValueError(f"unknown tpch tables {unknown}")
+        known = {n for t in tables for n, ty in _SCHEMAS[t] if ty.is_string}
+        unknown = sorted(set(distinct_text) - known)
+        if unknown:
+            raise ValueError(f"no text columns {unknown} in {list(tables)}")
+        self.distinct_text = tuple(distinct_text)
         self.sf = sf
         self._metadata = _Metadata(sf, tables)
         self._splits = _SplitManager(sf)
@@ -683,4 +705,5 @@ class TpchConnector(Connector):
 
     def page_source(self, split: Split, columns: Sequence[str],
                     pushdown=None, rows_per_batch: int = 1 << 17) -> PageSource:
-        return TpchPageSource(self._gen, split, columns, rows_per_batch)
+        return TpchPageSource(self._gen, split, columns, rows_per_batch,
+                              self.distinct_text)

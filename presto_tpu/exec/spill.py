@@ -37,7 +37,9 @@ from ..memory import QueryMemoryPool, batch_device_bytes
 from ..obs.metrics import REGISTRY
 from ..obs.trace import TRACER
 from ..ops.aggregation import AggSpec
-from ..ops.jitcache import grouped_aggregate_jit as grouped_aggregate, compact_jit
+from ..ops.jitcache import (
+    compact_jit, finish_states_jit, grouped_aggregate_jit as grouped_aggregate,
+    merge_pair_jit, merge_states_jit, pad_capacity_jit, prefix_jit)
 from ..ops.sort import SortKey, sort_batch
 from ..parallel.exchange import hash_partition_ids
 
@@ -369,17 +371,58 @@ class SpillableBuildBuffer:
             self.store.close()
 
 
+#: the grouped state (`AggSpillBuffer`): partial states taken in, merges
+#: of two states into one (the final fold's among them), the lanes those
+#: merges read, and the live groups of each finished state
+_AGG_PARTIALS = REGISTRY.counter("agg_partials_total")
+_AGG_MERGES = REGISTRY.counter("agg_state_merges_total")
+_AGG_LANES_MERGED = REGISTRY.counter("agg_state_lanes_merged_total")
+_AGG_GROUPS = REGISTRY.counter("agg_state_groups_total")
+
+
+@dataclasses.dataclass
+class _State:
+    """One state batch of the grouped aggregation on the device."""
+    batch: Batch
+    #: every key once (a partial's or a merge's output; not what an
+    #: exchange hands a FINAL step, which may hold several producers')
+    unique: bool
+    #: live rows first, ascending by the sort path's operands: what
+    #: ``merge_states`` takes
+    normalized: bool
+    #: the live groups, where they have been read back
+    groups: Optional[int] = None
+
+
 class AggSpillBuffer:
     """Grouped-aggregation state accumulator: merges partial-state batches
     on device; under memory pressure stages states to host partitioned by
     group-key hash, finalizing partition-serially (reference
     SpillableHashAggregationBuilder.java + MergingHashAggregationBuilder).
     Group keys are disjoint across hash partitions, so per-partition FINAL
-    results concatenate to the global answer."""
+    results concatenate to the global answer.
+
+    On the device the state is a binary counter of states, at most one a
+    capacity (a power of two): a partial is cut to the bucket of its live
+    groups and takes its capacity's place; where one stands there already
+    the two merge into one of twice the capacity, and so on up. Equal
+    capacities only, so a query compiles ONE merge program a capacity
+    whatever the number of its batches, and no state is sorted again
+    whole for every sixteen partials that arrive. Two normalized states
+    of integer keys merge in ``ops.aggregation.merge_states`` (no sort,
+    no gather); any other pair in ``grouped_aggregate`` over their
+    concatenation, inside the program. A live count is read a few states
+    late (``LATE``), when the device has long computed it."""
+
+    #: states whose live count is not read yet: the oldest is read when
+    #: one more arrives, so the host stays this far ahead of the device
+    LATE = 2
+    #: a state this small is not worth a readback to cut it smaller
+    CUT_FLOOR = 1 << 12
 
     def __init__(self, pool: QueryMemoryPool, name: str,
                  key_idx: Sequence[int], aggs: Sequence[AggSpec],
-                 n_partitions: int, merge_every: int = 16,
+                 n_partitions: int,
                  key_bounds=None, allow_dense: bool = True,
                  error_sink=None):
         self.ctx = pool.context(name, revoke_cb=self._spill_all)
@@ -397,64 +440,154 @@ class AggSpillBuffer:
         # the per-batch partials sorted (and so appended no flag)
         self.error_sink = error_sink
         self.n_partitions = n_partitions
-        self.merge_every = merge_every
-        self.device: List[Batch] = []
+        #: states not cut to their live count yet, oldest first
+        self.late: List[_State] = []
+        #: capacity -> the one state of that capacity
+        self.levels: dict = {}
         self.store: Optional[HostPartitionStore] = None
         self.spilled = False
 
-    def add_partial(self, partial: Batch) -> None:
+    # -- taking partials in --------------------------------------------------
+    def add_partial(self, partial: Batch, unique: bool = True,
+                    normalized: bool = False) -> None:
+        """One more partial state. ``unique``: every key once (false for
+        what an exchange hands a FINAL step); ``normalized``: the sort
+        path made it (live rows first, in the sort's order)."""
+        _AGG_PARTIALS.inc()
         # pool lock: revoke callbacks (_spill_all) arrive from other
         # threads mid-merge; see SpillableBuildBuffer.add
         with self.ctx.pool.lock:
             if self.spilled:
                 self._stage(partial)
                 return
-            nb = batch_device_bytes(partial)
-            if self.ctx.pool.try_reserve(nb, self.ctx):
-                self.device.append(partial)
-                if len(self.device) < self.merge_every:
-                    return
-                # snapshot-and-clear under the lock; the merge itself
-                # (which host-syncs for the compaction size) runs
-                # outside so other operators' reserves aren't blocked
-                # behind device compute. A revoke landing mid-merge
-                # sees an empty device list and just flips `spilled`.
-                snapshot = self.device
-                self.device = []
-            else:
+            if not self.ctx.pool.try_reserve(batch_device_bytes(partial),
+                                             self.ctx):
                 self.ctx.revoke()
                 self._stage(partial)
                 return
-        states = concat_batches(snapshot)
-        self._flag_bounds(states)
-        merged = grouped_aggregate(states,
-                                   self.key_idx, self.aggs, mode="merge",
-                                   key_bounds=self.key_bounds,
-                                   allow_dense=self.allow_dense)
-        state = compact_jit(
-            merged, bucket_capacity(max(merged.host_count(), 1)))
-        with self.ctx.pool.lock:
-            self.ctx.release_all()
-            if not self.spilled and self.ctx.pool.try_reserve(
-                    batch_device_bytes(state), self.ctx):
-                self.device.append(state)
-            else:
-                self._stage(state)
-                self.spilled = True
+            self.late.append(_State(partial, unique, unique and normalized))
+        self._settle(self.LATE)
 
-    def _flag_bounds(self, states: Batch) -> None:
-        """Mirror of this merge/final call's kernel dispatch: when the
-        dense (clamping) path engages for THIS batch, emit the
-        bounds-violation scalar — state batches keep raw key values, so
-        out-of-bounds keys from a sort-path partial are still visible
-        here (exec/local.py owns the per-partial-batch flags)."""
-        if self.key_bounds is None or not self.allow_dense \
-                or self.error_sink is None:
+    def _settle(self, keep: int) -> None:
+        """Place the oldest late states until ``keep`` are left: each is
+        cut to its live count and takes its capacity's place, or merges
+        with the state that stands there, the merged state late in its
+        turn. The cuts and merges (readbacks, launches) run outside the
+        lock so other operators' reserves aren't blocked behind device
+        compute; a revoke landing meanwhile flips ``spilled``, and what
+        is in flight here is staged."""
+        while True:
+            with self.ctx.pool.lock:
+                if self.spilled or len(self.late) <= keep:
+                    return
+                st = self.late.pop(0)
+            st = self._cut(st)
+            with self.ctx.pool.lock:
+                other = (None if self.spilled
+                         else self.levels.pop(st.batch.capacity, None))
+                if other is None:
+                    self._hold(st, late=False)
+                    continue
+            merged = self._merge(other, st)
+            with self.ctx.pool.lock:
+                self._hold(merged, late=True)
+
+    def _hold(self, st: _State, late: bool) -> None:
+        """Under the lock: ``st`` among the late states or at its
+        capacity's place, or to the host where a revoke has landed; the
+        pool held to what the buffer holds now."""
+        if self.spilled:
+            self._stage(st.batch)
             return
-        from ..ops.aggregation import dense_path_selected
-        from ..ops.jitcache import key_bounds_violation_jit
-        if dense_path_selected(states, self.key_idx, self.aggs,
-                               key_bounds=self.key_bounds):
+        if late:
+            self.late.append(st)
+        else:
+            self.levels[st.batch.capacity] = st
+        self.ctx.release_all()
+        held = self.late + list(self.levels.values())
+        if not self.ctx.pool.try_reserve(
+                sum(batch_device_bytes(s.batch) for s in held), self.ctx):
+            self.ctx.revoke()
+
+    def _cut(self, st: _State) -> _State:
+        """``st`` with every key once (a state that may hold a key
+        twice goes through the group-by, alone) and, behind a readback,
+        at the bucket of its live groups."""
+        b = st.batch
+        if not st.unique:
+            dense = self._dense(b, b.capacity)
+            self._flag_bounds(b, dense)
+            b = grouped_aggregate(b, self.key_idx, self.aggs, mode="merge",
+                                  key_bounds=self.key_bounds,
+                                  allow_dense=self.allow_dense)
+            st = _State(b, True, not dense)
+        groups = None
+        if b.capacity > self.CUT_FLOOR:
+            groups = b.host_count("agg-state-groups")
+            cap = bucket_capacity(max(groups, 1), minimum=self.CUT_FLOOR)
+            if cap < b.capacity:
+                b = (prefix_jit(b, cap) if st.normalized
+                     else compact_jit(b, cap))
+        return _State(b, True, st.normalized, groups)
+
+    def _dense(self, like: Batch, lanes: int) -> bool:
+        """Host-only mirror of ``grouped_aggregate``'s dispatch for a
+        merge over ``lanes`` lanes laid out as ``like``."""
+        from ..ops.aggregation import (
+            _wide_state_aggs, dense_group_plan, has_drain_agg)
+        if not self.allow_dense or has_drain_agg(self.aggs) \
+                or _wide_state_aggs(self.aggs):
+            return False
+        return dense_group_plan(like, self.key_idx, lanes,
+                                self.key_bounds) is not None
+
+    def _merge(self, a: _State, b: _State) -> _State:
+        """Two states of unique keys as one; of one capacity where the
+        counter merges them, and ``a`` padded to ``b``'s in the fold."""
+        from ..ops.aggregation import merge_network_ok
+        n_keys = len(self.key_idx)
+        lanes = a.batch.capacity + b.batch.capacity
+        same_dicts = all(
+            x.dictionary is y.dictionary or x.dictionary == y.dictionary
+            for x, y in zip(a.batch.columns, b.batch.columns))
+        network = (a.normalized and b.normalized and same_dicts
+                   and a.batch.capacity == b.batch.capacity
+                   and lanes & (lanes - 1) == 0
+                   and merge_network_ok(a.batch, n_keys, self.aggs))
+        _AGG_MERGES.inc()
+        _AGG_LANES_MERGED.inc(lanes)
+        # groups_out: not known until the merged state's count is read,
+        # LATE states from now (the fold and the finish read theirs)
+        with TRACER.span("agg-merge", lanes_in=lanes, groups_out=-1,
+                         mode="network" if network else "sort"):
+            if network:
+                return _State(merge_states_jit(a.batch, b.batch, n_keys,
+                                               self.aggs), True, True)
+            dense = self._dense(a.batch, lanes)
+            self._flag_bounds(a.batch, dense)
+            self._flag_bounds(b.batch, dense)
+            if same_dicts:
+                merged = merge_pair_jit(a.batch, b.batch, self.key_idx,
+                                        self.aggs, self.key_bounds,
+                                        self.allow_dense)
+            else:
+                # string columns under different dictionaries: their
+                # codes are unified on the host, eagerly
+                merged = grouped_aggregate(
+                    concat_batches([a.batch, b.batch]), self.key_idx,
+                    self.aggs, mode="merge", key_bounds=self.key_bounds,
+                    allow_dense=self.allow_dense)
+            return _State(merged, True, not dense)
+
+    def _flag_bounds(self, states: Batch, dense: bool) -> None:
+        """Where the dense (clamping) path engages for a merge over
+        ``states``, emit the bounds-violation scalar — state batches
+        keep raw key values, so out-of-bounds keys from a sort-path
+        partial are still visible here (exec/local.py owns the
+        per-partial-batch flags)."""
+        if dense and self.key_bounds is not None \
+                and self.error_sink is not None:
+            from ..ops.jitcache import key_bounds_violation_jit
             self.error_sink(key_bounds_violation_jit(
                 states, self.key_idx, self.key_bounds))
 
@@ -468,41 +601,76 @@ class AggSpillBuffer:
 
     def _spill_all(self) -> int:
         _SPILL_REVOCATIONS.inc()
+        held = self.late + list(self.levels.values())
         with TRACER.span("spill-revoke", buffer="hash-agg",
-                         batches=len(self.device)):
+                         batches=len(held)):
             freed = 0
-            for b in self.device:
-                freed += self._stage(b)
-            self.device = []
+            for st in held:
+                freed += self._stage(st.batch)
+            self.late, self.levels = [], {}
             self.spilled = True
             return freed
+
+    # -- the finished state ---------------------------------------------------
+    def _fold(self) -> Optional[_State]:
+        """Everything held as ONE state of unique keys: the late states
+        placed, then the levels folded from the smallest up, the folded
+        state padded to the next level's capacity (the next is at least
+        as large: it is the smallest left and larger than the last)."""
+        self._settle(0)
+        acc: Optional[_State] = None
+        while True:
+            with self.ctx.pool.lock:
+                if self.spilled or not self.levels:
+                    break
+                st = self.levels.pop(min(self.levels))
+            if acc is None:
+                acc = st
+                continue
+            if acc.batch.capacity < st.batch.capacity:
+                acc = _State(pad_capacity_jit(acc.batch, st.batch.capacity),
+                             True, acc.normalized)
+            acc = self._cut(self._merge(acc, st))
+        if acc is not None and self.spilled:
+            with self.ctx.pool.lock:
+                self._stage(acc.batch)
+            return None
+        return acc
 
     def results(self, final: bool = True) -> Iterator[Batch]:
         """Final rows (default) or merged partial states (``final=False``,
         the PARTIAL-step output shipped to a downstream exchange)."""
-        mode = "final" if final else "merge"
+        state = self._fold()
         with self.ctx.pool.lock:
-            # consumers hold the yielded state from here on; snapshot the
-            # device list under the lock so a late revoke can't re-stage
-            # what we are about to yield
+            # consumers hold the yielded state from here on: a late
+            # revoke can't re-stage what we are about to yield
             self.ctx.pin()
-            spilled, device = self.spilled, list(self.device)
+            spilled = self.spilled
         if not spilled:
-            if not device:
+            if state is None:
                 return
-            states = (device[0] if len(device) == 1
-                      else concat_batches(device))
-            self._flag_bounds(states)
-            yield grouped_aggregate(states, self.key_idx, self.aggs,
-                                    mode=mode, key_bounds=self.key_bounds,
-                                    allow_dense=self.allow_dense)
+            with TRACER.span("agg-merge", lanes_in=state.batch.capacity,
+                             mode="finish") as span:
+                # the live groups where a cut has read them: a state
+                # that stayed under CUT_FLOOR is not read back for the
+                # counter's sake (the host would wait for the device
+                # where TPC-H Q1's sort overlaps it)
+                if state.groups is not None:
+                    _AGG_GROUPS.inc(state.groups)
+                span.annotate(groups_out=-1 if state.groups is None
+                              else state.groups)
+                out = (finish_states_jit(state.batch, len(self.key_idx),
+                                         self.aggs)
+                       if final else state.batch)
+            yield out
             return
+        mode = "final" if final else "merge"
         for p in range(self.n_partitions):
             part = None if self.store is None else \
                 self.store.partition_batch(p)
             if part is None:
                 continue
-            self._flag_bounds(part)
+            self._flag_bounds(part, self._dense(part, part.capacity))
             yield grouped_aggregate(part, self.key_idx, self.aggs,
                                     mode=mode, key_bounds=self.key_bounds,
                                     allow_dense=self.allow_dense)

@@ -32,7 +32,8 @@ from .prefix import prefix_sum
 # was inside grouped_aggregate's jit)
 from . import int128 as _int128  # noqa: F401
 from .. import types as T
-from ..batch import Batch, Column, Schema, bucket_capacity
+from ..batch import (Batch, Column, Schema, bucket_capacity, compress_lanes,
+                     compress_moves, live_indices, per_lane, shift_lanes)
 from ..types import Type
 
 _VARIANCE_FNS = ("var_samp", "var_pop", "stddev_samp",
@@ -180,18 +181,31 @@ def mark_distinct_flags(batch: Batch,
     return jnp.zeros(batch.capacity, dtype=bool).at[s_idx].set(boundary)
 
 
+#: the rank operand of a dead row; a live row's rank holds a bit a key,
+#: set where that key is NULL (the first key's bit the highest)
+_DEAD_RANK = 1 << 30
+
+
 def _group_key_ops(batch: Batch,
                    group_indices: Sequence[int]) -> List[jnp.ndarray]:
-    """Lexicographic sort operands for GROUP BY keys: [dead_rank, then per
-    key (null_rank, null-neutralized data)]. Shared by every kernel whose
-    output rows must align positionally across separate sorts of the same
-    batch (grouped_aggregate and the percentile drain)."""
-    dead_rank = jnp.where(batch.row_mask, 0, 1).astype(jnp.int32)
-    key_ops: List[jnp.ndarray] = [dead_rank]
-    for gi in group_indices:
+    """Sort operands for GROUP BY keys: [rank, then per key its
+    null-neutralized data]. ONE int32 rank carries what orders rows
+    before their key data does: dead rows last, and the pattern of NULL
+    keys (every operand more costs XLA's TPU sort compile seconds;
+    grouping needs equal tuples adjacent, not any particular order of
+    the groups). Shared by every kernel whose output rows must align
+    positionally across separate sorts of the same batch
+    (grouped_aggregate and the percentile drain), and the order the
+    merge network of :func:`merge_states` keeps."""
+    assert len(group_indices) < 30
+    rank = jnp.where(batch.row_mask, 0, _DEAD_RANK).astype(jnp.int32)
+    key_ops: List[jnp.ndarray] = [rank]
+    for j, gi in enumerate(group_indices):
         c = batch.columns[gi]
         data = c.data
-        key_ops.append(jnp.where(c.validity, 0, 1).astype(jnp.int32))  # nulls last
+        null_bit = 1 << (len(group_indices) - 1 - j)
+        rank = rank | jnp.where(c.validity | ~batch.row_mask, 0,
+                                null_bit).astype(jnp.int32)
         if getattr(data, "ndim", 1) == 2:
             # long-decimal limb pairs: lexicographic (hi, unsigned lo)
             # is value order (ops/int128.py sortable_lo)
@@ -205,6 +219,7 @@ def _group_key_ops(batch: Batch,
             data = data.astype(jnp.int32)
         # neutralize NULL rows' data so stale values can't split NULL groups
         key_ops.append(jnp.where(c.validity, data, jnp.zeros_like(data)))
+    key_ops[0] = rank
     return key_ops
 
 
@@ -215,8 +230,8 @@ def _boundary_groups(s_keys, s_mask):
         diff = diff | (op != jnp.roll(op, 1))
     first = jnp.zeros_like(s_mask).at[0].set(True)
     boundary = s_mask & (diff | first)
-    group_id = jnp.maximum(prefix_sum(boundary.astype(jnp.int64)) - 1, 0)
-    num_groups = jnp.sum(boundary.astype(jnp.int64))
+    group_id = jnp.maximum(prefix_sum(boundary.astype(jnp.int32)) - 1, 0)
+    num_groups = jnp.sum(boundary.astype(jnp.int32))
     return boundary, group_id, num_groups
 
 
@@ -233,15 +248,29 @@ def _group_sort(batch: Batch, group_indices: Sequence[int]):
     the whole batch through the comparator is never worth it.
     """
     key_ops = _group_key_ops(batch, group_indices)
-    idx = jnp.arange(batch.capacity, dtype=jnp.int32)
-    out = jax.lax.sort(key_ops + [idx], num_keys=len(key_ops),
-                       is_stable=True)
-    s_keys = out[1:-1]                    # sorted key operands (minus dead rank)
-    perm = out[-1]
-    s_mask = jnp.take(batch.row_mask, perm, axis=0)
-    s_data = [jax.tree_util.tree_map(
-        lambda a: jnp.take(a, perm, axis=0), c.data) for c in batch.columns]
-    s_valid = [jnp.take(c.validity, perm, axis=0) for c in batch.columns]
+    rows = ([c.data for c in batch.columns],
+            [c.validity for c in batch.columns], batch.row_mask)
+
+    def by_sort():
+        idx = jnp.arange(batch.capacity, dtype=jnp.int32)
+        # the row index is the LAST KEY: every row's tuple differs, so
+        # the unstable sort is deterministic (compiled for a described
+        # v5e at 2^20 rows: stable 124 s, unstable 61 s; PR 33)
+        out = jax.lax.sort(key_ops + [idx], num_keys=len(key_ops) + 1,
+                           is_stable=False)
+        return list(out[:-1]), jax.tree_util.tree_map(
+            lambda a: jnp.take(a, out[-1], axis=0), rows)
+
+    # A batch whose rows stand in the keys' order already (a fact table
+    # clustered by its key, a probe side that kept its order: lineitem
+    # by l_orderkey in TPC-H Q3 and Q18) is grouped as it stands: no
+    # sort, and no gather a column behind it (~11 ns a lane a column on
+    # the v5e). Observed a batch, on the device; both branches compile.
+    before = [shift_lanes(k, -1) for k in key_ops]
+    ordered = jnp.all((_lex_greater(key_ops, before)
+                       | _lex_equal(key_ops, before))[1:])
+    s_keys, (s_data, s_valid, s_mask) = jax.lax.cond(
+        ordered, lambda: (key_ops, rows), by_sort)
     boundary, group_id, num_groups = _boundary_groups(s_keys, s_mask)
     return s_data, s_valid, s_mask, boundary, group_id, num_groups
 
@@ -384,47 +413,47 @@ def _dense_key_columns(batch: Batch, group_indices: Sequence[int],
 
 
 class _SegReducers:
-    """Group reductions over a precomputed group id via ``segment_*``
-    scatter ops — the right shape when group ids are dense from a sort
-    (num_segments is large, ids are sorted runs).
+    """Group reductions over the sorted runs of a group sort: ``gid``
+    the dense group id a row, ``boundary`` the first row of each run,
+    ``live`` the rows that count; results hold group g at index g, in
+    ``cap`` lanes.
 
-    When ``starts`` is provided (sorted-run group ids with per-group
-    start indices, absent groups pointing one past the end), 64-bit
-    sums take the scan path instead of the scatter: i64 goes through
-    the Pallas digit-plane cumsum (ops/pallas_scan.py, exact), f64
-    through an XLA cumsum + boundary differences — the 64-bit scatter
-    runs ~8M rows/s on this chip while linear scans stream 50-80x
-    faster. f64 prefix differences round differently than per-group
-    scatter order, which SQL sum(double) permits."""
+    A sum is a prefix sum over the rows and, a run, the difference of
+    its last row's and of the row before its first: both picked out by
+    the compress network (``batch.compress_moves``), the runs in their
+    order, so no scatter and no gather (the 64-bit scatter runs ~8M
+    rows/s on the v5e, a gather ~11 ns a lane; an int64 wraps and its
+    differences are exact; f64 prefix differences round differently
+    than per-group scatter order, which SQL sum(double) permits). min
+    and max stay segment scatters."""
 
     def __init__(self, group_id: jnp.ndarray, cap: int,
-                 starts: Optional[jnp.ndarray] = None,
-                 n_rows: Optional[int] = None):
+                 boundary: jnp.ndarray, live: jnp.ndarray):
         self.gid, self.cap = group_id, cap
-        self.starts, self.n_rows = starts, n_rows
+        self.first, self.num_groups = compress_moves(boundary)
+        # a run's last row: the next is another run's first, or dead
+        self.last, _ = compress_moves(
+            live & (shift_lanes(boundary, 1) | ~shift_lanes(live, 1)))
+
+    def _fit(self, x):
+        n = x.shape[0]
+        if self.cap <= n:
+            return x[:self.cap]
+        return jnp.pad(x, [(0, self.cap - n)] + [(0, 0)] * (x.ndim - 1))
+
+    def front(self, x):
+        """``x`` at each run's first row, group g at index g."""
+        return self._fit(compress_lanes(self.first, x))
 
     def count(self, valid):
-        return self.sum(valid.astype(jnp.int64))
+        return self.sum(valid.astype(jnp.int32)).astype(jnp.int64)
 
     def sum(self, x):
-        if self.starts is not None and getattr(x, "ndim", 0) == 1:
-            from .pallas_scan import pallas_supported, segment_sum_sorted_i64
-            if x.dtype == jnp.int64 and pallas_supported():
-                return segment_sum_sorted_i64(
-                    x, self.starts, self.cap,
-                    max_rows_per_group=self.n_rows)
-            if x.dtype == jnp.float64 and pallas_supported():
-                n = x.shape[0]
-                csum = prefix_sum(x)
-                prev = jnp.clip(self.starts - 1, 0, n - 1)
-                ends = jnp.concatenate(
-                    [jnp.clip(self.starts[1:] - 1, 0, n - 1),
-                     jnp.full((1,), n - 1, self.starts.dtype)])
-                hi = jnp.take(csum, ends, axis=0)
-                lo = jnp.where(self.starts <= 0, 0.0,
-                               jnp.take(csum, prev, axis=0))
-                return hi - lo
-        return jax.ops.segment_sum(x, self.gid, num_segments=self.cap)
+        if getattr(x, "ndim", 0) != 1:
+            return jax.ops.segment_sum(x, self.gid, num_segments=self.cap)
+        csum = prefix_sum(x)
+        return self._fit(compress_lanes(self.last, csum)
+                         - compress_lanes(self.first, shift_lanes(csum, -1)))
 
     def min(self, x):
         return jax.ops.segment_min(x, self.gid, num_segments=self.cap)
@@ -897,14 +926,14 @@ def _grouped_percentiles(batch: Batch, group_indices: Sequence[int],
     vneutral = jnp.where(valid, vdata, jnp.zeros_like(vdata))
     out = jax.lax.sort(key_ops + [val_null, vneutral],
                        num_keys=len(key_ops) + 2, is_stable=False)
-    s_live = out[0] == 0
-    s_keys = out[1:len(key_ops)]
+    s_live = out[0] < _DEAD_RANK
+    s_keys = out[:len(key_ops)]
     s_vnull, s_vals = out[-2], out[-1]
     boundary, group_id, num_groups = _boundary_groups(s_keys, s_live)
     nvalid = jax.ops.segment_sum(
         (s_live & (s_vnull == 0)).astype(jnp.int64), group_id,
         num_segments=cap)
-    bidx = jnp.nonzero(boundary, size=cap, fill_value=batch.capacity - 1)[0]
+    bidx = live_indices(boundary, cap)[0]
     out_mask = jnp.arange(cap) < num_groups
     results = []
     for k in _select_ks(aggs, nvalid):
@@ -1047,26 +1076,18 @@ def grouped_aggregate(
         s_data, s_valid, s_mask, boundary, group_id, num_groups = \
             _group_sort(batch, group_indices)
 
-        # group key output: gather the first row of each segment
-        bidx = jnp.nonzero(boundary, size=cap,
-                           fill_value=batch.capacity - 1)[0]
+        red = _SegReducers(group_id, cap, boundary, s_mask)
         out_mask = jnp.arange(cap) < num_groups
+        # group key output: the first row of each run
         key_cols = []
         for gi in group_indices:
             c = batch.columns[gi]
             key_cols.append(Column(
                 c.type,
-                jnp.take(s_data[gi], bidx, axis=0),
-                jnp.take(s_valid[gi], bidx, axis=0) & out_mask,
+                jax.tree_util.tree_map(red.front, s_data[gi]),
+                red.front(s_valid[gi]) & out_mask,
                 c.dictionary,
             ))
-
-        # sorted-run starts for the scan-path 64-bit sums (absent groups
-        # point one past the end — see pallas_scan.segment_sum_sorted_i64)
-        starts = jnp.where(out_mask, bidx,
-                           batch.capacity).astype(jnp.int32)
-        red = _SegReducers(group_id, cap, starts=starts,
-                           n_rows=batch.capacity)
         if from_states:
             state_data = s_data[n_keys:]
             state_dicts = [c.dictionary for c in batch.columns[n_keys:]]
@@ -1078,6 +1099,18 @@ def grouped_aggregate(
                                 red, from_states=False,
                                 col_dicts=[c.dictionary
                                            for c in batch.columns])
+
+    return _assemble(batch, group_indices, aggs, mode, key_cols, seg,
+                     out_mask)
+
+
+def _assemble(batch: Batch, group_indices: Sequence[int],
+              aggs: Sequence[AggSpec], mode: str, key_cols: List[Column],
+              seg, out_mask: jnp.ndarray) -> Batch:
+    """The output batch of a grouping over ``batch``: the key columns,
+    then the states (``partial``/``merge``) or the finalized values of
+    the per-aggregate reductions ``seg``."""
+    from_states = mode in ("final", "merge")
 
     def value_dict(agg: AggSpec):
         """Dictionary for a string-valued min/max output/state column."""
@@ -1114,6 +1147,168 @@ def grouped_aggregate(
                 valid & out_mask,
                 value_dict(agg) if agg.output_type.is_string else None))
     return Batch(Schema(out_fields), out_cols, out_mask)
+
+
+# -- states of unique keys: merge without a sort, finish without one ----------
+#
+# What a sort-path ``partial`` or ``merge`` gives back is NORMALIZED: its
+# live rows first, ascending by the operands of :func:`_group_key_ops`,
+# every key once. Two such states merge in a bitonic merge network
+# (log2 of the lanes elementwise passes, the state columns riding along)
+# where ``lax.sort`` would sort them as if they were in no order and a
+# gather a column would follow (~11 ns a lane a column on the v5e); and
+# any state of unique keys finishes lane by lane.
+
+def merge_network_ok(batch: Batch, n_keys: int,
+                     aggs: Sequence[AggSpec]) -> bool:
+    """Host-only: can :func:`merge_states` take states laid out as
+    ``batch``? Its comparator is the signed integer order ``lax.sort``
+    gives integer operands, so every key is one integer (or boolean, or
+    dictionary-coded) column; a DOUBLE key (NaN, the zeros) or a
+    decimal(38) limb pair sorts through ``grouped_aggregate``."""
+    if has_drain_agg(aggs) or n_keys >= 30:
+        return False
+    for c in batch.columns[:n_keys]:
+        if getattr(c.data, "ndim", 1) != 1 or not (
+                c.data.dtype == jnp.bool_
+                or jnp.issubdtype(c.data.dtype, jnp.integer)):
+            return False
+    return True
+
+
+def _lex_greater(a: Sequence[jnp.ndarray],
+                 b: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    gt = jnp.zeros(a[0].shape, bool)
+    eq = jnp.ones(a[0].shape, bool)
+    for x, y in zip(a, b):
+        gt = gt | (eq & (x > y))
+        eq = eq & (x == y)
+    return gt
+
+
+def _lex_equal(a: Sequence[jnp.ndarray],
+               b: Sequence[jnp.ndarray]) -> jnp.ndarray:
+    eq = jnp.ones(a[0].shape, bool)
+    for x, y in zip(a, b):
+        eq = eq & (x == y)
+    return eq
+
+
+def _bitonic_merge(keys: List[jnp.ndarray],
+                   payload: List[jnp.ndarray]):
+    """``keys`` (lexicographic, each half of the lanes ascending) and
+    the ``payload`` that rides along, all lanes ascending. The second
+    half is reversed, which makes the lanes bitonic, and log2(lanes)
+    half-cleaners follow: lane i and lane i ^ s keep the smaller and
+    the larger tuple. Flat elementwise passes over shifted copies, as
+    ``batch.live_indices``: no sort, no gather, no small minor
+    dimension for the TPU to pad."""
+    n = keys[0].shape[0]
+    ops = [jnp.concatenate([x[:n // 2], jnp.flip(x[n // 2:], axis=0)])
+           for x in list(keys) + list(payload)]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    s = n // 2
+    while s >= 1:
+        low = (lane & s) == 0
+        other = [jnp.where(per_lane(low, x), shift_lanes(x, s),
+                           shift_lanes(x, -s)) for x in ops]
+        mine, theirs = ops[:len(keys)], other[:len(keys)]
+        take = jnp.where(low, _lex_greater(mine, theirs),
+                         _lex_greater(theirs, mine))
+        ops = [jnp.where(per_lane(take, x), o, x)
+               for x, o in zip(ops, other)]
+        s //= 2
+    return ops[:len(keys)], ops[len(keys):]
+
+
+class _PairReducers:
+    """Group reductions over sorted runs of AT MOST TWO rows (two
+    states of unique keys, merged), each group's value moved to the
+    front by the compress network: no scatter, no gather."""
+
+    def __init__(self, boundary: jnp.ndarray, live: jnp.ndarray):
+        #: lane i + 1 holds the second row of lane i's run
+        self.pair = shift_lanes(live & ~boundary, 1)
+        self.boundary = boundary
+        self.moves, self.num_groups = compress_moves(boundary)
+
+    def _with_second(self, x, fn):
+        return self.front(jnp.where(per_lane(self.pair, x),
+                                    fn(x, shift_lanes(x, 1)), x))
+
+    def front(self, x):
+        return compress_lanes(self.moves, x)
+
+    def count(self, valid):
+        return self.sum(valid.astype(jnp.int64))
+
+    def sum(self, x):
+        return self._with_second(x, jnp.add)
+
+    def min(self, x):
+        return self._with_second(x, jnp.minimum)
+
+    def max(self, x):
+        return self._with_second(x, jnp.maximum)
+
+    def gather(self, per_group):
+        gid = jnp.maximum(prefix_sum(self.boundary.astype(jnp.int32)) - 1, 0)
+        return jnp.take(per_group, gid, axis=0)
+
+
+class _RowReducers:
+    """Every row a group of its own (one state of unique keys)."""
+
+    count = staticmethod(lambda valid: valid.astype(jnp.int64))
+    sum = min = max = gather = staticmethod(lambda x: x)
+
+
+def merge_states(a: Batch, b: Batch, n_keys: int,
+                 aggs: Sequence[AggSpec]) -> Batch:
+    """Two NORMALIZED states of one layout and capacity (and equal
+    dictionaries: ``merge_network_ok`` and the caller see to that) as
+    one, normalized, of twice the capacity: ``grouped_aggregate(...,
+    mode="merge")`` over their concatenation, without its sort and its
+    gathers."""
+    keys_idx = list(range(n_keys))
+    both = [jnp.concatenate([x, y]) for x, y in zip(
+        _group_key_ops(a, keys_idx) + [c.data for c in a.columns[n_keys:]],
+        _group_key_ops(b, keys_idx) + [c.data for c in b.columns[n_keys:]])]
+    s_keys, s_state = _bitonic_merge(both[:n_keys + 1], both[n_keys + 1:])
+    rank = s_keys[0]
+    s_mask = rank < _DEAD_RANK
+    boundary, _, _ = _boundary_groups(s_keys, s_mask)
+    red = _PairReducers(boundary, s_mask)
+    cap = rank.shape[0]
+    out_mask = jnp.arange(cap) < red.num_groups
+    front_rank = red.front(rank)
+    key_cols = []
+    for j, c in enumerate(a.columns[:n_keys]):
+        data = red.front(s_keys[1 + j])
+        if c.data.dtype == jnp.bool_:
+            data = data.astype(jnp.bool_)
+        valid = (front_rank & (1 << (n_keys - 1 - j))) == 0
+        key_cols.append(Column(c.type, data, valid & out_mask,
+                               c.dictionary))
+    n_state = len(s_state)
+    seg = _segment_aggs(
+        aggs, s_state, [s_mask] * n_state, s_mask, red, from_states=True,
+        col_dicts=[c.dictionary for c in a.columns[n_keys:]])
+    return _assemble(a, keys_idx, aggs, "merge", key_cols, seg, out_mask)
+
+
+def finish_states(state: Batch, n_keys: int,
+                  aggs: Sequence[AggSpec]) -> Batch:
+    """A state of UNIQUE keys (any partial or merge output, normalized
+    or not) finalized lane by lane: ``grouped_aggregate(...,
+    mode="final")`` over it, without the sort."""
+    cols = state.columns[n_keys:]
+    seg = _segment_aggs(
+        aggs, [c.data for c in cols], [c.validity for c in cols],
+        state.row_mask, _RowReducers, from_states=True,
+        col_dicts=[c.dictionary for c in cols])
+    return _assemble(state, list(range(n_keys)), aggs, "final",
+                     list(state.columns[:n_keys]), seg, state.row_mask)
 
 
 def global_aggregate(

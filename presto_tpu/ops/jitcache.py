@@ -26,7 +26,8 @@ from .._devtools import lockcheck as _lockcheck
 from ..obs import profiler as _prof
 from ..obs.metrics import REGISTRY
 from ..obs.trace import TRACER
-from .aggregation import AggSpec, global_aggregate, grouped_aggregate
+from .aggregation import (
+    AggSpec, finish_states, global_aggregate, grouped_aggregate, merge_states)
 
 _JIT_HITS = REGISTRY.counter("jit_cache_hits_total")
 _JIT_MISSES = REGISTRY.counter("jit_cache_misses_total")
@@ -266,6 +267,63 @@ def grouped_aggregate_jit(batch, group_indices: Sequence[int],
                     output_capacity,
                     tuple(key_bounds) if key_bounds else None,
                     allow_dense)(batch)
+
+
+def _merge_pair_factory(group_indices, aggs, key_bounds, allow_dense):
+    def run(a, b):
+        from ..batch import concat_batches
+        return grouped_aggregate(concat_batches([a, b]), group_indices,
+                                 aggs, "merge", None,
+                                 allow_dense=allow_dense,
+                                 key_bounds=key_bounds)
+    return run
+
+
+_merge_pair = _entry_cache("grouped_aggregate_pair", _merge_pair_factory)
+
+
+def merge_pair_jit(a, b, group_indices: Sequence[int],
+                   aggs: Sequence[AggSpec], key_bounds=None,
+                   allow_dense: bool = True):
+    """Two state batches of one layout and equal dictionaries merged by
+    ``grouped_aggregate`` over their concatenation, INSIDE the program
+    (``AggSpillBuffer``'s merge where the network cannot take the
+    pair)."""
+    return _merge_pair(tuple(group_indices), tuple(aggs),
+                       tuple(key_bounds) if key_bounds else None,
+                       allow_dense)(a, b)
+
+
+_merge_states = _entry_cache(
+    "grouped_aggregate_merge",
+    lambda n_keys, aggs: lambda a, b: merge_states(a, b, n_keys, aggs))
+
+
+def merge_states_jit(a, b, n_keys: int, aggs: Sequence[AggSpec]):
+    """``ops.aggregation.merge_states``: two normalized states of one
+    capacity as one, without a sort or a gather."""
+    return _merge_states(n_keys, tuple(aggs))(a, b)
+
+
+_finish_states = _entry_cache(
+    "grouped_aggregate_finish",
+    lambda n_keys, aggs: lambda s: finish_states(s, n_keys, aggs))
+
+
+def finish_states_jit(state, n_keys: int, aggs: Sequence[AggSpec]):
+    """``ops.aggregation.finish_states``: a state of unique keys
+    finalized lane by lane."""
+    return _finish_states(n_keys, tuple(aggs))(state)
+
+
+_prefix = _entry_cache(
+    "prefix", lambda capacity: lambda b: b.prefix(capacity))
+
+
+def prefix_jit(batch, capacity: int):
+    """The first ``capacity`` lanes of a batch whose live rows come
+    first (a sort-path state): a slice, where ``compact_jit`` gathers."""
+    return _prefix(capacity)(batch)
 
 
 def _bounds_violation_factory(group_indices, key_bounds):

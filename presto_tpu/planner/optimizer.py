@@ -27,6 +27,7 @@ from ..expr import ir
 from ..expr.rewrite import (
     combine_conjuncts, conjuncts, referenced_inputs, remap_inputs,
 )
+from ..obs.metrics import REGISTRY
 from ..sql.analyzer import Field
 from .plan import (
     AggregationNode, DistinctNode, FilterNode, JoinNode, LimitNode,
@@ -223,6 +224,24 @@ def _rewrite_joins(node: PlanNode, session: Session) -> PlanNode:
     # top-down: a filter directly above a join tree contributes its
     # conjuncts to the join graph BEFORE the tree is reordered; leaves of
     # the graph are rewritten recursively inside _plan_join_graph
+    if isinstance(node, SemiJoinNode):
+        # `key IN (subquery)` conjuncts sit above the WHERE's filter
+        # (planner.plan_query_spec): over an inner join tree each goes
+        # into the join graph, to be planned on the relation that owns
+        # its key (reference PredicatePushDown, which pushes the
+        # conjunct before TransformUncorrelatedInPredicateSubqueryToSemiJoin
+        # makes it a semi join)
+        semis: List[SemiJoinNode] = []
+        below: PlanNode = node
+        while isinstance(below, SemiJoinNode):
+            semis.append(below)
+            below = below.source
+        preds = []
+        if isinstance(below, FilterNode):
+            preds, below = [below.predicate], below.child
+        if isinstance(below, JoinNode) and below.join_type in ("cross",
+                                                               "inner"):
+            return _plan_join_graph(below, preds, session, semis[::-1])
     if (isinstance(node, FilterNode) and isinstance(node.child, JoinNode)
             and node.child.join_type in ("cross", "inner")):
         return _plan_join_graph(node.child, [node.predicate], session)
@@ -341,8 +360,22 @@ def _estimate_rows(node: PlanNode, session: Session) -> float:
     return _stats_calc(session).rows(node)
 
 
+#: semi joins planned on the one relation of a join tree that owns their
+#: key, below the joins (EXPLAIN shows where each sits)
+_SEMIJOIN_PUSHED = REGISTRY.counter("plan_semijoin_pushed_total")
+
+
 def _plan_join_graph(join: JoinNode, extra_preds: List[ir.Expr],
-                     session: Session) -> PlanNode:
+                     session: Session,
+                     semis: Sequence[SemiJoinNode] = ()) -> PlanNode:
+    """Plan the inner join tree under ``join`` as a graph: single-leaf
+    predicates filter their leaf, equalities between leaves are edges,
+    the order is greedy by estimate. ``semis`` are the semi joins that
+    stood above the tree (innermost first, keys as positions of the
+    tree's output): one whose source columns all come from ONE leaf
+    filters that leaf, IN and NOT IN alike (either is a predicate over
+    the row's key and the whole filtering set, so it commutes with an
+    inner join whatever is NULL); the others go back on top."""
     leaves: List[PlanNode] = []
     preds: List[ir.Expr] = []
     _flatten_join_tree(join, leaves, preds, 0)
@@ -392,6 +425,29 @@ def _plan_join_graph(join: JoinNode, extra_preds: List[ir.Expr],
         if ps else lf
         for lf, ps in ((leaves[i], leaf_preds[i]) for i in range(len(leaves)))
     ]
+    above: List[SemiJoinNode] = []
+    for semi in semis:
+        refs = set(semi.source_keys)
+        if semi.residual is not None:
+            refs |= {r for r in referenced_inputs(semi.residual) if r < total}
+        ls = {leaf_of(r) for r in refs}
+        if len(ls) != 1:
+            above.append(semi)
+            continue
+        (li,) = ls
+        n_leaf = len(leaves[li].fields)
+        shift = {r: r - offsets[li] for r in refs}
+        residual = semi.residual
+        if residual is not None:
+            shift.update({r: r - total + n_leaf
+                          for r in referenced_inputs(residual) if r >= total})
+            residual = remap_inputs(residual, shift)
+        new_leaves[li] = dataclasses.replace(
+            semi, source=new_leaves[li],
+            filtering=_rewrite_joins(semi.filtering, session),
+            source_keys=tuple(shift[k] for k in semi.source_keys),
+            fields=leaves[li].fields, residual=residual)
+        _SEMIJOIN_PUSHED.inc()
     sizes = [_estimate_rows(nl, session) for nl in new_leaves]
 
     # greedy join order: start from the largest leaf (fact table), repeatedly
@@ -511,7 +567,12 @@ def _plan_join_graph(join: JoinNode, extra_preds: List[ir.Expr],
         ir.input_ref(gmap[g], _field_at(leaves, offsets, g).type)
         for g in range(total))
     fields = tuple(_field_at(leaves, offsets, g) for g in range(total))
-    return ProjectNode(child=current, exprs=exprs, fields=fields)
+    result: PlanNode = ProjectNode(child=current, exprs=exprs, fields=fields)
+    for semi in above:
+        result = dataclasses.replace(
+            semi, source=result,
+            filtering=_rewrite_joins(semi.filtering, session))
+    return result
 
 
 def _field_at(leaves, offsets, g: int) -> Field:
@@ -748,6 +809,8 @@ def _key_unique(node: PlanNode, keys: Sequence[int],
         return set(keys) == set(range(len(node.fields)))
     if isinstance(node, (FilterNode, SortNode, TopNNode, LimitNode)):
         return _key_unique(node.child, keys, session)
+    if isinstance(node, SemiJoinNode):      # a filter on its source
+        return _key_unique(node.source, keys, session)
     if isinstance(node, ProjectNode):
         src = []
         for k in keys:

@@ -188,9 +188,11 @@ def test_profile_holds_the_engines_spans_nested(runner, tracer_on,
     by_id = {s["spanId"]: s for s in spans}
     for s in spans:
         if s["name"] == "dispatch":
-            # a launch made while a sync waits is the sync's child
+            # a launch made while a sync waits is the sync's child, one
+            # made by a merge of the grouped state the merge's
             parent = by_id[s["parentId"]]["name"]
-            assert parent.startswith("op:") or parent == "device-sync"
+            assert parent.startswith("op:") \
+                or parent in ("device-sync", "agg-merge")
 
 
 def test_tracer_off_constructs_nothing(runner, monkeypatch):

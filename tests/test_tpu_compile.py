@@ -153,6 +153,34 @@ def test_compact_compiles_for_v5e_without_a_scatter(one_chip, cap):
     assert c.memory_analysis() is not None
 
 
+def test_state_merge_compiles_for_v5e_without_a_sort_or_a_gather(one_chip):
+    """``jit_op_grouped_aggregate_merge`` over TPC-H Q18's subquery
+    state (an order key, a DOUBLE sum and its count) at 2^20 lanes a
+    side: the merge network, the pair reducers and the compress network
+    are elementwise passes; the TPU compiler's program holds no sort,
+    no gather and no scatter."""
+    import re
+    from presto_tpu import types as T
+    from presto_tpu.batch import Batch, Schema
+    from presto_tpu.ops.aggregation import AggSpec, grouped_aggregate
+    from presto_tpu.ops.jitcache import _merge_states
+    aggs = (AggSpec("sum", 1, T.DOUBLE, "s"),)
+    state = grouped_aggregate(Batch.from_arrays(
+        Schema([("k", T.BIGINT), ("v", T.DOUBLE)]),
+        [[1, 2, 2], [1.0, 2.0, 3.0]], num_rows=3), [0], aggs,
+        mode="partial")
+
+    def widen(leaf):
+        return jax.ShapeDtypeStruct((N_BATCH,) + leaf.shape[1:],
+                                    leaf.dtype, sharding=one_chip)
+    side = jax.tree_util.tree_map(widen, state)
+    c = _merge_states(1, aggs).fn.lower(side, side).compile()
+    text = c.as_text()
+    assert "jit_op_grouped_aggregate_merge" in text
+    assert not re.findall(r"\s(scatter|sort|gather)\(", text)
+    assert c.memory_analysis() is not None
+
+
 def _probe_shapes(one_chip):
     i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,  # noqa: E731
                                          sharding=one_chip)

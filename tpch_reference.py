@@ -1,4 +1,4 @@
-"""Plain NumPy implementations of TPC-H Q6, Q1 and Q3 over the chunks
+"""Plain NumPy implementations of TPC-H Q6, Q1, Q3 and Q18 over the chunks
 ``TpchConnector`` generates — the reference ``chip_smoke.py`` calls
 (as the answer the chip must reproduce). They share no code with the
 engine beyond the generator that makes the data: no planner, no expression compiler, no
@@ -29,6 +29,9 @@ Q3_LINEITEM_COLS = ["l_orderkey", "l_extendedprice", "l_discount",
 Q3_ORDERS_COLS = ["o_orderkey", "o_custkey", "o_orderdate",
                   "o_shippriority"]
 Q3_CUSTOMER_COLS = ["c_custkey", "c_mktsegment"]
+Q18_LINEITEM_COLS = ["l_orderkey", "l_quantity"]
+Q18_ORDERS_COLS = ["o_orderkey", "o_custkey", "o_totalprice", "o_orderdate"]
+Q18_CUSTOMER_COLS = ["c_custkey", "c_name"]
 
 
 def stage_host(conn, table, cols, rows_per_batch=1 << 20):
@@ -50,7 +53,10 @@ def stage_host(conn, table, cols, rows_per_batch=1 << 20):
         vocabs = []
         for name in cols:
             arr, vocab = data[name]
-            assert vocab != "text", "free-text columns not staged"
+            if isinstance(vocab, str):     # free text: the strings as they are
+                arrays.append(np.asarray(arr, dtype=object))
+                vocabs.append(None)
+                continue
             arrays.append(np.asarray(arr))
             vocabs.append(tuple(vocab) if vocab is not None else None)
         host.append(tuple(arrays) + (np.ones(cn, dtype=bool),))
@@ -149,3 +155,30 @@ def q3_numpy(c_host, o_host, li_host, seg_code: int, limit: int = 10):
     return [(int(k), float(r), int(d), int(pr))
             for k, r, d, pr in zip(bkey[nz][order], rev_acc[nz][order],
                                    bdate[nz][order], bprio[nz][order])]
+
+
+def q18_numpy(c_host, o_host, li_host, quantity: float, limit: int = 100):
+    """Chunks in ``Q18_*_COLS`` orders -> the first ``limit`` of (c_name,
+    c_custkey, o_orderkey, o_orderdate epoch day, o_totalprice,
+    sum(l_quantity)) by o_totalprice desc, o_orderdate, o_orderkey, over
+    the orders whose quantities add up to MORE than ``quantity``."""
+    ck, cname, cmask = tuple(
+        np.concatenate([h[i] for h in c_host]) for i in range(3))
+    ok_, ocust, oprice, odate, omask = tuple(
+        np.concatenate([h[i] for h in o_host]) for i in range(5))
+    lk, lqty, lmask = tuple(
+        np.concatenate([h[i] for h in li_host]) for i in range(3))
+    # a bincount of quantities per order (quantities are whole numbers:
+    # the sums are exact), then the threshold, strictly
+    total = np.bincount(lk[lmask], weights=np.round(lqty[lmask], 2),
+                        minlength=int(ok_.max()) + 1)
+    om = omask & (total[ok_] > quantity)
+    okey, cust, price, date = ok_[om], ocust[om], oprice[om], odate[om]
+    # the three lookups: the order's sum, its customer's row, their name
+    c_order = np.argsort(ck[cmask], kind="stable")
+    pos = c_order[np.searchsorted(ck[cmask][c_order], cust)]
+    assert (ck[cmask][pos] == cust).all()
+    name = cname[cmask][pos]
+    order = np.lexsort((okey, date, -price))[:limit]
+    return [(str(name[i]), int(cust[i]), int(okey[i]), int(date[i]),
+             float(price[i]), float(total[okey[i]])) for i in order]
