@@ -161,6 +161,8 @@ _PATH_COUNTERS = (
     "agg_dense_path_selected_total", "agg_sort_path_selected_total",
     "join_strategy_selected_total.direct.replicated",
     "join_strategy_selected_total.direct.partitioned",
+    "join_strategy_selected_total.compare.replicated",
+    "join_strategy_selected_total.compare.partitioned",
     "join_strategy_selected_total.sorted.replicated",
     "join_strategy_selected_total.sorted.partitioned",
     "join_strategy_selected_total.expand.replicated",

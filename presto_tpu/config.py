@@ -109,7 +109,7 @@ _sp("grouped_execution", "boolean", True,
 _sp("join_dense_path", "boolean", True,
     "stats-driven dense-key direct-address join builds: the planner "
     "attaches hard build-key bounds (JoinNode.key_bounds) and the "
-    "executor answers bounded key tuples in two gathers")
+    "executor answers bounded key tuples in one gather")
 _sp("join_pallas_probe", "boolean", False,
     "fuse direct-join probe lookup + liveness + payload gathers into "
     "the Pallas ragged-gather kernel on TPU backends. Off by default: "

@@ -65,7 +65,7 @@ _FUSED_TAIL_LANES = REGISTRY.counter("fused_tail_lanes_total")
 
 def _note_join_strategy(stats, node, strategy: str, dist: str) -> None:
     """Join-dispatch observability: one count per executed join/semi
-    operator, labeled strategy (direct / sorted / expand) x
+    operator, labeled strategy (direct / compare / sorted / expand) x
     distribution — the trace-level signal the strategy-selection tests
     assert on, next to EXPLAIN ANALYZE's per-row [strategy ...] suffix."""
     REGISTRY.counter(
@@ -1397,14 +1397,13 @@ class _Executor:
                                             summary=summary,
                                             key_bounds=nd.key_bounds)
             from ..ops import pallas_join as PJ
-            from ..ops.join import is_direct_prepared
+            from ..ops.join import lookup_form
             payload_cols = tuple(range(len(nd.right.fields)))
             use_pallas = (self._pallas_probe_on()
                           and PJ.supports_join(prep, build,
                                                payload_cols))
             _note_join_strategy(
-                self.stats, nd,
-                "direct" if is_direct_prepared(prep) else "sorted",
+                self.stats, nd, lookup_form(prep),
                 nd.distribution)
             dyn_keys: Tuple[int, ...] = ()
             dyn_val = jnp.zeros((0, 2), dtype=jnp.int64)
@@ -1547,10 +1546,10 @@ class _Executor:
                                              key_bounds=node.key_bounds)
                     if build is not None else None)
             if build is not None:
-                from ..ops.join import is_direct_prepared
+                from ..ops.join import lookup_form
                 _note_join_strategy(
                     self.stats, node,
-                    ("direct" if is_direct_prepared(prep) else "sorted")
+                    lookup_form(prep)
                     if node.build_unique else "expand",
                     node.distribution)
             # ONE build-side multiplicity readback replaces the per-probe-
@@ -1794,20 +1793,28 @@ class _Executor:
            summary, no extra sync);
         3. anything else gets the sorted composite search.
 
-        Direct tables answer a probe key in TWO gathers independent of
-        build size, where the sorted path pays O(log n) random gathers
-        per probe lane — the dominant join cost on this hardware."""
+        Direct tables answer a probe key in one gather (two for a run's
+        length) independent of build size, where the sorted path pays
+        O(log n) random gathers per probe lane — the dominant join cost
+        on this hardware. A build of at most COMPARE_ALL_LIMIT lanes
+        gets NO table, whatever the planner promised (the promise is
+        still checked): the probe compares each lane with every key of
+        the sorted layout and gathers nothing (ops/join._compare_all)."""
+        from ..ops.join import COMPARE_ALL_LIMIT, direct_keyed_plan
         keys = tuple(keys)
+        small = build.capacity <= COMPARE_ALL_LIMIT
         if key_bounds and bool_property(self.session, "join_dense_path",
                                         True):
-            from ..ops.join import direct_keyed_plan
             plan = direct_keyed_plan(tuple(key_bounds))
             if plan is not None:
                 los, sizes, K = plan
                 self.error_flags.append(key_bounds_violation_jit(
                     build, keys, tuple(key_bounds)))
-                return prepare_direct_keyed_jit(build, keys, los, sizes,
-                                                bucket_capacity(K))
+                if not small:
+                    return prepare_direct_keyed_jit(
+                        build, keys, los, sizes, bucket_capacity(K))
+        if small:
+            return prepare_build_jit(build, keys)
         if len(keys) == 1 and isinstance(build.columns[keys[0]].type,
                                          _DYN_TYPES):
             if summary is None:
@@ -2033,14 +2040,22 @@ class _Executor:
         build = self._drain(node.filtering)
         skeys = list(node.source_keys)
         fkeys = list(node.filtering_keys)
-        prep = (self._prepare_join_build(build, fkeys,
+        summary = None
+        if build is not None:
+            # the same cut _join_once gives its build, from the same
+            # readback: what a HAVING left of a 2^24-lane state (TPC-H
+            # Q18: ~60 keys) is probed as the bucket of its live rows
+            summary = self._build_summary(build, fkeys)
+            scap = bucket_capacity(max(int(summary[0]), 1))
+            if scap < build.capacity:
+                build = compact_jit(build, scap)
+        prep = (self._prepare_join_build(build, fkeys, summary=summary,
                                          key_bounds=node.key_bounds)
                 if build is not None else None)
         if build is not None:
-            from ..ops.join import is_direct_prepared
+            from ..ops.join import lookup_form
             _note_join_strategy(
-                self.stats, node,
-                "direct" if is_direct_prepared(prep) else "sorted",
+                self.stats, node, lookup_form(prep),
                 node.distribution)
         res_maxk = (self._build_multiplicity(prep)
                     if build is not None and node.residual is not None

@@ -115,11 +115,15 @@ def prepare_direct(build: Batch, key_cols: Sequence[int], lo0,
 
     TPU rationale: random gathers run at ~55M/s on v5e, and the sorted
     path's binary search spends O(log n) gathers per probe row; a direct
-    table answers [lo, hi) of a probe key's sorted match run in TWO
-    gathers, independent of build size.
+    table answers a probe key's first match in ONE gather (lo_table) and
+    the [lo, hi) of its sorted match run in two, independent of build
+    size.
 
     Returns (lo0, lo_table, cnt_table, s_ops, slive, perm): tables are
-    indexed by (key - lo0); empty slots hold (n, 0)."""
+    indexed by (key - lo0); empty slots hold (n, 0). INVARIANT (both
+    direct layouts; ``_point_lookup`` rests on it and reads lo_table
+    alone): dead rows go to the overflow slot, which is sliced off, so
+    for every slot inside the table ``lo < n`` iff ``cnt > 0``."""
     s_ops, slive, perm = build_sorted(build, key_cols)
     n = s_ops[0].shape[0]
     off = jnp.clip(s_ops[0] - lo0, 0, size - 1).astype(jnp.int32)
@@ -185,9 +189,11 @@ def prepare_direct_keyed(build: Batch, key_cols: Sequence[int],
                          size: int):
     """Multi-key direct-address table from PLANNER-PROMISED key bounds
     (``JoinNode.key_bounds``): composite mixed-radix slot per key tuple,
-    answered in TWO gathers per probe lane regardless of arity or build
-    size. Table capacity is host-known at PLAN time, so every batch of
-    every query sharing the plan reuses one executable shape.
+    answered in one gather per probe lane (two for a run's length)
+    regardless of arity or build size; ``prepare_direct``'s invariant
+    (``lo < n`` iff ``cnt > 0``) holds here too. Table capacity is
+    host-known at PLAN time, so every batch of every query sharing the
+    plan reuses one executable shape.
 
     Live build keys outside their promised bounds land in the overflow
     slot (they can never match) — the executor independently raises
@@ -251,10 +257,86 @@ def direct_slot_codes(q_ops, prepared):
     return jnp.clip(code, 0, size - 1).astype(jnp.int32), inr
 
 
+#: largest capacity of a sorted build that a probe answers by comparing
+#: every lane with every build key, no gather at all (a static shape:
+#: ``_point_lookup`` and ``_range_lookup`` read it here, and
+#: ``exec/local._prepare_join_build`` hands builds at or under it the
+#: sorted layout instead of filling a direct table for them). The
+#: largest power of two MEASURED at which the membership is at least
+#: twice as fast as ONE gather into a direct table, one key and a
+#: two-key tuple alike (`tools/join_probe.py`, TPU v5 lite, PR 34; ns a
+#: probe lane at 2^20 and 2^21 lanes, the same within 2 %): one gather
+#: 7.2 (two, the parent's: 21.1 to 22.0; table of 2^17 or 2^24 slots
+#: alike); compare-all, the hit alone, one key / two keys: n = 128
+#: 0.22 to 0.25 / 0.38 to 0.39, 512 0.80 to 0.82 / 1.40 to 1.42, 2048
+#: 3.15 to 3.17 / 5.51 to 5.52 (two keys: not twice as fast), 8192 12.5
+#: / 21.9; with the position too (a lookup join's): 128 0.48 / 0.88 to
+#: 1.20, 512 1.76 to 1.85 / 3.36 to 4.60, 2048 6.9 to 7.3 / 13.3 to
+#: 18.2 (slower than the gather). The binary search it replaces there:
+#: 144 to 448 ns a lane
+COMPARE_ALL_LIMIT = 512
+
+#: build keys compared in one pass of ``_compare_all``'s loop: a
+#: [chunk, lanes] compare reduces over its MAJOR axis inside one fusion
+#: (lanes stay on the vector lanes), and a longer build loops over
+#: chunks so that no [n, lanes] buffer exists whatever XLA fuses
+_COMPARE_CHUNK = 128
+
+
+def lookup_form(prepared) -> str:
+    """How a probe lane finds its first match in ``prepared``: ``direct``
+    (one gather into a table), ``compare`` (a sorted build at or under
+    COMPARE_ALL_LIMIT: no gather) or ``sorted`` (binary search) — the
+    label the executors report as the join's strategy."""
+    if is_direct_prepared(prepared):
+        return "direct"
+    n = prepared[0][0].shape[0]
+    return "compare" if n <= COMPARE_ALL_LIMIT else "sorted"
+
+
+def _compare_all(s_ops, slive, q_ops):
+    """(lo, cnt) per probe lane over a SMALL sorted build, by comparing
+    each lane with every build key: lo counts the build tuples
+    lexicographically below the lane's (the keys are sorted and dead
+    rows hold the max sentinel, so that is the first match's position,
+    what ``_lex_searchsorted(side="left")`` finds), cnt the LIVE tuples
+    equal to it (``slive`` keeps a probe key equal to the sentinel from
+    matching dead rows). Elementwise compares and a reduction over the
+    build axis: no gather, no memory access beyond the operands."""
+    n = s_ops[0].shape[0]
+    shape = q_ops[0].shape
+
+    def chunk(s_chunk, live_chunk):
+        less = jnp.zeros((live_chunk.shape[0],) + shape, dtype=bool)
+        eq = jnp.ones_like(less)
+        for s, q in zip(s_chunk, q_ops):
+            sv = s[:, None]
+            less = less | (eq & (sv < q[None, :]))
+            eq = eq & (sv == q[None, :])
+        return (jnp.sum(less, axis=0, dtype=jnp.int32),
+                jnp.sum(eq & live_chunk[:, None], axis=0, dtype=jnp.int32))
+
+    if n <= _COMPARE_CHUNK:
+        return chunk(s_ops, slive)
+    rows = -(-n // _COMPARE_CHUNK)
+    pad = rows * _COMPARE_CHUNK - n     # 0 for the buckets' powers of two
+    # padding counts nowhere: the max sentinel is below no key, and dead
+    s2 = [jnp.pad(s, (0, pad), constant_values=_key_sentinel(s.dtype))
+          .reshape(rows, _COMPARE_CHUNK) for s in s_ops]
+    live2 = jnp.pad(slive, (0, pad)).reshape(rows, _COMPARE_CHUNK)
+
+    def body(i, acc):
+        lo, cnt = chunk([s[i] for s in s2], live2[i])
+        return acc[0] + lo, acc[1] + cnt
+    zero = jnp.zeros(shape, dtype=jnp.int32)
+    return jax.lax.fori_loop(0, rows, body, (zero, zero))
+
+
 def _range_lookup(q_ops, prepared):
     """Per-probe-lane [lo, hi) over the SORTED build — via the direct
-    table (2 gathers, single-key or composite) or composite binary
-    search (2 log n gathers)."""
+    table (2 gathers, single-key or composite), by comparing with every
+    key of a small build (no gather) or composite binary search
+    (2 log n gathers)."""
     if is_direct_prepared(prepared):
         s_ops = _split_prepared(prepared)[0]
         lo_table, cnt_table = ((prepared[1], prepared[2])
@@ -266,20 +348,31 @@ def _range_lookup(q_ops, prepared):
         cnt = jnp.where(inr, jnp.take(cnt_table, idx, axis=0), 0)
         return lo.astype(jnp.int32), (lo + cnt).astype(jnp.int32)
     s_ops, slive, _ = prepared
+    if lookup_form(prepared) == "compare":
+        lo, cnt = _compare_all(s_ops, slive, q_ops)
+        return lo, lo + cnt
     lo = _lex_searchsorted(s_ops, q_ops, side="left")
     hi = _lex_searchsorted(s_ops, q_ops, side="right")
     return lo, hi
 
 
 def _point_lookup(q_ops, prepared):
-    """(pos, hit) of each probe lane's first match in the sorted build."""
+    """(pos, hit) of each probe lane's first match in the sorted build:
+    ONE gather into a direct table (``lo < n`` says the slot is taken:
+    ``prepare_direct``'s invariant, so cnt_table is not read), none for
+    a small sorted build, a binary search otherwise."""
+    s_ops, slive, _ = _split_prepared(prepared)
+    n = s_ops[0].shape[0]
     if is_direct_prepared(prepared):
-        lo, hi = _range_lookup(q_ops, prepared)
-        n = _split_prepared(prepared)[0][0].shape[0]
-        return jnp.clip(lo, 0, n - 1), hi > lo
-    s_ops, slive, _ = prepared
+        lo_table = prepared[1] if _is_direct(prepared) else prepared[2]
+        idx, inr = direct_slot_codes(q_ops, prepared)
+        lo = jnp.take(lo_table, idx, axis=0)
+        return jnp.clip(lo, 0, n - 1), inr & (lo < n)
+    if lookup_form(prepared) == "compare":
+        lo, cnt = _compare_all(s_ops, slive, q_ops)
+        return jnp.clip(lo, 0, n - 1), cnt > 0
     pos = _lex_searchsorted(s_ops, q_ops, side="left")
-    pos = jnp.minimum(pos, s_ops[0].shape[0] - 1)
+    pos = jnp.minimum(pos, n - 1)
     hit = _tuple_eq(s_ops, q_ops, pos) & jnp.take(slive, pos, axis=0)
     return pos, hit
 

@@ -114,11 +114,209 @@ def test_direct_keyed_plan_gates():
 
 
 # ---------------------------------------------------------------------------
+# the point lookup's three forms against the binary search
+# ---------------------------------------------------------------------------
+
+I64_MAX = int(np.iinfo(np.int64).max)
+
+
+def _case(name):
+    """(build, probe, key columns, planner-style bounds or None,
+    unique build keys) of one parity case; build column -1 is a payload,
+    probe column -1 a row id."""
+    rng = np.random.default_rng(34)
+    L = J.COMPARE_ALL_LIMIT
+    if name == "ints":
+        # NULL and negative keys, keys ON the bounds, probe keys outside
+        bk = [-5, 0, 7, 3, -2, 4, 6]
+        build = _with_nulls(Batch.from_pydict({
+            "k": (T.BIGINT, bk), "v": (T.BIGINT, list(range(len(bk))))}),
+            0, [3])
+        probe = _with_nulls(Batch.from_pydict({
+            "p": (T.BIGINT, [-5, 7, -6, 8, 0, 1, 3, -2, 100, -100, 4, 4]),
+            "x": (T.BIGINT, list(range(12)))}), 0, [5])
+        return build, probe, [0], ((-5, 7),), True
+    if name == "int64_max":
+        # the dead rows' sentinel IS int64-max: a live max key matches,
+        # and where the build has none a max probe key matches nothing
+        bk = [I64_MAX - 3, I64_MAX, I64_MAX - 1, I64_MAX - 7]
+        build = Batch.from_pydict({
+            "k": (T.BIGINT, bk), "v": (T.BIGINT, [1, 2, 3, 4])},
+            capacity=16)
+        probe = Batch.from_pydict({
+            "p": (T.BIGINT, [I64_MAX, I64_MAX - 1, I64_MAX - 2,
+                             I64_MAX - 7, I64_MAX - 8, None]),
+            "x": (T.BIGINT, list(range(6)))})
+        return build, probe, [0], ((I64_MAX - 7, I64_MAX),), True
+    if name == "no_max_in_build":
+        build = Batch.from_pydict({
+            "k": (T.BIGINT, [1, 2, 3]), "v": (T.BIGINT, [1, 2, 3])},
+            capacity=8)
+        probe = Batch.from_pydict({
+            "p": (T.BIGINT, [I64_MAX, 3, -I64_MAX - 1, 0]),
+            "x": (T.BIGINT, list(range(4)))})
+        return build, probe, [0], None, True
+    if name == "double":
+        # -0.0 joins +0.0; the u64 total-order operands
+        build = Batch.from_pydict({
+            "k": (T.DOUBLE, [-0.0, 1.5, -2.25, 1e300, None]),
+            "v": (T.BIGINT, [1, 2, 3, 4, 5])})
+        probe = Batch.from_pydict({
+            "p": (T.DOUBLE, [0.0, -0.0, 1.5, 2.25, -2.25, 1e300, -1e300,
+                             None]),
+            "x": (T.BIGINT, list(range(8)))})
+        return build, probe, [0], None, True
+    if name == "tuple":
+        pairs = sorted({(int(a), int(b)) for a, b in zip(
+            rng.integers(-20, 20, 300), rng.integers(5, 12, 300))})
+        build = _with_nulls(_build([a for a, _ in pairs],
+                                   [b for _, b in pairs],
+                                   list(range(len(pairs)))), 1, [3, 50])
+        probe = _with_nulls(Batch.from_pydict({
+            "p1": (T.BIGINT, rng.integers(-25, 25, 500).tolist()),
+            "p2": (T.BIGINT, rng.integers(3, 14, 500).tolist()),
+            "x": (T.BIGINT, list(range(500)))}), 1, [0, 7, 100])
+        return build, probe, [0, 1], ((-20, 19), (5, 11)), True
+    if name == "duplicates":
+        bk = rng.integers(-8, 8, 200).tolist()
+        build = _with_nulls(Batch.from_pydict({
+            "k": (T.BIGINT, bk), "v": (T.BIGINT, list(range(200)))}),
+            0, [1, 2])
+        probe = _with_nulls(Batch.from_pydict({
+            "p": (T.BIGINT, rng.integers(-12, 12, 300).tolist()),
+            "x": (T.BIGINT, list(range(300)))}), 0, [9])
+        return build, probe, [0], ((-8, 7),), False
+    # a build of exactly the limit's lanes (the last compare-all shape)
+    # and of twice as many (the first that searches), a few lanes dead
+    cap = L if name == "at_limit" else 2 * L
+    bk = rng.permutation(4 * cap)[:cap - 5] - cap
+    build = Batch.from_pydict({
+        "k": (T.BIGINT, bk.tolist()),
+        "v": (T.BIGINT, list(range(cap - 5)))}, capacity=cap)
+    probe = Batch.from_pydict({
+        "p": (T.BIGINT, (rng.integers(0, 5 * cap, 700) - cap - 7).tolist()),
+        "x": (T.BIGINT, list(range(700)))})
+    return build, probe, [0], ((-cap, 3 * cap - 1),), True
+
+
+def _prepared(form, build, keys, bounds):
+    if form == "direct":
+        lo, hi = bounds[0]
+        return J.prepare_direct(build, keys, lo, hi - lo + 1)
+    if form == "keyed":
+        los, sizes, K = J.direct_keyed_plan(bounds)
+        return J.prepare_direct_keyed(build, keys, los, sizes, K)
+    return J.prepare_build(build, keys)
+
+
+def _answers(probe, build, keys, prepared, unique, empty):
+    """Everything that goes through ``_point_lookup``, as plain values."""
+    out = {}
+    pay = [len(build.columns) - 1]
+    if unique:
+        for jt in ("inner", "left"):
+            out[jt] = _rows(J.lookup_join(probe, build, keys, keys, pay,
+                                          ["v"], jt, prepared=prepared))
+        survived = jnp.arange(probe.capacity) % 3 != 0
+        out["visited"] = np.asarray(J.unique_match_build_mask(
+            probe, build, keys, keys, survived,
+            prepared=prepared)).tolist()
+    for tag, negated, null_aware in (("in", False, True),
+                                     ("not_in", True, True),
+                                     ("not_exists", True, False)):
+        out[tag] = np.asarray(J.semi_join_mask(
+            probe, build, keys, keys, negated, null_aware,
+            prepared=prepared)).tolist()
+    # NOT IN against an EMPTY build keeps every probe row, NULL keys too
+    out["not_in_empty"] = np.asarray(J.semi_join_mask(
+        probe, empty[0], keys, keys, True, True,
+        prepared=empty[1])).tolist()
+    return out
+
+
+@pytest.mark.parametrize("case,form", [
+    ("ints", "direct"), ("ints", "keyed"), ("ints", "compare"),
+    ("int64_max", "direct"), ("int64_max", "keyed"),
+    ("int64_max", "compare"), ("no_max_in_build", "compare"),
+    ("double", "compare"),
+    ("tuple", "keyed"), ("tuple", "compare"),
+    ("duplicates", "direct"), ("duplicates", "keyed"),
+    ("duplicates", "compare"),
+    ("at_limit", "direct"), ("at_limit", "keyed"),
+    ("at_limit", "compare"),
+    ("twice_limit", "direct"), ("twice_limit", "keyed"),
+    ("twice_limit", "sorted"),
+])
+def test_point_lookup_forms_match_the_binary_search(monkeypatch, case,
+                                                    form):
+    """ISSUE 34: a probe lane learns (pos, hit) from ONE gather into a
+    direct table (either layout) or, of a sorted build at or under
+    COMPARE_ALL_LIMIT, from no gather at all; every caller of
+    ``_point_lookup`` answers as it does over the binary search."""
+    build, probe, keys, bounds, unique = _case(case)
+    dead = Batch(build.schema, build.columns,
+                 jnp.zeros_like(build.row_mask))
+    prepared = _prepared(form, build, keys, bounds)
+    assert J.lookup_form(prepared) == {"keyed": "direct"}.get(form, form)
+    got = _answers(probe, build, keys, prepared, unique,
+                   (dead, _prepared(form, dead, keys, bounds)))
+    with monkeypatch.context() as m:
+        m.setattr(J, "COMPARE_ALL_LIMIT", 0)     # every lane searches
+        ref = J.prepare_build(build, keys)
+        assert J.lookup_form(ref) == "sorted"
+        want = _answers(probe, build, keys, ref, unique,
+                        (dead, J.prepare_build(dead, keys)))
+    assert got == want
+    assert any(want["in"]) and not all(want["in"])
+    assert want["not_in_empty"] == np.asarray(probe.row_mask).tolist()
+    if case == "ints":
+        # a NULL in the build: NOT IN passes nothing
+        assert not any(want["not_in"]) and any(want["not_exists"])
+
+
+@pytest.mark.parametrize("layout", ["direct", "keyed"])
+def test_direct_tables_say_taken_in_lo_alone(layout):
+    """What the one-gather lookup rests on: inside the table a slot's
+    ``lo < n`` exactly where its ``cnt > 0``, with dead rows, NULL keys,
+    duplicate keys and (keyed) live rows OUTSIDE the promised bounds,
+    which go to the overflow slot with the dead."""
+    rng = np.random.default_rng(5)
+    build = _with_nulls(_build(rng.integers(-6, 30, 200).tolist(),
+                               rng.integers(0, 9, 200).tolist(),
+                               list(range(200))), 0, [4, 5, 6])
+    mask = np.ones(build.capacity, dtype=bool)
+    mask[[0, 17, 199]] = False
+    build = Batch(build.schema, build.columns,
+                  build.row_mask & jnp.asarray(mask))
+    if layout == "direct":
+        prep = J.prepare_direct(build, [0], -6, 64)
+        lo_t, cnt_t = prep[1], prep[2]
+    else:
+        # k1's promise (0..20) is broken by live rows on both sides
+        los, sizes, K = J.direct_keyed_plan(((0, 20), (0, 8)))
+        prep = J.prepare_direct_keyed(build, [0, 1], los, sizes, K)
+        lo_t, cnt_t = prep[2], prep[3]
+    n = build.capacity
+    lo_t, cnt_t = np.asarray(lo_t), np.asarray(cnt_t)
+    assert ((lo_t < n) == (cnt_t > 0)).all()
+    assert (lo_t[cnt_t == 0] == n).all()
+    assert 0 < (cnt_t > 0).sum() < lo_t.shape[0]
+    assert cnt_t.max() > 1                        # duplicates are there
+
+
+# ---------------------------------------------------------------------------
 # Pallas probe kernel parity (interpret mode on the CPU mesh)
 # ---------------------------------------------------------------------------
 
 @pytest.fixture
-def force_pallas(monkeypatch):
+def no_compare_all(monkeypatch):
+    """Every build gets the layout it got before the compare-all form:
+    the kernel's tests are about DIRECT tables, and theirs are tiny."""
+    monkeypatch.setattr(J, "COMPARE_ALL_LIMIT", 0)
+
+
+@pytest.fixture
+def force_pallas(monkeypatch, no_compare_all):
     monkeypatch.setattr(PJ, "FORCE_PALLAS_PROBE", True)
 
 
@@ -221,7 +419,8 @@ def test_pallas_probe_off_by_default():
 
 
 @pytest.mark.parametrize("fused", [False, True])
-def test_pallas_kernel_failure_fails_query(monkeypatch, fused):
+def test_pallas_kernel_failure_fails_query(monkeypatch, no_compare_all,
+                                           fused):
     """With join_pallas_probe=true a kernel that fails to lower fails
     the query with the compiler's message — nothing re-runs it on
     another implementation. With the default (off) the same query
@@ -362,11 +561,12 @@ def test_semi_partitioned_row_parity(tpch_runner):
 # bounds that lie -> STATS_BOUND_VIOLATION through the error channel
 # ---------------------------------------------------------------------------
 
-def test_join_bound_violation_fails_query():
+def _lying_dim():
+    """(runner, connector, stats) over fact(fk, v) and a 3-key dim(k,
+    name) whose promised bounds (1..5) its key 99 breaks."""
     from presto_tpu.connectors.spi import (CatalogManager, ColumnStats,
                                            TableStats)
     from presto_tpu.connectors.memory import MemoryConnector
-    from presto_tpu.errors import QueryError
     from presto_tpu.exec.runner import LocalRunner
     conn = MemoryConnector()
     catalogs = CatalogManager()
@@ -376,7 +576,6 @@ def test_join_bound_violation_fails_query():
               "(values (1, 'a'), (2, 'b'), (99, 'z')) t(k, name)")
     r.execute("create table memory.default.fact as select * from "
               "(values (1, 10), (2, 20), (99, 30)) t(fk, v)")
-
     lying = {
         "dim": TableStats(
             row_count=3.0,
@@ -384,9 +583,14 @@ def test_join_bound_violation_fails_query():
             primary_key=("k",)),
         "fact": TableStats(row_count=3.0, columns={}),
     }
-    meta = conn.metadata
-    monkeypatch_stats = lambda self, t: lying.get(
+    return r, conn, lambda self, t: lying.get(
         t.table, TableStats(row_count=3.0))
+
+
+def test_join_bound_violation_fails_query():
+    from presto_tpu.errors import QueryError
+    r, conn, monkeypatch_stats = _lying_dim()
+    meta = conn.metadata
     orig = type(meta).table_stats
     type(meta).table_stats = monkeypatch_stats
     try:
@@ -420,4 +624,39 @@ def test_explain_analyze_shows_strategy(tpch_runner):
         "explain analyze select c_name, n_name from customer "
         "join nation on c_nationkey = n_nationkey").rows
     text = "\n".join(r[0] for r in ex)
-    assert "[strategy direct/replicated]" in text
+    # nation is 25 keys: no table is filled for it, no lane gathers
+    assert "[strategy compare/replicated]" in text
+
+
+def test_small_build_is_compared_not_gathered(tpch_runner, monkeypatch):
+    """ISSUE 34: a join (and a semi join) whose build is under
+    COMPARE_ALL_LIMIT fills no table although the planner promised
+    bounds: strategy ``compare``, the rows the ``direct`` strategy
+    returns, and a promise that lies still fails the query."""
+    sql = ("select c_name, n_name from customer join nation "
+           "on c_nationkey = n_nationkey where c_custkey in "
+           "(select o_custkey from orders where o_totalprice > 495000)")
+    name = "join_strategy_selected_total.%s.replicated"
+    c0, d0 = _metric(name % "compare"), _metric(name % "direct")
+    text = "\n".join(r[0] for r in tpch_runner.execute(
+        "explain analyze " + sql).rows)
+    assert text.count("[strategy compare/replicated]") == 2
+    rows = tpch_runner.execute(sql).rows
+    assert rows and _metric(name % "compare") == c0 + 4
+    assert _metric(name % "direct") == d0
+    with monkeypatch.context() as m:
+        m.setattr(J, "COMPARE_ALL_LIMIT", 0)
+        direct = tpch_runner.execute(sql).rows
+    assert _metric(name % "direct") == d0 + 2
+    assert sorted(rows) == sorted(direct)
+    # lying bounds over a 3-key build (test_join_bound_violation_fails_
+    # query's tables): compared, and refused all the same
+    from presto_tpu.errors import QueryError
+    r, conn, lying_stats = _lying_dim()
+    monkeypatch.setattr(type(conn.metadata), "table_stats", lying_stats)
+    c1 = _metric(name % "compare")
+    with pytest.raises(QueryError) as ei:
+        r.execute("select v, name from memory.default.fact "
+                  "join memory.default.dim on fk = k")
+    assert ei.value.name == "STATS_BOUND_VIOLATION"
+    assert _metric(name % "compare") == c1 + 1

@@ -181,6 +181,55 @@ def test_state_merge_compiles_for_v5e_without_a_sort_or_a_gather(one_chip):
     assert c.memory_analysis() is not None
 
 
+def _described(tree, lanes, one_chip):
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(
+            (lanes,) + leaf.shape[1:], leaf.dtype, sharding=one_chip),
+        tree)
+
+
+@pytest.mark.parametrize("form,lanes,gathers", [
+    ("compare", 1 << 21, 0), ("direct", N_BATCH, 1)])
+def test_membership_probe_compiles_for_v5e_with_one_gather_or_none(
+        one_chip, record_property, form, lanes, gathers):
+    """``jit_op_semi_join_mask`` as TPC-H Q18 runs it (ISSUE 34): 2^21
+    lineitem lanes against the 128-lane bucket that holds what the semi
+    join left of orders compile to NO gather and no [lanes, keys]
+    buffer (the compares reduce over the build axis inside their
+    fusion); 2^20 lanes against a 2^24-slot direct table (the parent's
+    layout for the same 128 lanes) to exactly ONE, where the parent's
+    read lo_table and cnt_table."""
+    import re
+    import time
+    from presto_tpu import types as T
+    from presto_tpu.batch import Batch
+    from presto_tpu.ops import join as J
+    from presto_tpu.ops.jitcache import _semi
+    small = Batch.from_pydict({"k": (T.BIGINT, [3, 1, 2])})
+    build = _described(small, 128, one_chip)
+    if form == "compare":
+        prep = _described(J.prepare_build(small, [0]), 128, one_chip)
+    else:
+        los, sizes, lo_t, cnt_t, *rest = J.prepare_direct_keyed(
+            small, [0], (0,), (4,), 4)
+        prep = tuple(jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=one_chip)
+                     for x in (los, sizes)) + tuple(
+            _described(x, 1 << 24, one_chip) for x in (lo_t, cnt_t)
+        ) + tuple(_described(rest, 128, one_chip))
+    assert J.lookup_form(prep) == form
+    t = time.perf_counter()
+    c = _semi((0,), (0,), False, False).fn.lower(
+        _described(small, lanes, one_chip), build, prep).compile()
+    record_property("compile_s", round(time.perf_counter() - t, 2))
+    text = c.as_text()
+    assert "jit_op_semi_join_mask" in text
+    assert len(re.findall(r"\sgather\(", text)) == gathers
+    assert not re.findall(r"\s(scatter|sort)\(", text)
+    # nothing of lanes x keys is held: a few words a lane at most
+    assert c.memory_analysis().temp_size_in_bytes <= 16 * lanes
+
+
 def _probe_shapes(one_chip):
     i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,  # noqa: E731
                                          sharding=one_chip)
