@@ -46,6 +46,12 @@ class TableStats:
     #: (reference spi/statistics/TableStatistics.java has no PK notion;
     #: Presto infers uniqueness from distinct counts, we declare it)
     primary_key: Tuple[str, ...] = ()
+    #: columns by which the table's rows arrive in ascending order,
+    #: batch by batch, where the connector states it: a group-by over
+    #: them groups a batch as it stands and compiles no sort
+    #: (optimizer._attach_group_bounds, AggregationNode.ordered_input);
+    #: a batch out of order fails the query, as any statistic that lies
+    clustered_by: Tuple[str, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)

@@ -535,12 +535,22 @@ class TpchPageSource(PageSource):
         schema = tpch_schema(table)
         if table == "lineitem":
             o_start, o_end = self.split.info
-            # orders per chunk such that ~rows_per_batch lines (avg 4/order)
+            a = o_start
+            # whole orders: at most a quarter of rows_per_batch of them
+            # (4.0 lines an order on average: a group-by by order key
+            # then holds a chunk's groups in a quarter of the lanes,
+            # chunk after chunk the same bucket), and no more than fit
+            # rows_per_batch LINES: the quarter alone left every other
+            # chunk a few lines over 2^20, staged at 2^21 lanes, half of
+            # them dead (91M lanes for SF10's 60M lines)
             step = max(1, self.rows_per_batch // 4)
-            for a in range(o_start, o_end, step):
-                b = min(a + step, o_end)
-                okeys = np.arange(a, b, dtype=np.int64)
+            while a < o_end:
+                okeys = np.arange(a, min(a + step, o_end), dtype=np.int64)
                 counts = _lines_per_order(okeys)
+                fit = max(1, int(np.searchsorted(
+                    np.cumsum(counts), self.rows_per_batch, side="right")))
+                okeys, counts = okeys[:fit], counts[:fit]
+                a += fit
                 rep_ok = np.repeat(okeys, counts)
                 ln = np.arange(len(rep_ok)) - np.repeat(
                     np.cumsum(counts) - counts, counts)
@@ -565,9 +575,12 @@ def tpch_schema(table: str) -> Schema:
 
 
 class _Metadata(ConnectorMetadata):
-    def __init__(self, sf: float, tables: Sequence[str]):
+    def __init__(self, sf: float, tables: Sequence[str],
+                 clustered_by: Optional[Dict[str, Sequence[str]]] = None):
         self.sf = sf
         self.tables = tuple(tables)
+        self.clustered_by = {t: tuple(c) for t, c in
+                             (clustered_by or {}).items()}
 
     def list_tables(self, schema: Optional[str] = None) -> List[str]:
         return list(self.tables)
@@ -640,7 +653,8 @@ class _Metadata(ConnectorMetadata):
             if pk not in cols:
                 cols[pk] = ColumnStats(distinct_count=n if len(self._PRIMARY_KEYS[t]) == 1 else None)
         return TableStats(row_count=n, columns=cols,
-                          primary_key=self._PRIMARY_KEYS.get(t, ()))
+                          primary_key=self._PRIMARY_KEYS.get(t, ()),
+                          clustered_by=self.clustered_by.get(t, ()))
 
 
 class _SplitManager(ConnectorSplitManager):
@@ -669,14 +683,28 @@ class TpchConnector(Connector):
     applies_pushdown = False    # page_source drops it
 
     def __init__(self, sf: float = 0.01, tables: Sequence[str] = TABLES,
-                 distinct_text: Sequence[str] = ()):
+                 distinct_text: Sequence[str] = (),
+                 clustered_by: Optional[Dict[str, Sequence[str]]] = None):
         """``tables``: the tables this catalog holds (a deployment of
         one fact table lists it alone); every TPC-H table by default.
         ``distinct_text``: text columns of which the deployment states
         that every row holds another value (``c_name`` is
         ``Customer#<c_custkey>``): a batch of such a column takes its
         rows' positions for codes and its strings for vocabulary, and
-        no Python loop looks each string up for a repeat."""
+        no Python loop looks each string up for a repeat.
+        ``clustered_by``: {table: columns} of which the deployment
+        states that the table's rows are generated in those columns'
+        order (every table here is generated in its primary key's
+        order, lineitem by ``l_orderkey`` then ``l_linenumber``): the
+        statistics then say so (``TableStats.clustered_by``), a
+        group-by over them compiles no sort, and a batch out of order
+        fails the query."""
+        for t, cols in (clustered_by or {}).items():
+            pk = _Metadata._PRIMARY_KEYS.get(t, ()) if t in tables else ()
+            if not cols or tuple(cols) != pk[:len(cols)]:
+                raise ValueError(
+                    f"tpch {t!r} is not generated clustered by "
+                    f"{list(cols)} (its order is {list(pk)})")
         unknown = sorted(set(tables) - set(TABLES))
         if unknown:
             raise ValueError(f"unknown tpch tables {unknown}")
@@ -686,7 +714,7 @@ class TpchConnector(Connector):
             raise ValueError(f"no text columns {unknown} in {list(tables)}")
         self.distinct_text = tuple(distinct_text)
         self.sf = sf
-        self._metadata = _Metadata(sf, tables)
+        self._metadata = _Metadata(sf, tables, clustered_by)
         self._splits = _SplitManager(sf)
         self._gen = _Gen(sf)
 

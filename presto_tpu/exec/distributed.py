@@ -1684,6 +1684,7 @@ class DistributedExecutor(_Executor):
         # replicated filtering side; expansion factor from ONE build-side
         # multiplicity readback (skewed builds per-chunk, as in the join)
         from .local import mark_exists_mask
+        from ..expr.params import collect_params, current_args
         from ..ops.join import build_sorted, max_multiplicity
         mult_fn = self._smap(
             lambda f: max_multiplicity(
@@ -1712,8 +1713,12 @@ class DistributedExecutor(_Executor):
             fn = fns.get(maxk)
             if fn is None:
                 def local_mark(p: Batch, f: Batch, _k=maxk) -> Batch:
-                    mask = mark_exists_mask(p, f, skeys, fkeys,
-                                            node.residual, neg, _k)
+                    # a plan template's parameters as the values bound
+                    # now: constants of this shard program
+                    slots = collect_params([node.residual])
+                    mask, _ = mark_exists_mask(
+                        p, f, skeys, fkeys, node.residual, neg, _k,
+                        pargs=current_args(slots) if slots else ())
                     return Batch(p.schema, p.columns, mask)
                 fn = fns[maxk] = self._smap(local_mark, 2,
                                             replicated_in=(1,), stage="semi")

@@ -15,6 +15,7 @@ ExchangeClient-fed init semantics without a network hop.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
@@ -32,8 +33,8 @@ from ..ops.jitcache import global_aggregate_jit as global_aggregate, grouped_agg
 from ..ops.jitcache import (
     build_key_ranks_jit, build_match_mask_jit, expand_join_jit,
     key_bounds_violation_jit, lookup_join_jit, lookup_join_pallas_jit,
-    match_count_max_jit, prepare_build_jit, prepare_direct_jit,
-    prepare_direct_keyed_jit, semi_join_mask_jit,
+    match_count_max_jit, pack_sorted_payload_jit, prepare_build_jit,
+    prepare_direct_jit, prepare_direct_keyed_jit, semi_join_mask_jit,
 )
 from ..obs.metrics import REGISTRY
 from ..obs.trace import TRACER, device_sync
@@ -63,16 +64,31 @@ _FUSED_SOURCE_LANES = REGISTRY.counter("fused_source_lanes_total")
 _FUSED_TAIL_LANES = REGISTRY.counter("fused_tail_lanes_total")
 
 
-def _note_join_strategy(stats, node, strategy: str, dist: str) -> None:
+#: residual semi/anti joins executed, by the form that decided the
+#: residual, and the lanes ``expand_join`` produced for the m:n form
+#: (none for the keyed one)
+_SEMI_RESIDUAL = {form: REGISTRY.counter(f"semi_join_residual_total.{form}")
+                  for form in ("keyed", "expand")}
+_SEMI_EXPANDED_LANES = REGISTRY.counter("semi_join_expanded_lanes_total")
+
+
+def _note_join_strategy(stats, node, strategy: str, dist: str,
+                        residual: Optional[str] = None) -> None:
     """Join-dispatch observability: one count per executed join/semi
     operator, labeled strategy (direct / compare / sorted / expand) x
     distribution — the trace-level signal the strategy-selection tests
-    assert on, next to EXPLAIN ANALYZE's per-row [strategy ...] suffix."""
+    assert on, next to EXPLAIN ANALYZE's per-row [strategy ...] suffix.
+    ``residual``: how a semi join's residual is decided (`keyed` on the
+    one row of a unique build, `expand` over the m:n matches), counted
+    in ``semi_join_residual_total.<form>`` and printed in the suffix."""
     REGISTRY.counter(
         f"join_strategy_selected_total.{strategy}.{dist}").inc()
+    if residual is not None:
+        _SEMI_RESIDUAL[residual].inc()
     if stats is not None and hasattr(stats, "record_join_strategy"):
-        stats.record_join_strategy(node, strategy, dist)
-from ..ops.join import expand_join, semi_join_mask
+        stats.record_join_strategy(node, strategy, dist, residual)
+
+from ..ops.join import expand_join
 from ..ops.sort import SortKey, limit as limit_kernel, sort_batch, top_n
 from ..planner.plan import (
     AggregationNode, DistinctNode, FilterNode, GroupIdNode, JoinNode,
@@ -273,36 +289,99 @@ def _apply_dynamic_bounds(probe: Batch,
     return Batch(probe.schema, probe.columns, keep)
 
 
+def _residual_true(residual, cols, live, pargs):
+    """(lanes of ``live`` where ``residual`` over ``cols`` is TRUE, the
+    row-error scalar over ``live`` or None); traceable."""
+    from ..expr.compiler import _err_scalar, _param_trace, eval_expr
+    from ..expr.functions import Val
+    with _param_trace([residual], pargs):
+        p = eval_expr(residual, [Val(c.data, c.validity, c.type,
+                                     c.dictionary) for c in cols])
+    return live & p.valid & p.data, _err_scalar([p.err], live)
+
+
+def _residual_payload(residual, n_src: int):
+    """(build columns the residual reads, the residual over the source's
+    columns followed by exactly those)."""
+    from ..expr.rewrite import referenced_inputs, remap_inputs
+    refs = referenced_inputs(residual)
+    payload = sorted(i - n_src for i in refs if i >= n_src)
+    remap = {i: i if i < n_src else n_src + payload.index(i - n_src)
+             for i in refs}
+    return payload, remap_inputs(residual, remap)
+
+
 def mark_exists_mask(probe: Batch, build: Batch, probe_keys, build_keys,
-                     residual, negated: bool, max_matches: int, ex=None):
-    """Correlated-EXISTS mark: probe row passes iff ANY build row with
+                     residual, negated: bool, max_matches: int,
+                     prepared=None, pargs=()):
+    """Correlated-EXISTS mark over a build that may hold a key many
+    times (the `expand` form): a probe row passes iff ANY build row with
     equal keys satisfies the residual predicate (over probe fields +
     build fields). The decorrelated mark-join shape of reference
-    TransformExistsApplyToCorrelatedJoin.java: expand the m:n matches,
-    filter by the residual, then test membership of each probe row id in
-    the surviving matches."""
-    from ..expr.rewrite import referenced_inputs, remap_inputs
-    cap = probe.capacity
-    rid = Column(T.BIGINT, jnp.arange(cap, dtype=jnp.int64),
-                 probe.row_mask, None)
-    schema2 = Schema(list(zip(probe.schema.names, probe.schema.types))
-                     + [("$rid", T.BIGINT)])
-    probe2 = Batch(schema2, list(probe.columns) + [rid], probe.row_mask)
-    payload = list(range(len(build.columns)))
-    pnames = [f"$f{i}" for i in payload]
-    expanded = expand_join(probe2, build, probe_keys, build_keys,
-                           payload, pnames, "inner", max_matches)
-    # expanded layout: probe cols, $rid, build cols — shift build refs by 1
-    n_src = len(probe.columns)
-    shift = {i: (i if i < n_src else i + 1)
-             for i in referenced_inputs(residual)}
-    filt = compile_filter(remap_inputs(residual, shift), expanded.schema,
-                          errors=True)
-    kept, err = filt(expanded)
-    if err is not None and ex is not None:
-        ex.error_flags.append(err)
-    return semi_join_mask(probe2, kept, [n_src], [n_src],
-                          negated=negated, null_aware=False)
+    TransformExistsApplyToCorrelatedJoin.java: expand the m:n matches
+    (slot k of every probe lane its k-th match, the build columns the
+    residual reads gathered for each), evaluate the residual, and mark
+    the lanes any of whose slots passed. (mask, row-error scalar or
+    None); pure and traceable: the executor launches it as one named
+    program (``_residual_program``), the mesh inside its own."""
+    k = max(1, max_matches)
+    payload, residual = _residual_payload(residual, len(probe.columns))
+    expanded = expand_join(probe, build, probe_keys, build_keys, payload,
+                           [f"$f{i}" for i in payload], "inner", k,
+                           prepared=prepared)
+    found, err = _residual_true(residual, expanded.columns,
+                                expanded.row_mask, pargs)
+    found = jnp.any(found.reshape(k, probe.capacity), axis=0)
+    return (probe.row_mask & ~found if negated else found), err
+
+
+def keyed_exists_mask(probe: Batch, build: Batch, probe_keys, residual,
+                      negated: bool, prepared, packed, pargs=()):
+    """The mark over a build that holds every key ONCE (the `keyed`
+    form): a probe row's one match is looked up, the build columns the
+    residual reads come from ``packed`` (``ops.join.pack_sorted_payload``)
+    in one gather, and the residual is decided on that row. Nothing is
+    expanded. (mask, row-error scalar or None)."""
+    from ..ops.join import keyed_match
+    payload, residual = _residual_payload(residual, len(probe.columns))
+    cols, match = keyed_match(probe, build, probe_keys, payload, prepared,
+                              packed)
+    found, err = _residual_true(residual, list(probe.columns) + cols,
+                                match, pargs)
+    return (probe.row_mask & ~found if negated else found), err
+
+
+_RESIDUAL_PROGRAMS: Dict[tuple, object] = {}
+
+
+def _residual_program(form: str, node: SemiJoinNode, probe_schema: Schema,
+                      max_matches: int = 1):
+    """The ONE program a probe batch of a residual semi join launches:
+    ``expr_semi_<form>_<digest>`` (an expression program: its digest is
+    the residual's and the schemas', ``system.runtime.executables`` has
+    the text, plan-template parameters arrive as traced operands).
+    Called with ``(probe, build, prepared[, packed])``."""
+    from ..expr.compiler import _ExprProgram
+    key = (form, node.residual, probe_schema, node.source_keys,
+           node.filtering_keys, node.negated, max_matches)
+    fn = _RESIDUAL_PROGRAMS.get(key)
+    if fn is None:
+        # (the program outlives the query: it holds the residual and
+        # the keys, not the node and the plan under it)
+        residual, negated = node.residual, node.negated
+        skeys, fkeys = list(node.source_keys), list(node.filtering_keys)
+
+        def run(args, pargs=()):
+            if form == "keyed":
+                probe, build, prepared, packed = args
+                return keyed_exists_mask(probe, build, skeys, residual,
+                                         negated, prepared, packed, pargs)
+            probe, build, prepared = args
+            return mark_exists_mask(probe, build, skeys, fkeys, residual,
+                                    negated, max_matches, prepared, pargs)
+        fn = _RESIDUAL_PROGRAMS[key] = _ExprProgram(
+            "semi_" + form, key, run, [residual])
+    return fn
 
 
 import functools
@@ -777,13 +856,17 @@ class _Executor:
         yield Batch(_plan_schema(node), list(b.columns) + [mark_col],
                     b.row_mask)
 
-    def _grouped_partial_fn(self, group, aggs, kb):
+    def _grouped_partial_fn(self, group, aggs, kb, ordered=False):
         """Per-batch partial aggregation with the stats-bounds contract:
         record which kernel the grouping takes (once — the dispatch is
         shape-stable across an operator's batches), and when static key
         bounds are in play, append the device-side violation scalar to the
         error channel so a connector overclaiming its statistics fails the
-        query instead of silently misgrouping (one sync per query)."""
+        query instead of silently misgrouping (one sync per query).
+        ``ordered``: the planner promises every batch in the keys' order
+        (AggregationNode.ordered_input): the sort path compiles no sort,
+        and the scalar that says whether the batch kept the promise
+        goes the same way."""
         from ..ops.aggregation import dense_path_selected
         allow = bool_property(self.session, "dense_grouping", True)
         seen = {}
@@ -805,9 +888,13 @@ class _Executor:
             # (the state, whether the sort path made it: live rows
             # first and in the sort's order, which the state's merges
             # build on)
-            return grouped_aggregate(b, group, aggs, mode="partial",
-                                     key_bounds=kb,
-                                     allow_dense=allow), not dense
+            state = grouped_aggregate(b, group, aggs, mode="partial",
+                                      key_bounds=kb, allow_dense=allow,
+                                      ordered=ordered)
+            if ordered:
+                state, out_of_order = state
+                self.error_flags.append(out_of_order)
+            return state, not dense
         return partial
 
     def _expr_stage(self, nd: PlanNode):
@@ -1026,7 +1113,8 @@ class _Executor:
                 _AGG_STEP_DECLINED.inc()
                 partials = parallel_drivers(
                     self.run(node.child),
-                    self._grouped_partial_fn(group, aggs, kb),
+                    self._grouped_partial_fn(group, aggs, kb,
+                                             node.ordered_input),
                     concurrency)
             for p, normalized in partials:
                 buf.add_partial(p, normalized=normalized)
@@ -1777,7 +1865,7 @@ class _Executor:
         return out
 
     def _prepare_join_build(self, build: Batch, keys, summary=None,
-                            key_bounds=()):
+                            key_bounds=(), unique: bool = False):
         """LookupSource choice (reference HashBuilderOperator's
         BigintGroupByHash-vs-MultiChannel split), stats-first:
 
@@ -1799,7 +1887,10 @@ class _Executor:
         on this hardware. A build of at most COMPARE_ALL_LIMIT lanes
         gets NO table, whatever the planner promised (the promise is
         still checked): the probe compares each lane with every key of
-        the sorted layout and gathers nothing (ops/join._compare_all)."""
+        the sorted layout and gathers nothing (ops/join._compare_all).
+        ``unique``: the planner knows every key once (a residual semi
+        join's summary): a direct table then addresses the build as it
+        stands, unsorted (ops/join.build_in_order)."""
         from ..ops.join import COMPARE_ALL_LIMIT, direct_keyed_plan
         keys = tuple(keys)
         small = build.capacity <= COMPARE_ALL_LIMIT
@@ -1812,7 +1903,8 @@ class _Executor:
                     build, keys, tuple(key_bounds)))
                 if not small:
                     return prepare_direct_keyed_jit(
-                        build, keys, los, sizes, bucket_capacity(K))
+                        build, keys, los, sizes, bucket_capacity(K),
+                        unique)
         if small:
             return prepare_build_jit(build, keys)
         if len(keys) == 1 and isinstance(build.columns[keys[0]].type,
@@ -1824,7 +1916,7 @@ class _Executor:
                 span = hi - lo + 1
                 if 0 < span <= self.DIRECT_SPAN_LIMIT:
                     return prepare_direct_jit(
-                        build, keys, lo, bucket_capacity(span))
+                        build, keys, lo, bucket_capacity(span), unique)
         return prepare_build_jit(build, keys)
 
     def _pallas_probe_on(self) -> bool:
@@ -2037,29 +2129,50 @@ class _Executor:
             yield concat_batches(reps) if len(reps) > 1 else reps[0]
 
     def _SemiJoinNode(self, node: SemiJoinNode) -> Iterator[Batch]:
-        build = self._drain(node.filtering)
         skeys = list(node.source_keys)
         fkeys = list(node.filtering_keys)
-        summary = None
-        if build is not None:
-            # the same cut _join_once gives its build, from the same
-            # readback: what a HAVING left of a 2^24-lane state (TPC-H
-            # Q18: ~60 keys) is probed as the bucket of its live rows
-            summary = self._build_summary(build, fkeys)
-            scap = bucket_capacity(max(int(summary[0]), 1))
-            if scap < build.capacity:
-                build = compact_jit(build, scap)
-        prep = (self._prepare_join_build(build, fkeys, summary=summary,
-                                         key_bounds=node.key_bounds)
-                if build is not None else None)
-        if build is not None:
-            from ..ops.join import lookup_form
-            _note_join_strategy(
-                self.stats, node, lookup_form(prep),
-                node.distribution)
-        res_maxk = (self._build_multiplicity(prep)
-                    if build is not None and node.residual is not None
-                    else None)
+        # how a residual is decided: on the ONE row a source row's keys
+        # find where the planner knows the filtering side unique (a
+        # summary by key, optimizer._summarize_semi_residuals; a primary
+        # key): `keyed`; over the m:n matches otherwise: `expand`
+        form = (None if node.residual is None
+                else "keyed" if node.filtering_unique else "expand")
+        summary = prep = packed = res_maxk = None
+        # the filtering side of a residual semi join from its first
+        # batch to the prepared layout, its launches and readbacks
+        # included (they are the build's cost)
+        with (TRACER.span("semi-build", form=form, rows_in=-1,
+                          groups_out=-1) if form is not None
+              else contextlib.nullcontext()) as span:
+            build = self._drain(node.filtering)
+            if build is not None:
+                # the same cut _join_once gives its build, from the same
+                # readback: what a HAVING left of a 2^24-lane state
+                # (TPC-H Q18: ~60 keys) is probed as the bucket of its
+                # live rows
+                summary = self._build_summary(build, fkeys)
+                scap = bucket_capacity(max(int(summary[0]), 1))
+                if scap < build.capacity:
+                    build = compact_jit(build, scap)
+                prep = self._prepare_join_build(
+                    build, fkeys, summary=summary,
+                    key_bounds=node.key_bounds, unique=form == "keyed")
+                from ..ops.join import is_direct_prepared, lookup_form
+                _note_join_strategy(self.stats, node, lookup_form(prep),
+                                    node.distribution, form)
+                if form == "keyed":
+                    packed = pack_sorted_payload_jit(
+                        build, _residual_payload(
+                            node.residual, len(node.source.fields))[0],
+                        prep, is_direct_prepared(prep))
+                elif form == "expand":
+                    res_maxk = self._build_multiplicity(prep)
+                if span is not None:
+                    # the live rows of the build, from the readback the
+                    # build's cut made: the groups of a keyed summary,
+                    # the rows an expansion pairs the source with
+                    span.annotate(**{"groups_out" if form == "keyed"
+                                     else "rows_in": int(summary[0])})
         for b in self.run(node.source):
             if build is None:
                 if node.negated:
@@ -2068,16 +2181,22 @@ class _Executor:
                     yield Batch(b.schema, b.columns,
                                 jnp.zeros_like(b.row_mask))
                 continue
-            if node.residual is None:
+            if form is None:
                 mask = semi_join_mask_jit(b, build, skeys, fkeys,
                                           node.negated, node.null_aware,
                                           prep)
+            elif form == "keyed":
+                mask, err = _residual_program(form, node, b.schema)(
+                    (b, build, prep, packed))
             else:
                 maxk = res_maxk if res_maxk is not None else int(
                     match_count_max_jit(b, build, skeys, fkeys, prep))
-                mask = mark_exists_mask(
-                    b, build, skeys, fkeys, node.residual, node.negated,
-                    bucket_capacity(max(maxk, 1), minimum=1), ex=self)
+                maxk = bucket_capacity(max(maxk, 1), minimum=1)
+                _SEMI_EXPANDED_LANES.inc(b.capacity * maxk)
+                mask, err = _residual_program(form, node, b.schema, maxk)(
+                    (b, build, prep))
+            if form is not None and err is not None:
+                self.error_flags.append(err)
             yield Batch(b.schema, b.columns, mask)
 
 

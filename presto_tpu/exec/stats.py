@@ -127,10 +127,12 @@ class StatsCollector:
         return out
 
     def record_join_strategy(self, node, strategy: str,
-                             distribution: str) -> None:
+                             distribution: str,
+                             residual: Optional[str] = None) -> None:
         """Executed join-dispatch verdict for one join/semi-join
-        operator (exec/local._Executor._note_join_strategy's sink)."""
-        self.join_strategy[node] = (strategy, distribution)
+        operator (exec/local._Executor._note_join_strategy's sink);
+        ``residual`` the form that decided a semi join's residual."""
+        self.join_strategy[node] = (strategy, distribution, residual)
 
     def join_strategy_for(self, node) -> Optional[tuple]:
         return self.join_strategy.get(node)

@@ -235,7 +235,8 @@ def _boundary_groups(s_keys, s_mask):
     return boundary, group_id, num_groups
 
 
-def _group_sort(batch: Batch, group_indices: Sequence[int]):
+def _group_sort(batch: Batch, group_indices: Sequence[int],
+                order_violation: Optional[list] = None):
     """Sort rows by group keys; return (key_operands, permuted batch arrays).
 
     Returns (sorted_cols, sorted_validity, sorted_mask, boundary, group_id,
@@ -250,6 +251,26 @@ def _group_sort(batch: Batch, group_indices: Sequence[int]):
     key_ops = _group_key_ops(batch, group_indices)
     rows = ([c.data for c in batch.columns],
             [c.validity for c in batch.columns], batch.row_mask)
+
+    def squeezed():
+        # dead lanes among the live ones (a filter's mask over a table
+        # clustered by the keys: the late lines of TPC-H Q21) leave
+        # through the compress network, the live rows to the front in
+        # their order: log2(lanes) elementwise passes a column, where
+        # the sort below costs a gather a column (~11 ns a lane)
+        moves, count = compress_moves(batch.row_mask)
+        live = jnp.arange(batch.capacity) < count
+
+        def front(a):
+            a = compress_lanes(moves, a)
+            return jnp.where(per_lane(live, a), a, jnp.zeros_like(a))
+        ops = [front(k) for k in key_ops]
+        ops[0] = jnp.where(live, ops[0], _DEAD_RANK).astype(jnp.int32)
+        data, valid, _ = jax.tree_util.tree_map(front, rows)
+        return ops, (data, valid, live)
+    key_ops, rows = jax.lax.cond(
+        jnp.any(batch.row_mask[1:] & ~batch.row_mask[:-1]),
+        squeezed, lambda: (key_ops, rows))
 
     def by_sort():
         idx = jnp.arange(batch.capacity, dtype=jnp.int32)
@@ -269,8 +290,20 @@ def _group_sort(batch: Batch, group_indices: Sequence[int]):
     before = [shift_lanes(k, -1) for k in key_ops]
     ordered = jnp.all((_lex_greater(key_ops, before)
                        | _lex_equal(key_ops, before))[1:])
-    s_keys, (s_data, s_valid, s_mask) = jax.lax.cond(
-        ordered, lambda: (key_ops, rows), by_sort)
+    if order_violation is not None:
+        # the planner promises the order (the scan's table is clustered
+        # by the keys: AggregationNode.ordered_input): the sort's branch
+        # is not compiled (two thirds of this program's compile seconds
+        # at 2^20 lanes), and a batch out of order fails the query
+        # through the row-error channel, as a key outside its promised
+        # bounds does
+        from ..errors import STATS_BOUND_VIOLATION
+        order_violation.append(jnp.where(
+            ordered, jnp.int32(0), jnp.int32(STATS_BOUND_VIOLATION)))
+        s_keys, (s_data, s_valid, s_mask) = key_ops, rows
+    else:
+        s_keys, (s_data, s_valid, s_mask) = jax.lax.cond(
+            ordered, lambda: (key_ops, rows), by_sort)
     boundary, group_id, num_groups = _boundary_groups(s_keys, s_mask)
     return s_data, s_valid, s_mask, boundary, group_id, num_groups
 
@@ -424,8 +457,10 @@ class _SegReducers:
     order, so no scatter and no gather (the 64-bit scatter runs ~8M
     rows/s on the v5e, a gather ~11 ns a lane; an int64 wraps and its
     differences are exact; f64 prefix differences round differently
-    than per-group scatter order, which SQL sum(double) permits). min
-    and max stay segment scatters."""
+    than per-group scatter order, which SQL sum(double) permits). A min
+    or a max is a scan within the runs and the same pick of each run's
+    last row (a 64-bit segment scatter took 0.13 s a 2^20-lane batch a
+    column); a register tile's (HLL) stays a segment scatter."""
 
     def __init__(self, group_id: jnp.ndarray, cap: int,
                  boundary: jnp.ndarray, live: jnp.ndarray):
@@ -455,11 +490,27 @@ class _SegReducers:
         return self._fit(compress_lanes(self.last, csum)
                          - compress_lanes(self.first, shift_lanes(csum, -1)))
 
+    def _over_runs(self, x, fn):
+        """``fn`` folded over each run of ``x`` (the rows stand run by
+        run), a run's result where its last row stood: a scan that
+        doubles its reach a pass and never crosses a run's first row
+        (log2 of the lanes elementwise passes), then the compress
+        network, as for a sum."""
+        run = self.gid + 1              # zeros move in: no run's number
+        for k in range((x.shape[0] - 1).bit_length()):
+            same = shift_lanes(run, -(1 << k)) == run
+            x = jnp.where(same, fn(x, shift_lanes(x, -(1 << k))), x)
+        return self._fit(compress_lanes(self.last, x))
+
     def min(self, x):
-        return jax.ops.segment_min(x, self.gid, num_segments=self.cap)
+        if getattr(x, "ndim", 0) != 1:
+            return jax.ops.segment_min(x, self.gid, num_segments=self.cap)
+        return self._over_runs(x, jnp.minimum)
 
     def max(self, x):
-        return jax.ops.segment_max(x, self.gid, num_segments=self.cap)
+        if getattr(x, "ndim", 0) != 1:
+            return jax.ops.segment_max(x, self.gid, num_segments=self.cap)
+        return self._over_runs(x, jnp.maximum)
 
     def hll(self, valid, hashed, m):
         """HLL register update: one segment_max over flattened
@@ -1020,6 +1071,7 @@ def grouped_aggregate(
     output_capacity: Optional[int] = None,
     allow_dense: bool = True,
     key_bounds: Optional[Sequence[Optional[Tuple[int, int]]]] = None,
+    order_violation: Optional[list] = None,
 ) -> Batch:
     """GROUP BY aggregation. mode: 'single' | 'partial' | 'final' | 'merge'.
 
@@ -1032,6 +1084,11 @@ def grouped_aggregate(
     ``key_bounds`` (one Optional[(lo, hi)] per group key, from
     AggregationNode.key_bounds) lets integer keys join the dense
     composite-code path; see dense_group_plan.
+
+    ``order_violation``: a list, given where the planner promises that
+    every batch's live rows stand in the keys' order: the sort path then
+    groups them as they stand, compiles no sort, and appends the device
+    scalar that says whether the promise held (an error code or 0).
     """
     assert mode in ("single", "partial", "final", "merge")
     if has_drain_agg(aggs):
@@ -1074,7 +1131,7 @@ def grouped_aggregate(
                for parts in raw]
     else:
         s_data, s_valid, s_mask, boundary, group_id, num_groups = \
-            _group_sort(batch, group_indices)
+            _group_sort(batch, group_indices, order_violation)
 
         red = _SegReducers(group_id, cap, boundary, s_mask)
         out_mask = jnp.arange(cap) < num_groups

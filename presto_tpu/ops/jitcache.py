@@ -248,12 +248,23 @@ def _entry_cache(name: str, factory):
 
 
 def _grouped_factory(group_indices, aggs, mode, output_capacity,
-                     key_bounds, allow_dense):
+                     key_bounds, allow_dense, ordered=False):
     def run(batch):
         return grouped_aggregate(batch, group_indices, aggs, mode,
                                  output_capacity, allow_dense=allow_dense,
                                  key_bounds=key_bounds)
-    return run
+
+    def run_ordered(batch):
+        # (the state, the scalar that says whether the batch stood in
+        # the keys' order: 0 where the dense path took it, which asks
+        # for no order)
+        import jax.numpy as jnp
+        flag: list = []
+        out = grouped_aggregate(batch, group_indices, aggs, mode,
+                                output_capacity, allow_dense=allow_dense,
+                                key_bounds=key_bounds, order_violation=flag)
+        return out, (flag[0] if flag else jnp.int32(0))
+    return run_ordered if ordered else run
 
 
 _grouped = _entry_cache("grouped_aggregate", _grouped_factory)
@@ -262,11 +273,14 @@ _grouped = _entry_cache("grouped_aggregate", _grouped_factory)
 def grouped_aggregate_jit(batch, group_indices: Sequence[int],
                           aggs: Sequence[AggSpec], mode: str = "single",
                           output_capacity: Optional[int] = None,
-                          key_bounds=None, allow_dense: bool = True):
+                          key_bounds=None, allow_dense: bool = True,
+                          ordered: bool = False):
+    """``ordered``: the batch's live rows stand in the keys' order, the
+    planner says; the answer is then (state, order-violation scalar)."""
     return _grouped(tuple(group_indices), tuple(aggs), mode,
                     output_capacity,
                     tuple(key_bounds) if key_bounds else None,
-                    allow_dense)(batch)
+                    allow_dense, ordered)(batch)
 
 
 def _merge_pair_factory(group_indices, aggs, key_bounds, allow_dense):
@@ -506,12 +520,13 @@ from .join import prepare_direct  # noqa: E402
 
 _prepare_direct = _entry_cache(
     "prepare_direct",
-    lambda key_cols, size: (
-        lambda b, lo0: prepare_direct(b, key_cols, lo0, size)))
+    lambda key_cols, size, unique=False: (
+        lambda b, lo0: prepare_direct(b, key_cols, lo0, size, unique)))
 
 
-def prepare_direct_jit(build, key_cols, lo0, size: int):
-    return _prepare_direct(tuple(key_cols), size)(build, lo0)
+def prepare_direct_jit(build, key_cols, lo0, size: int,
+                       unique: bool = False):
+    return _prepare_direct(tuple(key_cols), size, unique)(build, lo0)
 
 
 from .join import prepare_direct_keyed  # noqa: E402
@@ -519,16 +534,32 @@ from .join import prepare_direct_keyed  # noqa: E402
 
 _prepare_direct_keyed = _entry_cache(
     "prepare_direct_keyed",
-    lambda key_cols, los, sizes, size: (
-        lambda b: prepare_direct_keyed(b, key_cols, los, sizes, size)))
+    lambda key_cols, los, sizes, size, unique=False: (
+        lambda b: prepare_direct_keyed(b, key_cols, los, sizes, size,
+                                       unique)))
 
 
-def prepare_direct_keyed_jit(build, key_cols, los, sizes, size: int):
+def prepare_direct_keyed_jit(build, key_cols, los, sizes, size: int,
+                             unique: bool = False):
     """Planner-bounded multi-key direct table: los/sizes/size are
     host-static (from JoinNode.key_bounds), so the table capacity — and
     every probe executable shape over it — is known at plan time."""
     return _prepare_direct_keyed(tuple(key_cols), tuple(los),
-                                 tuple(sizes), size)(build)
+                                 tuple(sizes), size, unique)(build)
+
+
+from .join import pack_sorted_payload  # noqa: E402
+
+_pack_payload = _entry_cache(
+    "pack_sorted_payload",
+    lambda payload, in_order: lambda b, prep: pack_sorted_payload(
+        b, payload, prep, in_order))
+
+
+def pack_sorted_payload_jit(build, payload, prepared, in_order=False):
+    """``ops.join.pack_sorted_payload``: once a unique build whose
+    residual semi join reads ``payload`` of the one match."""
+    return _pack_payload(tuple(payload), in_order)(build, prepared)
 
 
 def _lookup_pallas_factory(pkeys, bkeys, payload, names, jt):

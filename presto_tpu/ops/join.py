@@ -97,6 +97,21 @@ def build_sorted(build: Batch, key_cols: Sequence[int]):
     return s_ops, slive, perm
 
 
+def build_in_order(build: Batch, key_cols: Sequence[int]):
+    """``build_sorted``'s triple for a build that holds every key ONCE
+    and is addressed through a direct table alone: the rows as they
+    stand (the identity permutation, dead and null-key rows where they
+    are, under the sentinel). A direct table finds a key's run by its
+    slot, and a run of one row is contiguous in any order, so nothing
+    has to be sorted (at 2^24 lanes the sort compiles for 140 s for a
+    described v5e); a binary search or compare-all needs
+    ``build_sorted``."""
+    ops, kvalid = _key_arrays(build, key_cols)
+    live = build.row_mask & kvalid
+    return ([jnp.where(live, op, _key_sentinel(op.dtype)) for op in ops],
+            live, jnp.arange(build.capacity, dtype=jnp.int32))
+
+
 def prepare_build(build: Batch, key_cols: Sequence[int]):
     """One-time build-side preparation (sorted key operands + live mask +
     permutation) shared by every probe batch of a join — the role of the
@@ -107,7 +122,7 @@ def prepare_build(build: Batch, key_cols: Sequence[int]):
 
 
 def prepare_direct(build: Batch, key_cols: Sequence[int], lo0,
-                   size: int):
+                   size: int, unique: bool = False):
     """Direct-address lookup table for a single integer key with a
     host-known bounded range — the BigintGroupByHash-style dense-int
     fast path applied to joins (reference BigintGroupByHash.java's array
@@ -123,18 +138,30 @@ def prepare_direct(build: Batch, key_cols: Sequence[int], lo0,
     indexed by (key - lo0); empty slots hold (n, 0). INVARIANT (both
     direct layouts; ``_point_lookup`` rests on it and reads lo_table
     alone): dead rows go to the overflow slot, which is sliced off, so
-    for every slot inside the table ``lo < n`` iff ``cnt > 0``."""
-    s_ops, slive, perm = build_sorted(build, key_cols)
+    for every slot inside the table ``lo < n`` iff ``cnt > 0``.
+    ``unique``: the caller knows every key once; the build is addressed
+    as it stands (``build_in_order``)."""
+    s_ops, slive, perm = (build_in_order if unique
+                          else build_sorted)(build, key_cols)
     n = s_ops[0].shape[0]
     off = jnp.clip(s_ops[0] - lo0, 0, size - 1).astype(jnp.int32)
     tgt = jnp.where(slive, off, size)       # dead rows -> overflow slot
     idx = jnp.arange(n, dtype=jnp.int32)
     lo_table = jnp.full(size + 1, n, dtype=jnp.int32) \
         .at[tgt].min(idx)[:size]
-    cnt_table = jnp.zeros(size + 1, dtype=jnp.int32) \
-        .at[tgt].add(jnp.int32(1))[:size]
+    cnt_table = _run_lengths(lo_table, tgt, n, size, unique)
     return (jnp.asarray(lo0, dtype=jnp.int64), lo_table, cnt_table,
             s_ops, slive, perm)
+
+
+def _run_lengths(lo_table, tgt, n: int, size: int, unique: bool):
+    """A direct layout's cnt_table: the rows of each slot's run, a
+    second scatter over the build; where every key stands once, 1 where
+    the slot is taken (the invariant), with no scatter."""
+    if unique:
+        return (lo_table < n).astype(jnp.int32)
+    return jnp.zeros(size + 1, dtype=jnp.int32) \
+        .at[tgt].add(jnp.int32(1))[:size]
 
 
 #: largest composite slot-table size a planner-keyed direct build may
@@ -186,7 +213,7 @@ def _composite_code(ops: Sequence[jnp.ndarray], los, sizes):
 
 def prepare_direct_keyed(build: Batch, key_cols: Sequence[int],
                          los: Sequence[int], sizes: Sequence[int],
-                         size: int):
+                         size: int, unique: bool = False):
     """Multi-key direct-address table from PLANNER-PROMISED key bounds
     (``JoinNode.key_bounds``): composite mixed-radix slot per key tuple,
     answered in one gather per probe lane (two for a run's length)
@@ -201,8 +228,11 @@ def prepare_direct_keyed(build: Batch, key_cols: Sequence[int],
     (the ``dense_group_plan`` contract), so an overclaiming connector
     fails the query instead of silently dropping matches.
 
+    ``unique``: as for ``prepare_direct``.
+
     Returns (los, sizes, lo_table, cnt_table, s_ops, slive, perm)."""
-    s_ops, slive, perm = build_sorted(build, key_cols)
+    s_ops, slive, perm = (build_in_order if unique
+                          else build_sorted)(build, key_cols)
     n = s_ops[0].shape[0]
     code, inr = _composite_code(s_ops, los, sizes)
     # lexicographic sort == composite-code sort inside the domain, so
@@ -211,8 +241,7 @@ def prepare_direct_keyed(build: Batch, key_cols: Sequence[int],
     idx = jnp.arange(n, dtype=jnp.int32)
     lo_table = jnp.full(size + 1, n, dtype=jnp.int32) \
         .at[tgt].min(idx)[:size]
-    cnt_table = jnp.zeros(size + 1, dtype=jnp.int32) \
-        .at[tgt].add(jnp.int32(1))[:size]
+    cnt_table = _run_lengths(lo_table, tgt, n, size, unique)
     return (jnp.asarray(los, dtype=jnp.int64),
             jnp.asarray(sizes, dtype=jnp.int64),
             lo_table, cnt_table, s_ops, slive, perm)
@@ -455,6 +484,109 @@ def lookup_join(
     else:
         mask = probe.row_mask
     return Batch(Schema(out_fields), out_cols, mask)
+
+
+# -- a unique build's payload, for a residual decided on the one match ---------
+
+def _payload_words(c: Column) -> List[jnp.ndarray]:
+    """A column's data as uint32 words (low word first), lane by lane."""
+    d = c.data
+    if getattr(d, "ndim", 1) == 2:          # long decimal: two int64 limbs
+        parts = [d[..., 0], d[..., 1]]
+    elif d.dtype == jnp.bool_:
+        parts = [d.astype(jnp.uint32)]
+    else:
+        parts = [d]
+    out: List[jnp.ndarray] = []
+    for x in parts:
+        if x.dtype.itemsize == 8:
+            u = jax.lax.bitcast_convert_type(x, jnp.uint64)
+            out += [u.astype(jnp.uint32),
+                    (u >> jnp.uint64(32)).astype(jnp.uint32)]
+        elif x.dtype.itemsize == 4:
+            out.append(jax.lax.bitcast_convert_type(x, jnp.uint32))
+        else:
+            out.append(x.astype(jnp.int32).astype(jnp.uint32))
+    return out
+
+
+def _payload_width(c: Column) -> int:
+    """Words ``_payload_words`` makes of the column."""
+    limbs = 2 if getattr(c.data, "ndim", 1) == 2 else 1
+    return limbs * (2 if c.data.dtype.itemsize == 8 else 1)
+
+
+def _payload_column(like: Column, words: List[jnp.ndarray],
+                    valid: jnp.ndarray) -> Column:
+    """The inverse of ``_payload_words`` for gathered words."""
+    def one(dtype, ws):
+        if dtype.itemsize == 8:
+            u = ws[0].astype(jnp.uint64) | (ws[1].astype(jnp.uint64)
+                                            << jnp.uint64(32))
+            return jax.lax.bitcast_convert_type(u, dtype)
+        if dtype.itemsize == 4:
+            return jax.lax.bitcast_convert_type(ws[0], dtype)
+        if dtype == jnp.bool_:
+            return ws[0] != 0
+        return ws[0].astype(jnp.int32).astype(dtype)
+    d = like.data
+    if getattr(d, "ndim", 1) == 2:
+        data = jnp.stack([one(d.dtype, words[0:2]), one(d.dtype, words[2:4])],
+                         axis=-1)
+    else:
+        data = one(d.dtype, words)
+    return Column(like.type, data, valid, like.dictionary)
+
+
+def pack_sorted_payload(build: Batch, payload: Sequence[int], prepared,
+                        in_order: bool = False):
+    """The ``payload`` columns of a build in the prepared layout's SORTED
+    order, packed into ONE uint32 array of shape [words, n]: every
+    column's data words and, last, one word of validity bits (bit j:
+    column j). Made once a build, so that a probe lane reads all it
+    needs of its match in one gather of a column of words at the
+    position ``_point_lookup`` found, where reading each column's data
+    and validity through ``perm`` is four gathers a column a lane
+    (``lookup_join``, PERF.md section 5: ~7 to 11 ns each).
+    ``in_order``: the layout is the build as it stands
+    (``build_in_order``), and nothing is gathered here either."""
+    assert len(payload) <= 32
+    words: List[jnp.ndarray] = []
+    bits = jnp.zeros(build.capacity, dtype=jnp.uint32)
+    for j, ci in enumerate(payload):
+        c = build.columns[ci]
+        words += _payload_words(c)
+        bits = bits | (c.validity.astype(jnp.uint32) << jnp.uint32(j))
+    packed = jnp.stack(words + [bits])
+    if in_order:
+        return packed
+    return jnp.take(packed, _split_prepared(prepared)[2], axis=1)
+
+
+def keyed_match(probe: Batch, build: Batch, probe_keys: Sequence[int],
+                payload: Sequence[int], prepared, packed
+                ) -> Tuple[List[Column], jnp.ndarray]:
+    """(payload columns, match) per probe lane against a build that
+    holds every key ONCE: the lane's one match found by
+    ``_point_lookup`` (one gather into a direct table, none for a small
+    build), its payload read from ``packed`` (``pack_sorted_payload``)
+    in one more. Nothing is expanded: the output has the probe's
+    capacity. NULL keys never match; a payload column is NULL where
+    there is no match."""
+    q_ops, pvalid = _key_arrays(probe, probe_keys)
+    pos, hit = _point_lookup(q_ops, prepared)
+    match = probe.row_mask & pvalid & hit
+    got = jnp.take(packed, pos, axis=1)             # [words, lanes]
+    cols: List[Column] = []
+    at = 0
+    for j, ci in enumerate(payload):
+        c = build.columns[ci]
+        n = _payload_width(c)
+        valid = ((got[-1] >> jnp.uint32(j)) & jnp.uint32(1)) != 0
+        cols.append(_payload_column(c, [got[at + i] for i in range(n)],
+                                    valid & match))
+        at += n
+    return cols, match
 
 
 def match_count_max(

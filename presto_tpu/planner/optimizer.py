@@ -31,7 +31,7 @@ from ..obs.metrics import REGISTRY
 from ..sql.analyzer import Field
 from .plan import (
     AggregationNode, DistinctNode, FilterNode, JoinNode, LimitNode,
-    OutputNode, PlanNode, ProjectNode, SemiJoinNode, SortNode,
+    OutputNode, PlanAgg, PlanNode, ProjectNode, SemiJoinNode, SortNode,
     TableScanNode, TopNNode, UnionNode, ValuesNode,
 )
 from .planner import LogicalPlan, Session, bool_property
@@ -62,6 +62,7 @@ def optimize(plan: LogicalPlan, session: Session) -> LogicalPlan:
         node = iterative_optimize(fold(node))
         node = _rewrite_joins(node, session)
         node, _ = _prune(node, list(range(len(node.fields))))
+        node = _summarize_semi_residuals(node)
         node = _implement_joins(node, session)
         if bool_property(session, "push_partial_aggregation_through_join",
                          True):
@@ -797,6 +798,83 @@ def _narrow(node: PlanNode, indices: List[int],
 
 
 # ---------------------------------------------------------------------------
+# Pass 2b: a correlated EXISTS with ONE comparison reads a summary by key
+# ---------------------------------------------------------------------------
+
+_SEMIJOIN_SUMMARIZED = REGISTRY.counter("plan_semijoin_summarized_total")
+
+#: `B op P` read as `P op' B`
+_FLIPPED = {"ne": "ne", "lt": "gt", "le": "ge", "gt": "lt", "ge": "le"}
+
+
+def _summarize_semi_residuals(node: PlanNode) -> PlanNode:
+    """A semi or anti join whose residual is ONE comparison ``P op B``
+    (``<>``, ``<``, ``<=``, ``>``, ``>=``; an ``=`` is one more key)
+    between an expression ``P`` of the source and a column ``B`` of the
+    filtering side asks, of the filtering rows with the source row's
+    keys, only whether ANY satisfies it, and that is decided by their
+    least and their greatest ``B``: some ``B <> P`` iff ``min <> P or
+    max <> P``, some ``B > P`` iff ``max > P``, some ``B < P`` iff
+    ``min < P`` (NULLs of ``B`` are in neither, a NULL ``P`` compares to
+    NULL, a key with no non-NULL ``B`` has NULL for both: no match, as
+    SQL says). So the filtering side becomes ``group by keys: min(B),
+    max(B)``, a row a key, and the residual a predicate over that ONE
+    row: the executor looks each source row's key up once and expands
+    nothing (``exec/local._SemiJoinNode``, the ``keyed`` form), where
+    the m:n form pairs every source row with every filtering row of its
+    key. TPC-H Q21's ``l2.l_suppkey <> l1.l_suppkey`` (twice), Q4- and
+    Q22-style EXISTS with a comparison. ``B`` a bare column of an
+    integer-family type (an expression would be evaluated, and could
+    raise, for filtering rows that match no source row); ``P`` anything
+    over the source's columns."""
+    node = node.with_children([_summarize_semi_residuals(c)
+                               for c in node.children])
+    if not isinstance(node, SemiJoinNode) or node.residual is None:
+        return node
+    r = node.residual
+    if not (isinstance(r, ir.Call) and r.name in _FLIPPED
+            and len(r.args) == 2):
+        return node
+    n_src = len(node.source.fields)
+
+    def side(e: ir.Expr) -> Optional[bool]:
+        refs = referenced_inputs(e)
+        if refs and all(i < n_src for i in refs):
+            return True
+        if refs and all(i >= n_src for i in refs):
+            return False
+        return None
+    op, (p, b) = r.name, r.args
+    if side(p) is False and side(b) is True:
+        op, p, b = _FLIPPED[op], b, p
+    if not (side(p) is True and side(b) is False
+            and isinstance(b, ir.InputRef)
+            and isinstance(b.type, _BOUNDABLE) and p.type == b.type):
+        return node
+    nk = len(node.filtering_keys)
+    summary = AggregationNode(
+        child=node.filtering, group_indices=tuple(node.filtering_keys),
+        aggs=(PlanAgg("min", b.index - n_src, b.type, "$semi_min"),
+              PlanAgg("max", b.index - n_src, b.type, "$semi_max")),
+        fields=tuple(node.filtering.fields[k] for k in node.filtering_keys)
+        + (Field("$semi_min", b.type), Field("$semi_max", b.type)))
+    least = ir.input_ref(n_src + nk, b.type)
+    greatest = ir.input_ref(n_src + nk + 1, b.type)
+    if op == "ne":
+        residual: ir.Expr = ir.special(
+            ir.Form.OR, r.type, ir.call("ne", r.type, p, least),
+            ir.call("ne", r.type, p, greatest))
+    else:
+        # `P < B` for some B iff P < max; `P > B` iff P > min
+        residual = ir.call(op, r.type, p,
+                           greatest if op in ("lt", "le") else least)
+    _SEMIJOIN_SUMMARIZED.inc()
+    return dataclasses.replace(
+        node, filtering=summary, filtering_keys=tuple(range(nk)),
+        residual=residual)
+
+
+# ---------------------------------------------------------------------------
 # Pass 3: join implementation (build side + distribution)
 # ---------------------------------------------------------------------------
 
@@ -1220,6 +1298,11 @@ def _attach_join_strategy(node: PlanNode, session: Session,
         if kb:
             node = dataclasses.replace(node, key_bounds=kb)
     if isinstance(node, SemiJoinNode):
+        if node.residual is not None:
+            # one filtering row a key: the residual is decided on that
+            # row, with no expansion (the executor's `keyed` form)
+            node = dataclasses.replace(node, filtering_unique=_key_unique(
+                node.filtering, node.filtering_keys, session))
         if dense:
             kb = _join_key_bounds(node.filtering, node.filtering_keys,
                                   session)
@@ -1239,6 +1322,33 @@ def _attach_join_strategy(node: PlanNode, session: Session,
     return node
 
 
+def _clustered_by(node: PlanNode, cols: Sequence[int],
+                  session: Session) -> bool:
+    """Do ``node``'s rows arrive, batch by batch, in the ascending order
+    of ``cols``? True where a connector states that a scanned table is
+    clustered by exactly those columns, in that order
+    (``TableStats.clustered_by``), and nothing between reorders rows:
+    filters and semi joins narrow the mask, pass-through projections
+    rename. A statistic like any other: the executor checks every batch
+    and a lie fails the query."""
+    if isinstance(node, FilterNode):
+        return _clustered_by(node.child, cols, session)
+    if isinstance(node, SemiJoinNode):
+        return _clustered_by(node.source, cols, session)
+    if isinstance(node, ProjectNode):
+        exprs = [node.exprs[c] for c in cols]
+        return (all(isinstance(e, ir.InputRef) for e in exprs)
+                and _clustered_by(node.child, [e.index for e in exprs],
+                                  session))
+    if isinstance(node, TableScanNode):
+        stats = session.catalogs.get(node.catalog).metadata.table_stats(
+            node.table)
+        by = tuple(getattr(stats, "clustered_by", ()) or ())
+        return bool(by) and tuple(
+            node.columns[c] for c in cols) == by[:len(cols)]
+    return False
+
+
 def _attach_group_bounds(node: PlanNode, session: Session) -> PlanNode:
     """Attach stats-derived static key bounds to aggregations and
     DISTINCTs whose composite key domain is provably small — the
@@ -1248,6 +1358,8 @@ def _attach_group_bounds(node: PlanNode, session: Session) -> PlanNode:
     node = node.with_children([_attach_group_bounds(c, session)
                                for c in node.children])
     if isinstance(node, AggregationNode) and node.group_indices:
+        if _clustered_by(node.child, node.group_indices, session):
+            node = dataclasses.replace(node, ordered_input=True)
         kb = _bounds_for_keys(node.child, node.group_indices, session)
         if kb:
             return dataclasses.replace(node, key_bounds=kb)

@@ -147,6 +147,13 @@ class AggregationNode(PlanNode):
     # ROLLUP/CUBE empty sets, whose grand-total row exists even over
     # empty input
     default_gids: Tuple[int, ...] = ()
+    # every batch's live rows arrive in the group keys' order: the keys
+    # are the columns a scanned table is clustered by
+    # (TableStats.clustered_by) and only filters and pass-through
+    # projections stand between. The sort path then groups a batch as it
+    # stands and compiles no sort; a batch out of order fails the query
+    # (optimizer._attach_group_bounds)
+    ordered_input: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -216,6 +223,11 @@ class SemiJoinNode(PlanNode):
     # stats-derived hard [lo, hi] per FILTERING key (see
     # JoinNode.key_bounds — enables the direct-address membership table)
     key_bounds: Tuple[Optional[Tuple[int, int]], ...] = ()
+    # the filtering side holds every key tuple once (a group-by over the
+    # keys, a primary key): a residual is then decided on the ONE row a
+    # source row's keys find, and nothing is expanded (the executor's
+    # `keyed` form; optimizer._attach_join_strategy)
+    filtering_unique: bool = False
 
     @property
     def children(self) -> Tuple[PlanNode, ...]:

@@ -397,6 +397,8 @@ def _label(n: PlanNode) -> str:
             spans = ["?" if b is None else f"{b[0]}..{b[1]}"
                      for b in n.key_bounds]
             dense = f", bounds=[{', '.join(spans)}]"
+        if n.ordered_input:
+            dense += ", ordered input"
         return (f"Aggregate[{n.step}, keys={list(n.group_indices)}"
                 f"{dense}] => [{aggs}]")
     if isinstance(n, JoinNode):
@@ -405,7 +407,9 @@ def _label(n: PlanNode) -> str:
                 f"{', unique' if n.build_unique else ''}"
                 f"{_bounds_label(n.key_bounds)}]")
     if isinstance(n, SemiJoinNode):
-        res = ", residual" if n.residual is not None else ""
+        res = "" if n.residual is None else (
+            ", residual on a unique build" if n.filtering_unique
+            else ", residual")
         return (f"SemiJoin[{'anti' if n.negated else 'semi'}, "
                 f"{n.distribution}, keys={list(n.source_keys)}{res}"
                 f"{_bounds_label(n.key_bounds)}]")
@@ -475,7 +479,8 @@ def _walk(n: PlanNode, depth: int, lines: List[str], stats=None) -> None:
             js = (stats.join_strategy_for(n)
                   if hasattr(stats, "join_strategy_for") else None)
             if js is not None:
-                suffix += f" [strategy {js[0]}/{js[1]}]"
+                form = f", residual {js[2]}" if len(js) > 2 and js[2] else ""
+                suffix += f" [strategy {js[0]}/{js[1]}{form}]"
         elif not isinstance(n, OutputNode):
             suffix = "   [not executed]"
     lines.append("  " * depth + "- " + _label(n) + suffix)

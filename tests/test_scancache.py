@@ -380,3 +380,34 @@ def test_explain_analyze_scan_cache_line(runner):
     text = "\n".join(r[0] for r in out.rows)
     assert "Scan cache:" in text
     assert "hit" in text.split("Scan cache:")[1]
+
+
+def test_a_narrower_scan_is_served_from_the_wider_entry():
+    """TPC-H Q21 reads lineitem three times, twice four columns and
+    once two of them: ONE resident copy serves all three (ISSUE 35). A
+    scan whose columns an entry of the same split holds is a hit on
+    that entry, narrowed on the host; a wider scan that arrives later
+    replaces what it makes redundant."""
+    from presto_tpu.exec.scancache import CACHE
+    from presto_tpu.obs.metrics import REGISTRY
+    CACHE.clear()
+    r = LocalRunner(tpch_sf=0.01, rows_per_batch=8192)
+    wide = "select sum(l_suppkey), max(l_commitdate) from lineitem " \
+        "where l_receiptdate > l_commitdate and l_orderkey > 0"
+    narrow = "select sum(l_suppkey), count(l_orderkey) from lineitem"
+    want_narrow = r.execute(narrow).rows          # staged: two columns
+    resident = CACHE.resident_bytes
+    want_wide = r.execute(wide).rows              # four: replaces the two
+    assert resident < CACHE.resident_bytes < 3 * resident
+    with CACHE._lock:
+        columns = {k[CACHE._COLUMNS] for k in CACHE._entries
+                   if k[3] == "lineitem"}
+    assert len(columns) == 1 and len(next(iter(columns))) == 4
+    hits = REGISTRY.value("scan_cache_hit_total")
+    misses = REGISTRY.value("scan_cache_miss_total")
+    held = CACHE.resident_bytes
+    assert r.execute(narrow).rows == want_narrow
+    assert r.execute(wide).rows == want_wide
+    assert REGISTRY.value("scan_cache_miss_total") == misses
+    assert REGISTRY.value("scan_cache_hit_total") > hits
+    assert CACHE.resident_bytes == held

@@ -230,6 +230,139 @@ def test_membership_probe_compiles_for_v5e_with_one_gather_or_none(
     assert c.memory_analysis().temp_size_in_bytes <= 16 * lanes
 
 
+N_STATE = 1 << 24        # a summary of TPC-H SF10's 15M orders by key
+
+
+def _q21_summary():
+    """(a summary by key as TPC-H Q21's residual semi joins read it:
+    order key, least and greatest supplier key; its direct layout, the
+    build as it stands), tiny."""
+    from presto_tpu import types as T
+    from presto_tpu.batch import Batch
+    from presto_tpu.ops import join as J
+    summary = Batch.from_pydict({"k": (T.BIGINT, [3, 1, 2]),
+                                 "lo": (T.BIGINT, [5, 5, 6]),
+                                 "hi": (T.BIGINT, [9, 5, 7])})
+    return summary, J.prepare_direct_keyed(summary, [0], (1,), (4,), 4,
+                                           unique=True)
+
+
+def _described_prepared(prepared, slots, lanes, one_chip):
+    los, sizes, lo_t, cnt_t, *rest = prepared
+    return tuple(jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+                 for x in (los, sizes)) + tuple(
+        _described(x, slots, one_chip) for x in (lo_t, cnt_t)
+    ) + tuple(_described(rest, lanes, one_chip))
+
+
+def test_keyed_semi_build_compiles_for_v5e_without_a_gather(
+        one_chip, record_property):
+    """The filtering side of a `keyed` residual semi join at TPC-H
+    SF10 (ISSUE 35): 2^24 lanes of (key, min, max), every key once. Its
+    direct table addresses the build as it stands: no sort of the
+    build's operands with a permutation behind it (the scatters that
+    fill the two tables are the program's only sorts), no gather; the
+    payload is packed with no gather and no sort. Compile seconds under
+    120 (19 and 0.3 on this sandbox's CPU, alone)."""
+    import re
+    import time
+    from presto_tpu.ops.jitcache import _pack_payload, _prepare_direct_keyed
+    summary, prep = _q21_summary()
+    build = _described(summary, N_STATE, one_chip)
+    t = time.perf_counter()
+    c = _prepare_direct_keyed((0,), (1,), (15_000_000,), N_STATE,
+                              True).fn.lower(build).compile()
+    text = c.as_text()
+    assert "jit_op_prepare_direct_keyed" in text
+    assert not re.findall(r"\sgather\(", text)
+    assert len(re.findall(r"\ssort\(", text)) <= 2
+    c = _pack_payload((1, 2), True).fn.lower(
+        build, _described_prepared(prep, N_STATE, N_STATE,
+                                   one_chip)).compile()
+    seconds = time.perf_counter() - t
+    record_property("compile_s", round(seconds, 2))
+    text = c.as_text()
+    assert "jit_op_pack_sorted_payload" in text
+    assert not re.findall(r"\s(gather|scatter|sort)\(", text)
+    assert seconds < 120
+
+
+def test_keyed_semi_probe_compiles_for_v5e_with_two_gathers(
+        one_chip, record_property):
+    """One probe batch of TPC-H Q21's EXISTS (2^20 late lines against
+    the 2^24-lane summary): ONE program, `jit_expr_semi_keyed_*`, that
+    holds exactly two gathers (the direct table, then the packed words
+    of the one match), no sort and no scatter, and nothing of lanes x
+    matches: the m:n form gathered every probe and build column for
+    each of 8 x 2^20 expanded lanes."""
+    import re
+    import time
+    from presto_tpu import types as T
+    from presto_tpu.batch import Batch
+    from presto_tpu.exec.local import _residual_program
+    from presto_tpu.expr import ir
+    from presto_tpu.planner.plan import SemiJoinNode
+    summary, prep = _q21_summary()
+    probe = Batch.from_pydict({"k": (T.BIGINT, [1, 2, 3]),
+                               "s": (T.BIGINT, [5, 6, 7])})
+    supp = ir.input_ref(1, T.BIGINT)
+    node = SemiJoinNode(
+        source=None, filtering=None, source_keys=(0,), filtering_keys=(0,),
+        fields=(), negated=True, null_aware=False, filtering_unique=True,
+        residual=ir.special(
+            ir.Form.OR, T.BOOLEAN,
+            ir.call("ne", T.BOOLEAN, supp, ir.input_ref(3, T.BIGINT)),
+            ir.call("ne", T.BOOLEAN, supp, ir.input_ref(4, T.BIGINT))))
+    program = _residual_program("keyed", node, probe.schema)
+    packed = jax.ShapeDtypeStruct((5, N_STATE), jnp.uint32,
+                                  sharding=one_chip)
+    t = time.perf_counter()
+    c = program.fn.lower((
+        _described(probe, N_BATCH, one_chip),
+        _described(summary, N_STATE, one_chip),
+        _described_prepared(prep, N_STATE, N_STATE, one_chip),
+        packed)).compile()
+    seconds = time.perf_counter() - t
+    record_property("compile_s", round(seconds, 2))
+    text = c.as_text()
+    assert program.program in text
+    assert program.program.startswith("jit_expr_semi_keyed_")
+    assert len(re.findall(r"\sgather\(", text)) == 2
+    assert not re.findall(r"\s(scatter|sort)\(", text)
+    assert c.memory_analysis().temp_size_in_bytes <= 64 * N_BATCH
+    assert seconds < 60
+
+
+def test_summary_partial_lowers_without_a_scatter_for_v5e(one_chip):
+    """The summary's partial group-by (`min`, `max` by key over a
+    2^20-lane batch) as the TPU path lowers it: a min or a max of a run
+    is a scan within the runs and the compress network, not the 64-bit
+    segment scatter it was (0.13 s a batch a column on the v5e); the
+    one sort is the branch for a batch out of key order. Lowered, not
+    compiled: the program compiles for two minutes on this CPU (129 s,
+    its compiled text then holds no scatter; my run, PR 35)."""
+    from presto_tpu import types as T
+    from presto_tpu.batch import Batch
+    from presto_tpu.ops.aggregation import AggSpec
+    from presto_tpu.ops.jitcache import _grouped
+    rows = Batch.from_pydict({"k": (T.BIGINT, [1, 2, 2]),
+                              "v": (T.BIGINT, [5, 6, 7])})
+    aggs = (AggSpec("min", 1, T.BIGINT, "lo"),
+            AggSpec("max", 1, T.BIGINT, "hi"))
+    import re
+
+    def ops(aggs):
+        lowered = _grouped((0,), aggs, "partial", None, None,
+                           True).fn.lower(_described(rows, N_BATCH, one_chip))
+        return re.findall(r"stablehlo\.(\w+)", lowered.as_text())
+    # (what is left are one-element updates of the boundary marks,
+    # which the TPU compiler makes slices: TPC-H Q18's partial, a sum,
+    # holds as many and compiles with none, PR 33)
+    mine, sums = ops(aggs), ops((AggSpec("sum", 1, T.BIGINT, "s"),))
+    assert mine.count("scatter") == sums.count("scatter") <= 4
+    assert mine.count("sort") == 1 and "while" not in mine
+
+
 def _probe_shapes(one_chip):
     i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32,  # noqa: E731
                                          sharding=one_chip)
