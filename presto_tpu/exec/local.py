@@ -71,6 +71,13 @@ _SEMI_RESIDUAL = {form: REGISTRY.counter(f"semi_join_residual_total.{form}")
                   for form in ("keyed", "expand")}
 _SEMI_EXPANDED_LANES = REGISTRY.counter("semi_join_expanded_lanes_total")
 
+#: how each payload-reading probe launch read its build's payload
+#: (``ops/join.payload_form`` over the capacities the program is traced
+#: with): `composed` through the permutation at the probe's lanes,
+#: `permuted` through sorted copies made at the build's
+_JOIN_PAYLOAD = {form: REGISTRY.counter(f"join_payload_selected_total.{form}")
+                 for form in ("composed", "permuted")}
+
 
 def _note_join_strategy(stats, node, strategy: str, dist: str,
                         residual: Optional[str] = None) -> None:
@@ -88,7 +95,18 @@ def _note_join_strategy(stats, node, strategy: str, dist: str,
     if stats is not None and hasattr(stats, "record_join_strategy"):
         stats.record_join_strategy(node, strategy, dist, residual)
 
-from ..ops.join import expand_join
+
+def _note_payload_form(probe_lanes: int, build_lanes: int, n_payload: int,
+                       pallas: bool = False) -> None:
+    """One count a probe launch that reads a build's payload.
+    ``probe_lanes``: the positions the program reads (an expansion's
+    k x capacity). The Pallas probe's kernel gathers from sorted planes
+    in VMEM: `permuted` whatever the sizes."""
+    _JOIN_PAYLOAD["permuted" if pallas else payload_form(
+        probe_lanes, build_lanes, n_payload)].inc()
+
+
+from ..ops.join import expand_join, payload_form
 from ..ops.sort import SortKey, limit as limit_kernel, sort_batch, top_n
 from ..planner.plan import (
     AggregationNode, DistinctNode, FilterNode, GroupIdNode, JoinNode,
@@ -1361,13 +1379,14 @@ class _Executor:
         preps_t, builds_t, dyns_t = tuple(preps), tuple(builds), tuple(dyns)
         window = max(1, int(self.session.properties.get(
             "fused_compact_window", 4)))
+        joins = tuple(st for st in tail if isinstance(st, JoinStage))
         return self._stream_fused(fn_head, fn_tail, source, pre_vals,
                                   preps_t, builds_t, dyns_t, window,
-                                  close_bufs)
+                                  close_bufs, joins)
 
     def _stream_fused(self, fn_head, fn_tail, source, pre_vals, preps_t,
                       builds_t, dyns_t, window,
-                      close_bufs) -> Iterator[Batch]:
+                      close_bufs, joins) -> Iterator[Batch]:
         """Head -> windowed compaction -> tail streaming loop. One
         liveness readback per ``window`` probe batches (the head carries
         each batch's live count as a traced scalar); the check disables
@@ -1408,6 +1427,11 @@ class _Executor:
 
         def run_tail(hb: Batch) -> Iterator[Batch]:
             _FUSED_TAIL_LANES.inc(hb.capacity)
+            # a lookup join keeps its probe's capacity: every stage of
+            # the tail is traced at the head batch's lanes
+            for st, build in zip(joins, builds_t):
+                _note_payload_form(hb.capacity, build.capacity,
+                                   len(st.payload), st.pallas)
             # a Pallas stage that fails to lower fails the query with
             # the compiler's message (join_pallas_probe, ops/pallas_join)
             out, err = fn_tail(hb, preps_t, builds_t, dyns_t)
@@ -1931,8 +1955,11 @@ class _Executor:
         on, a kernel that does not lower fails the query with the
         compiler's message."""
         from ..ops import pallas_join as PJ
-        if self._pallas_probe_on() and PJ.supports_join(prepared, build,
-                                                        payload):
+        pallas = self._pallas_probe_on() and PJ.supports_join(
+            prepared, build, payload)
+        _note_payload_form(probe.capacity, build.capacity, len(payload),
+                           pallas)
+        if pallas:
             return lookup_join_pallas_jit(
                 probe, build, lkeys, rkeys, payload, payload_names,
                 jt, prepared)
@@ -1976,9 +2003,12 @@ class _Executor:
                                            prepared))
         limit = self.SKEW_MATCH_LIMIT
         if maxk <= limit:
+            k = bucket_capacity(max(maxk, 1), minimum=1)
+            _note_payload_form(k * probe.capacity, build.capacity,
+                               len(payload))
             out = expand_join_jit(
                 probe, build, lkeys, rkeys, payload, payload_names, jt,
-                bucket_capacity(max(maxk, 1), minimum=1), prepared)
+                k, prepared)
             yield Batch(schema, out.columns, out.row_mask)
             return
         # skew fallback: chunk the build by within-key occurrence rank so
@@ -1990,6 +2020,8 @@ class _Executor:
             sub = Batch(build.schema, build.columns,
                         build.row_mask & (ranks >= c)
                         & (ranks < c + limit))
+            _note_payload_form(limit * probe.capacity, sub.capacity,
+                               len(payload))
             out = expand_join_jit(
                 probe, sub, lkeys, rkeys, payload, payload_names,
                 jt if c == 0 else "inner", limit, None)
@@ -2056,6 +2088,8 @@ class _Executor:
                     for c in range(0, maxk, limit)]
         has_survivor = None
         for sub, k, prep_c in subs:
+            _note_payload_form(k * probe.capacity, sub.capacity,
+                               len(payload))
             e = expand_join_jit(probe, sub, lkeys, rkeys, payload,
                                 payload_names, "inner", k, prep_c)
             gated = residual_fn(Batch(schema, e.columns, e.row_mask))
@@ -2137,6 +2171,9 @@ class _Executor:
         # key): `keyed`; over the m:n matches otherwise: `expand`
         form = (None if node.residual is None
                 else "keyed" if node.filtering_unique else "expand")
+        # the filtering side's columns the residual reads
+        res_payload = (() if form is None else _residual_payload(
+            node.residual, len(node.source.fields))[0])
         summary = prep = packed = res_maxk = None
         # the filtering side of a residual semi join from its first
         # batch to the prepared layout, its launches and readbacks
@@ -2162,9 +2199,7 @@ class _Executor:
                                     node.distribution, form)
                 if form == "keyed":
                     packed = pack_sorted_payload_jit(
-                        build, _residual_payload(
-                            node.residual, len(node.source.fields))[0],
-                        prep, is_direct_prepared(prep))
+                        build, res_payload, prep, is_direct_prepared(prep))
                 elif form == "expand":
                     res_maxk = self._build_multiplicity(prep)
                 if span is not None:
@@ -2193,6 +2228,8 @@ class _Executor:
                     match_count_max_jit(b, build, skeys, fkeys, prep))
                 maxk = bucket_capacity(max(maxk, 1), minimum=1)
                 _SEMI_EXPANDED_LANES.inc(b.capacity * maxk)
+                _note_payload_form(b.capacity * maxk, build.capacity,
+                                   len(res_payload))
                 mask, err = _residual_program(form, node, b.schema, maxk)(
                     (b, build, prep))
             if form is not None and err is not None:

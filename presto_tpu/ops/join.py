@@ -443,6 +443,66 @@ def _tuple_eq(s_ops, q_ops, pos) -> jnp.ndarray:
     return eq
 
 
+def payload_form(probe_lanes: int, build_lanes: int, n_payload: int) -> str:
+    """How a probe program reads the payload columns of a build whose
+    prepared layout addresses it through a permutation, from the three
+    static sizes of the call: ``composed`` gathers the permutation at the
+    matched positions (ONE int32 gather a probe lane) and then each
+    column's data and validity from the build AS IT STANDS; ``permuted``
+    first gathers each column's data and validity through the whole
+    permutation (two gathers a BUILD lane a column) and reads the sorted
+    copies at the positions. Both read the same rows; the cheaper one is
+    the one with fewer gathers: ``composed`` where one more gather a
+    probe lane is fewer than two a build lane a column.
+
+    MEASURED (`tools/join_probe.py payload`, TPU v5 lite, PR 36; ms a
+    call, permuted / composed, BIGINT columns): TPC-H Q3's shape, 2^15
+    lanes against 2^21 x four columns, 233.58 / 3.35 (against 2^23:
+    927.70 / 3.62; one column: 50.68 / 1.03); 2^18 against 2^21 x four
+    263.66 / 28.09; 2^20 against 2^21 x four 387.52 / 121.19. A gather
+    costs 10 to 14 ns a lane in either form (`composed` 31 ns a probe
+    lane with one column, 100 to 116 with four), so the crossover IS
+    at equal gather counts: AT it the two are within 3 to 10 % (2^20
+    against 2^17 x four 154.59 / 150.09; 2^18 against 2^17 x one 10.61
+    / 9.54), a factor of four past it `permuted` leads by 8 % (2^20
+    against 2^17 x one 34.99 / 38.09), a factor of four before it
+    `composed` by 22 % (2^18 against 2^17 x four 48.49 / 37.59).
+
+    The ONE statement of the rule: ``read_payload`` (the traced helper)
+    and the executor's counter ``join_payload_selected_total.<form>``
+    both call it."""
+    if probe_lanes < 2 * n_payload * build_lanes:
+        return "composed"
+    return "permuted"
+
+
+def permuted_payload(build: Batch, payload: Sequence[int], perm
+                     ) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
+    """(data, validity) of each payload column in the prepared layout's
+    SORTED order: two gathers of the BUILD's lanes a column. The one
+    place a probe program permutes a build: ``read_payload``'s
+    ``permuted`` form, and the Pallas probe (``ops/pallas_join``), whose
+    kernel gathers inside VMEM from planes that have to be sorted."""
+    return [(jnp.take(build.columns[ci].data, perm, axis=0),
+             jnp.take(build.columns[ci].validity, perm, axis=0))
+            for ci in payload]
+
+
+def read_payload(build: Batch, payload: Sequence[int], perm, pos
+                 ) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
+    """(data, validity) of each payload column at the sorted positions
+    ``pos`` (any shape: ``[C]`` of a lookup, ``[k, C]`` of an
+    expansion), in the form ``payload_form`` picks from the static
+    shapes; the rows read are the same in both."""
+    if payload_form(pos.size, build.capacity, len(payload)) == "composed":
+        orig = jnp.take(perm, pos, axis=0)
+        return [(jnp.take(build.columns[ci].data, orig, axis=0),
+                 jnp.take(build.columns[ci].validity, orig, axis=0))
+                for ci in payload]
+    return [(jnp.take(sdata, pos, axis=0), jnp.take(svalid, pos, axis=0))
+            for sdata, svalid in permuted_payload(build, payload, perm)]
+
+
 def lookup_join(
     probe: Batch,
     build: Batch,
@@ -468,17 +528,11 @@ def lookup_join(
 
     out_fields = list(zip(probe.schema.names, probe.schema.types))
     out_cols: List[Column] = list(probe.columns)
-    for ci, name in zip(payload, payload_names):
+    for ci, name, (data, valid) in zip(
+            payload, payload_names, read_payload(build, payload, perm, pos)):
         c = build.columns[ci]
-        sdata = jnp.take(c.data, perm, axis=0)
-        svalid = jnp.take(c.validity, perm, axis=0)
         out_fields.append((name, c.type))
-        out_cols.append(Column(
-            c.type,
-            jnp.take(sdata, pos, axis=0),
-            jnp.take(svalid, pos, axis=0) & match,
-            c.dictionary,
-        ))
+        out_cols.append(Column(c.type, data, valid & match, c.dictionary))
     if join_type == "inner":
         mask = match
     else:
@@ -686,19 +740,22 @@ def expand_join(
 
     out_fields = list(zip(probe.schema.names, probe.schema.types))
     out_cols: List[Column] = []
+
+    def flat(x):
+        # [k, C, ...] -> [k * C, ...]: a long decimal keeps its limbs
+        return x.reshape((-1,) + x.shape[2:])
+
     for c in probe.columns:
-        data = jnp.broadcast_to(c.data[None, :], (k,) + c.data.shape)
+        data = jnp.broadcast_to(c.data[None], (k,) + c.data.shape)
         valid = jnp.broadcast_to(c.validity[None, :], (k,) + c.validity.shape)
-        out_cols.append(Column(c.type, data.reshape(-1), valid.reshape(-1),
+        out_cols.append(Column(c.type, flat(data), valid.reshape(-1),
                                c.dictionary))
-    for ci, name in zip(payload, payload_names):
+    for ci, name, (gdata, gvalid) in zip(
+            payload, payload_names, read_payload(build, payload, perm, pos)):
         c = build.columns[ci]
-        sdata = jnp.take(c.data, perm, axis=0)
-        svalid = jnp.take(c.validity, perm, axis=0)
-        gdata = jnp.take(sdata, pos, axis=0)           # [k, C]
-        gvalid = jnp.take(svalid, pos, axis=0) & matched
+        gvalid = gvalid & matched                      # [k, C]
         out_fields.append((name, c.type))
-        out_cols.append(Column(c.type, gdata.reshape(-1), gvalid.reshape(-1),
+        out_cols.append(Column(c.type, flat(gdata), gvalid.reshape(-1),
                                c.dictionary))
     if join_type == "inner":
         mask = matched

@@ -62,7 +62,7 @@ import jax.numpy as jnp
 
 from ..batch import Batch, Column, Schema
 from .join import _key_arrays, direct_slot_codes, is_direct_prepared, \
-    _split_prepared
+    _split_prepared, permuted_payload
 
 R, L = 8, 128            # probe tile: 8 sublanes x 128 lanes
 TILE = R * L
@@ -297,10 +297,8 @@ def lookup_join_direct(
     tags: List[Tuple[str, int]] = []
     planes: List[jnp.ndarray] = []
     vbits = jnp.zeros(slive.shape, dtype=jnp.int32)
-    for c_i, ci in enumerate(payload):
-        c = build.columns[ci]
-        sdata = jnp.take(c.data, perm, axis=0)
-        svalid = jnp.take(c.validity, perm, axis=0)
+    for c_i, (sdata, svalid) in enumerate(
+            permuted_payload(build, payload, perm)):
         tag, ps = _decompose(sdata)
         tags.append((tag, len(ps)))
         planes.extend(ps)

@@ -28,10 +28,13 @@ def _metric(name: str) -> float:
     return 0.0
 
 
+def _sorted_rows(rows):
+    return sorted(rows, key=lambda t: tuple((v is None, str(type(v)), v)
+                                            for v in t))
+
+
 def _rows(batch):
-    def key(t):
-        return tuple((v is None, str(type(v)), v) for v in t)
-    return sorted([tuple(r) for r in batch.to_pylist()], key=key)
+    return _sorted_rows([tuple(r) for r in batch.to_pylist()])
 
 
 def _with_nulls(b: Batch, col: int, null_rows) -> Batch:
@@ -305,6 +308,143 @@ def test_direct_tables_say_taken_in_lo_alone(layout):
 
 
 # ---------------------------------------------------------------------------
+# a build's payload, read where it stands or through sorted copies (PR 36)
+# ---------------------------------------------------------------------------
+
+#: the payload zoo's build has 1024 lanes and three payload columns:
+#: under 2 * 3 * 1024 positions a program reads it `composed`
+_PAYLOAD_BUILD_ROWS = 900
+_PAYLOAD_FORMS = {"narrow": ("composed", 120), "wide": ("permuted", 6500)}
+
+
+def _payload_case(kind, shape):
+    """(build, probe, live build rows by key, probe (key, id) rows, the
+    keys' bounds): a BIGINT key, then a BIGINT with NULL cells, a two-limb decimal and
+    a dictionary column as payload; NULL-key and dead build rows; probe
+    keys that miss, NULL probe keys. An expansion's keys stand up to
+    four times."""
+    import decimal
+    rng = np.random.default_rng(36)
+    n = _PAYLOAD_BUILD_ROWS
+    unique = kind != "expand"
+    null_keys, dead_rows = [5, 77, 400], [3, 78, 500, 899]
+    keys = (rng.permutation(4 * n)[:n] - n if unique
+            else rng.integers(-n // 8, n // 8, n))
+    vals = [None if i % 11 == 0 else int(v) for i, v in enumerate(
+        rng.integers(-2**52, 2**52, n))]
+    decs = [None if i % 13 == 0 else decimal.Decimal(int(v)) * 10**6
+            + decimal.Decimal(int(w)) / 100 for i, (v, w) in enumerate(zip(
+                rng.integers(-2**52, 2**52, n), rng.integers(0, 10**4, n)))]
+    strs = [None if i % 17 == 0 else f"s{int(v)}"
+            for i, v in enumerate(rng.integers(0, 9, n))]
+    build = _with_nulls(Batch.from_pydict({
+        "k": (T.BIGINT, keys.tolist()), "v": (T.BIGINT, vals),
+        "dec": (T.decimal(30, 2), decs), "s": (T.VARCHAR, strs)}),
+        0, null_keys)
+    live = np.ones(build.capacity, dtype=bool)
+    live[dead_rows] = False
+    build = Batch(build.schema, build.columns,
+                  build.row_mask & jnp.asarray(live))
+    assert build.capacity == 1024
+    by_key = {}
+    for i, k in enumerate(keys.tolist()):
+        if i not in null_keys + dead_rows:
+            by_key.setdefault(k, []).append((vals[i], decs[i], strs[i]))
+    if not unique:
+        by_key = {k: rows for k, rows in by_key.items() if len(rows) <= 4}
+        keep = np.array([k in by_key for k in keys.tolist()])
+        build = Batch(build.schema, build.columns,
+                      build.row_mask & jnp.asarray(
+                          np.pad(keep, (0, build.capacity - n))))
+    m = _PAYLOAD_FORMS[shape][1]
+    lo, hi = (-n, 3 * n) if unique else (-n // 8, n // 8)
+    # two probe keys in three are build keys (NULL-key and dead rows'
+    # among them), the rest drawn around the domain
+    pk = np.where(rng.random(m) < 0.66, rng.choice(keys, m),
+                  rng.integers(lo - 20, hi + 20, m)).tolist()
+    probe = _with_nulls(Batch.from_pydict({
+        "p": (T.BIGINT, pk), "x": (T.BIGINT, list(range(m)))}), 0, [1, 9])
+    prows = [(None if i in (1, 9) else k, i) for i, k in enumerate(pk)]
+    return build, probe, by_key, prows, (lo, hi - 1)
+
+
+def _payload_answer(by_key, prows, join_type):
+    out = []
+    for k, i in prows:
+        hits = by_key.get(k, []) if k is not None else []
+        out += [(k, i) + h for h in hits]
+        if not hits and join_type == "left":
+            out.append((k, i, None, None, None))
+    return out
+
+
+@pytest.mark.parametrize("shape", ["narrow", "wide"])
+@pytest.mark.parametrize("kind,join_type,layout", [
+    (kind, jt, layout) for kind in ("lookup", "expand")
+    for jt in ("inner", "left")
+    for layout in ("sorted", "direct", "keyed", "keyed_in_order")
+    # a build addressed as it stands holds every key once
+    if (kind, layout) != ("expand", "keyed_in_order")])
+def test_payload_is_read_the_same_in_both_forms(monkeypatch, kind,
+                                                join_type, layout, shape):
+    """ISSUE 36: the payload of a build is read `composed` (through the
+    permutation at the probe's lanes, from the build as it stands) or
+    `permuted` (through sorted copies made at the build's), by the
+    static shapes alone: a narrow probe against a wide build and the
+    reverse give the rows a plain Python join gives, through
+    ``lookup_join`` and ``expand_join`` (k = 4), over every layout."""
+    build, probe, by_key, prows, (lo, hi) = _payload_case(kind, shape)
+    want_form = _PAYLOAD_FORMS[shape][0]
+    if layout == "sorted":
+        prep = J.prepare_build(build, [0])
+        assert J.lookup_form(prep) == "sorted"
+    elif layout == "direct":
+        prep = J.prepare_direct(build, [0], lo, hi - lo + 1)
+    else:
+        los, sizes, K = J.direct_keyed_plan(((lo, hi),))
+        prep = J.prepare_direct_keyed(build, [0], los, sizes, K,
+                                      unique=layout == "keyed_in_order")
+    picked = []
+    rule = J.payload_form
+    monkeypatch.setattr(J, "payload_form", lambda *a: (
+        picked.append((a, rule(*a))) or picked[-1][1]))
+    payload, names = [1, 2, 3], ["v", "dec", "s"]
+    if kind == "lookup":
+        out = J.lookup_join(probe, build, [0], [0], payload, names,
+                            join_type, prepared=prep)
+        lanes = probe.capacity
+    else:
+        out = J.expand_join(probe, build, [0], [0], payload, names,
+                            join_type, 4, prepared=prep)
+        lanes = 4 * probe.capacity
+    assert picked == [((lanes, build.capacity, 3), want_form)]
+    want = _payload_answer(by_key, prows, join_type)
+    assert _rows(out) == _sorted_rows(want)
+    assert any(r[2] is None and r[3] is not None for r in want)  # NULL cell
+    assert len(prows) // 2 < len(want)
+    if join_type == "left":
+        assert sum(r[2:] == (None, None, None) for r in want) > 10  # misses
+
+
+@pytest.mark.parametrize("probe,build,columns,form", [
+    (1 << 15, 1 << 20, 4, "composed"),    # TPC-H Q3 against orders
+    (1 << 15, 1 << 21, 4, "composed"),
+    (1 << 20, 1 << 17, 4, "permuted"),    # equal gather counts: as before
+    (1 << 19, 1 << 17, 4, "composed"),
+    (1 << 18, 1 << 17, 1, "permuted"),
+    (1 << 17, 1 << 17, 1, "composed"),
+    (1 << 21, 1 << 9, 8, "permuted"),     # a fact batch against a dimension
+    (128, 128, 1, "composed"),
+    (128, 1 << 24, 0, "permuted"),        # nothing to read: nothing gathered
+])
+def test_payload_form_is_decided_by_gather_count(probe, build, columns, form):
+    """``composed`` costs one more gather a probe lane, ``permuted`` two
+    a build lane a column: the rule, stated once."""
+    assert J.payload_form(probe, build, columns) == form
+    assert (probe < 2 * columns * build) == (form == "composed")
+
+
+# ---------------------------------------------------------------------------
 # Pallas probe kernel parity (interpret mode on the CPU mesh)
 # ---------------------------------------------------------------------------
 
@@ -383,6 +523,88 @@ def _star_runner(sf, rows_per_batch):
     catalogs.register("tpch", TpchConnector(sf=sf))
     return LocalRunner(catalogs=catalogs, catalog="tpch",
                        rows_per_batch=rows_per_batch)
+
+
+def _tpch_oracle(connector, columns):
+    """SQLite over the named columns of the connector's tables (dates
+    as ISO text)."""
+    import datetime
+    import sqlite3
+    from presto_tpu.connectors.spi import TableHandle
+
+    def val(v):
+        v = v.item() if hasattr(v, "item") else v
+        return v.isoformat() if isinstance(v, datetime.date) else v
+    conn = sqlite3.connect(":memory:")
+    for t, cols in columns.items():
+        conn.execute(f"create table {t} ({', '.join(cols)})")
+        for split in connector.split_manager.splits(
+                TableHandle("tpch", "default", t), 1):
+            for b in connector.page_source(split, cols).batches():
+                conn.executemany(
+                    f"insert into {t} values ({', '.join('?' * len(cols))})",
+                    [tuple(val(v) for v in r) for r in b.to_pylist()])
+    return conn
+
+
+def _q3_text():
+    """The text the cell ``tpch_sf1_q3`` sends, one binding."""
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location("bench_q3", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks", "templates", "q3.py"))
+    q3 = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(q3)
+    return q3.SQL.format(SEGMENT="BUILDING", DATE="1995-03-15")
+
+
+@pytest.mark.parametrize("case", ["q3_narrowed_probe", "fused_star_chain"])
+def test_engine_reads_the_payload_composed_and_answers_as_sqlite(case):
+    """ISSUE 36 through the front door. TPC-H Q3 as the cell sends it,
+    in two lineitem batches of 2^18 lanes, which the join cuts to its
+    matches (2^13 lanes) before it reads the four columns of orders'
+    2^16-lane build; and a star chain whose fused tail probes
+    customer's 2^11 lanes x 3 columns with 2^13: both count `composed`
+    launches and answer as SQLite does."""
+    if case == "q3_narrowed_probe":
+        sql = _q3_text()
+        r = _star_runner(0.05, 1 << 18)
+        columns = {
+            "lineitem": ["l_orderkey", "l_extendedprice", "l_discount",
+                         "l_shipdate"],
+            "orders": ["o_orderkey", "o_custkey", "o_orderdate",
+                       "o_shippriority"],
+            "customer": ["c_custkey", "c_mktsegment"]}
+        moved = "compact_applied_total"
+    else:
+        sql = ("select o_orderkey, c_name, n_name from orders "
+               "join customer on o_custkey = c_custkey "
+               "join nation on c_nationkey = n_nationkey "
+               "where o_totalprice > 300000 order by o_orderkey")
+        r = _star_runner(0.01, 1 << 14)
+        columns = {"orders": ["o_orderkey", "o_custkey", "o_totalprice"],
+                   "customer": ["c_custkey", "c_name", "c_nationkey"],
+                   "nation": ["n_nationkey", "n_name"]}
+        moved = "fused_tail_lanes_total"
+    oracle = _tpch_oracle(r.session.catalogs.get("tpch"), columns)
+    names = ("join_payload_selected_total.composed",
+             "join_payload_selected_total.permuted", moved)
+    before = [_metric(n) for n in names]
+    got = [tuple(v.item() if hasattr(v, "item") else v for v in row)
+           for row in r.execute(sql).rows]
+    composed, permuted, other = (
+        _metric(n) - b for n, b in zip(names, before))
+    assert composed >= 1 and other >= 1
+    # the other join of each plan reads a build of a few hundred lanes
+    # with at least four times as many: as before
+    assert permuted >= 1
+    want = oracle.execute(sql.replace("date '", "'")).fetchall()
+    assert len(got) == len(want) > 0
+    for g, w in zip(got, want):
+        g = tuple(v.isoformat() if hasattr(v, "isoformat") else v
+                  for v in g)
+        assert g == pytest.approx(w, rel=1e-9)
 
 
 def test_pallas_engine_parity(force_pallas):

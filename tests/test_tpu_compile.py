@@ -230,6 +230,66 @@ def test_membership_probe_compiles_for_v5e_with_one_gather_or_none(
     assert c.memory_analysis().temp_size_in_bytes <= 16 * lanes
 
 
+@pytest.mark.parametrize("form,lanes,build_lanes", [
+    ("composed", 1 << 15, 1 << 21), ("permuted", N_BATCH, N_SLOTS)])
+def test_lookup_join_reads_the_payload_for_v5e_by_its_shapes(
+        one_chip, record_property, form, lanes, build_lanes):
+    """``jit_op_lookup_join`` as TPC-H Q3 runs it (ISSUE 36): 2^15
+    lineitem lanes (what the join's cut leaves of a batch) against a
+    `direct_keyed` build of orders at 2^21 lanes, its four columns the
+    payload, compile to NO gather whose result has the build's lanes
+    and exactly ten of the probe's: the lookup, the permutation, and
+    four columns' data and validity (twelve in the program: a 64-bit
+    column is read as two words). The reverse shapes (a 2^20-lane
+    batch against 2^17 lanes: equal gather counts) keep the build-size
+    permutation of every column: eight gathers of the build's lanes,
+    nine of the probe's (ten and eleven in the program)."""
+    import datetime
+    import re
+    import time
+    from presto_tpu import types as T
+    from presto_tpu.batch import Batch
+    from presto_tpu.ops import join as J
+    from presto_tpu.ops.jitcache import _lookup
+    day = datetime.date(1995, 3, 15)
+    orders = Batch.from_pydict({
+        "o_orderkey": (T.BIGINT, [3, 1, 2]), "o_custkey": (T.BIGINT, [7, 8, 9]),
+        "o_orderdate": (T.DATE, [day] * 3),
+        "o_shippriority": (T.INTEGER, [0, 0, 0])})
+    lines = Batch.from_pydict({
+        "l_orderkey": (T.BIGINT, [1, 2, 5]),
+        "l_extendedprice": (T.DOUBLE, [1.0, 2.0, 3.0]),
+        "l_discount": (T.DOUBLE, [0.1, 0.0, 0.2])})
+    prep = _described_prepared(
+        J.prepare_direct_keyed(orders, [0], (1,), (4,), 4),
+        1 << 23, build_lanes, one_chip)
+    payload = (0, 1, 2, 3)
+    assert J.payload_form(lanes, build_lanes, len(payload)) == form
+    t = time.perf_counter()
+    c = _lookup((0,), (0,), payload, ("$b0", "$b1", "$b2", "$b3"),
+                "inner").fn.lower(
+        _described(lines, lanes, one_chip),
+        _described(orders, build_lanes, one_chip), prep).compile()
+    record_property("compile_s", round(time.perf_counter() - t, 2))
+    text = c.as_text()
+    assert "jit_op_lookup_join" in text
+    got = re.findall(r"= (\w+)\[(\d+)[\],][^=]*?\sgather\(", text)
+    assert len(got) == len(re.findall(r"\sgather\(", text))
+    # the v5e's compiler reads a 64-bit word as two of 32 bits: the two
+    # BIGINT columns are four `u32` gathers, the DATE and the INTEGER
+    # one `s32` each, the four validities `pred`
+    columns = {"u32": 4, "s32": 2, "pred": 4}
+    want = {(dt, lanes): n for dt, n in columns.items()}
+    want["s32", lanes] += 1                         # the table's lookup
+    if form == "composed":
+        want["s32", lanes] += 1                     # the permutation
+    else:
+        want.update({(dt, build_lanes): n for dt, n in columns.items()})
+    assert {k: got.count((k[0], str(k[1]))) for k in want} == want
+    assert len(got) == sum(want.values())
+    assert not re.findall(r"\s(scatter|sort)\(", text)
+
+
 N_STATE = 1 << 24        # a summary of TPC-H SF10's 15M orders by key
 
 

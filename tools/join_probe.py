@@ -23,10 +23,25 @@ NumPy.
 `COMPARE_ALL_LIMIT` is the largest n at which `compare` is at least
 twice as fast as `gather1`; its comment quotes this table.
 
-    chiprun -- python3 tools/join_probe.py
+The second table (`payload`, PR 36) times what follows the lookup: the
+read of a build's payload columns at the matched positions
+(`ops/join.read_payload`), in its two forms, over probes of 2^15, 2^18
+and 2^20 lanes against builds of 2^17, 2^21 and 2^23 lanes, one and
+four BIGINT columns, every cell read checked against NumPy:
 
-The table is in PERF.md section 5 (PR 34). On a CPU the numbers are the
-CPU's and say nothing of the chip."""
+  permuted  each column's data and validity gathered through the whole
+            permutation (BUILD size), then read at the positions
+  composed  the permutation gathered at the positions (probe size), then
+            data and validity read from the build as it stands
+
+`ops/join.payload_form` picks between them by gather count; its comment
+quotes this table.
+
+    chiprun -- python3 tools/join_probe.py            # both tables
+    chiprun -- python3 tools/join_probe.py payload    # or `lookup`: one
+
+The tables are in PERF.md section 5 (PRs 34, 36). On a CPU the numbers
+are the CPU's and say nothing of the chip."""
 from __future__ import annotations
 
 import json
@@ -171,20 +186,84 @@ def table(lane_logs, slot_logs, ns):
     return rows
 
 
+def payload_table(lane_logs, build_logs, widths):
+    """ms a call and ns a probe lane of `ops/join.read_payload` in each
+    form, whatever `payload_form` would pick at the shape (`picked`)."""
+    rows = []
+    rng = np.random.default_rng(36)
+    rule = J.payload_form
+    for bl in build_logs:
+        n = 1 << bl
+        perm_h = rng.permutation(n).astype(np.int32)
+        perm = jnp.asarray(perm_h)
+        for width in widths:
+            data = rng.integers(-2**40, 2**40, (width, n))
+            valid = rng.random((width, n)) < 0.9
+            build = Batch(
+                Schema([Field(f"v{i}", T.BIGINT) for i in range(width)]),
+                [Column(T.BIGINT, jnp.asarray(d), jnp.asarray(v), None)
+                 for d, v in zip(data, valid)], jnp.ones(n, bool))
+            payload = tuple(range(width))
+            for ll in lane_logs:
+                pos_h = rng.integers(0, n, 1 << ll).astype(np.int32)
+                pos = jnp.asarray(pos_h)
+                at = perm_h[pos_h]
+                picked = rule(1 << ll, n, width)
+                for form in ("permuted", "composed"):
+                    # a function a form: jit's trace cache knows nothing
+                    # of the rule swapped under it
+                    def read(build, perm, pos):
+                        return J.read_payload(build, payload, perm, pos)
+
+                    J.payload_form = lambda *_: form    # read at the trace
+                    try:
+                        t = time.perf_counter()
+                        exe = jax.jit(read).lower(build, perm, pos).compile()
+                        compile_s = time.perf_counter() - t
+                    finally:
+                        J.payload_form = rule
+                    ms = launch_ms(lambda: exe(build, perm, pos),
+                                   SLOW_LAUNCHES if form == "permuted"
+                                   and bl > 21 else LAUNCHES)
+                    got = exe(build, perm, pos)
+                    equal = all(
+                        (np.asarray(d) == data[i][at]).all()
+                        and (np.asarray(v) == valid[i][at]).all()
+                        for i, (d, v) in enumerate(got))
+                    row = {"lanes": f"2^{ll}", "build": f"2^{bl}",
+                           "columns": width, "variant": form,
+                           "picked": picked, "device_ms": ms,
+                           "ns_a_lane": round(1e6 * ms / (1 << ll), 3),
+                           "compile_s": round(compile_s, 2),
+                           "equal": bool(equal)}
+                    rows.append(row)
+                    print(json.dumps(row), flush=True)
+            del build
+    return rows
+
+
 def main() -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     dev = jax.devices()[0]
     print(f"[device] {dev.platform} {dev.device_kind}", flush=True)
     small = dev.platform == "cpu"      # a rehearsal: the shapes cut
-    rows = (table((10, 11), (8, 10), (128, 256)) if small
-            else table((20, 21), (17, 24), (128, 512, 2048, 8192)))
+    which = sys.argv[1] if len(sys.argv) > 1 else "all"
     out = {"device": f"{dev.platform} {dev.device_kind}",
-           "limit": J.COMPARE_ALL_LIMIT, "lookups": rows}
+           "limit": J.COMPARE_ALL_LIMIT}
+    if which in ("all", "lookup"):
+        out["lookups"] = (
+            table((10, 11), (8, 10), (128, 256)) if small
+            else table((20, 21), (17, 24), (128, 512, 2048, 8192)))
+    if which in ("all", "payload"):
+        out["payloads"] = (
+            payload_table((8, 11), (9, 12), (1, 4)) if small
+            else payload_table((15, 18, 20), (17, 21, 23), (1, 4)))
+    rows = out.get("lookups", []) + out.get("payloads", [])
     os.makedirs(os.path.join(_REPO, "chiprun_out"), exist_ok=True)
     with open(os.path.join(_REPO, "chiprun_out", "join_probe.json"),
               "w") as f:
         json.dump(out, f, indent=1)
-    return 0 if all(r["equal"] for r in rows) else 1
+    return 0 if rows and all(r["equal"] for r in rows) else 1
 
 
 if __name__ == "__main__":
