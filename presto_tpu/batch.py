@@ -168,8 +168,7 @@ class Batch:
         # explicit device_get: an int() on a device scalar is an IMPLICIT
         # transfer, which jax.transfer_guard("disallow") rejects — sizing
         # syncs are deliberate and should read as such
-        with device_sync(what):
-            return int(jax.device_get(self.count()))
+        return int(device_sync(what, self.count()))
 
     def column(self, name: str) -> Column:
         return self.columns[self.schema.index_of(name)]
@@ -279,10 +278,9 @@ class Batch:
         """Decode live rows to python tuples (for tests / client results):
         the answer's fetch, one ``device-sync`` (``what="result"``) over
         every column's transfer."""
-        with device_sync("result"):
-            host = jax.device_get(
-                (self.row_mask,
-                 [(c.data, c.validity) for c in self.columns]))
+        host = device_sync(
+            "result", (self.row_mask,
+                       [(c.data, c.validity) for c in self.columns]))
         mask = np.asarray(host[0])
         out_cols = []
         for col, (data, valid) in zip(self.columns, host[1]):

@@ -26,7 +26,6 @@ globally-sharded batch with equal per-shard capacity.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import itertools
 import os
@@ -140,21 +139,21 @@ def _batch_row_bytes(batch: Batch) -> int:
     return sum(c.data.dtype.itemsize + 1 for c in batch.columns) + 1
 
 
-@contextlib.contextmanager
-def _sync_record(what: str, kind: str = "sync"):
-    """A ``device-sync`` trace span that ALSO records the host-blocking
-    interval as a flight round (the control_sync/staging/drain buckets
-    of the mesh attribution). The executable dispatched inside one of
-    these intervals must be built with ``flight_kind=None`` so its wall
+def _sync_record(what: str, launch, *args):
+    """The host copy of the control scalar(s) ``launch(*args)`` returns:
+    the program launched, then read through ``device_sync`` (its
+    ``device-sync`` span opens once the launch is made). The whole
+    interval, launch included, is ALSO one ``sync`` flight round (the
+    control_sync bucket of the mesh attribution), so the executable
+    dispatched here must be built with ``flight_kind=None``: its wall
     isn't counted twice."""
     fl = _flight.current_flight()
     t0 = time.perf_counter() if fl is not None else 0.0
     try:
-        with device_sync(what):
-            yield
+        return device_sync(what, launch(*args))
     finally:
         if fl is not None:
-            fl.record(kind, wall=time.perf_counter() - t0)
+            fl.record("sync", wall=time.perf_counter() - t0)
 
 
 def _drain_inputs(*values) -> None:
@@ -165,12 +164,13 @@ def _drain_inputs(*values) -> None:
     bracket that compute smears into ``control_sync`` exactly when the
     fused exchange shrinks the real control plane, and the bucket
     budgets gate on a lie. After the drain, the sync bracket times only
-    the control round trip itself."""
+    the control round trip itself. The ``input-drain`` span is all
+    ``wait_s`` (``device_sync`` with nothing to fetch)."""
     fl = _flight.current_flight()
     t0 = time.perf_counter() if fl is not None else 0.0
     try:
-        with device_sync("input-drain"):
-            jax.block_until_ready([v for v in values if v is not None])
+        device_sync("input-drain", [v for v in values if v is not None],
+                    fetch=False)
     finally:
         if fl is not None:
             fl.record("drain", wall=time.perf_counter() - t0)
@@ -456,8 +456,8 @@ class _Repartitioner:
                 partition_counts(b, _k, _bk), 1,
                 flight_kind=None, stage="exchange")
         _drain_inputs(batch)
-        with _sync_record("exchange-quota"):
-            raw = np.asarray(jax.device_get(self._counts_fn(batch)))
+        raw = np.asarray(_sync_record(
+            "exchange-quota", self._counts_fn, batch))
         return raw.reshape(self.ex.n, self.map.buckets)
 
     # -- fused control plane --------------------------------------------------
@@ -494,10 +494,8 @@ class _Repartitioner:
         self._rounds_since_observe = len(self._pending)
         total = np.zeros((self.ex.n, self.map.buckets), dtype=np.int64)
         _drain_inputs(*take)
-        with _sync_record("exchange-skew-check"):
-            for c in take:
-                total += np.asarray(jax.device_get(c)).reshape(
-                    self.ex.n, self.map.buckets)
+        for c in _sync_record("exchange-skew-check", lambda: take):
+            total += np.asarray(c).reshape(self.ex.n, self.map.buckets)
         self._last_counts = total
         self.map.observe(total)
 
@@ -715,8 +713,7 @@ class DistributedExecutor(_Executor):
             lambda b: jnp.sum(b.row_mask, keepdims=True).astype(jnp.int64), 1,
             flight_kind=None)
         _drain_inputs(batch)
-        with _sync_record("shard-live-max"):
-            counts = np.asarray(jax.device_get(per(batch)))
+        counts = np.asarray(_sync_record("shard-live-max", per, batch))
         return int(counts.max()) if counts.size else 0
 
     def _replicate_device(self, batch: Batch) -> Batch:
@@ -905,49 +902,52 @@ class DistributedExecutor(_Executor):
 
     def _stage_parts(self, parts, schema: Schema, cap: int,
                      datas, valids, masks, vocabs) -> None:
-        """Fetch every shard's columns to the host (explicit
-        device_get: staging deliberately rounds through the host to
-        stack per-shard chunks — one device-sync span brackets the whole round so the stall is observable)."""
+        """Fetch every shard's columns to the host: staging
+        deliberately rounds through the host to stack per-shard chunks —
+        ONE ``device_sync`` reads the whole round so the stall is
+        observable."""
         ncols = len(schema)
         fl = _flight.current_flight()
         t0 = time.perf_counter()
-        with device_sync("scan-stage"):
-            for p in parts:
-                if p is None:
-                    for ci in range(ncols):
-                        dt = schema.types[ci].storage_dtype
-                        datas[ci].append(np.zeros(cap, dtype=np.dtype(dt)))
-                        valids[ci].append(np.zeros(cap, dtype=bool))
-                    masks.append(np.zeros(cap, dtype=bool))
-                    continue
-                from ..batch import unify_dictionaries
-                for ci, c in enumerate(p.columns):
-                    d = np.asarray(jax.device_get(c.data))
-                    v = np.asarray(jax.device_get(c.validity))
-                    if c.dictionary is not None:
-                        if vocabs[ci] is None:
-                            vocabs[ci] = c.dictionary
-                        elif vocabs[ci] != c.dictionary:
-                            # remap codes into the accumulated vocabulary
-                            merged, remaps = unify_dictionaries([
-                                _host_col(c.type, vocabs[ci]),
-                                c])
-                            vocabs[ci] = merged
-                            # remap previously collected shards
-                            prev_map = remaps[0]
-                            datas[ci] = [
-                                _apply_remap(a, prev_map) for a in datas[ci]]
-                            d = _apply_remap(d, remaps[1])
-                    pad = cap - d.shape[0]
-                    if pad:
-                        d = np.pad(d, (0, pad))
-                        v = np.pad(v, (0, pad))
-                    datas[ci].append(d)
-                    valids[ci].append(v)
-                m = np.asarray(jax.device_get(p.row_mask))
-                if cap - m.shape[0]:
-                    m = np.pad(m, (0, cap - m.shape[0]))
-                masks.append(m)
+        from ..batch import unify_dictionaries
+        fetched = iter(device_sync("scan-stage", [
+            ([(c.data, c.validity) for c in p.columns], p.row_mask)
+            for p in parts if p is not None]))
+        for p in parts:
+            if p is None:
+                for ci in range(ncols):
+                    dt = schema.types[ci].storage_dtype
+                    datas[ci].append(np.zeros(cap, dtype=np.dtype(dt)))
+                    valids[ci].append(np.zeros(cap, dtype=bool))
+                masks.append(np.zeros(cap, dtype=bool))
+                continue
+            cols, m = next(fetched)
+            for ci, (c, (d, v)) in enumerate(zip(p.columns, cols)):
+                d, v = np.asarray(d), np.asarray(v)
+                if c.dictionary is not None:
+                    if vocabs[ci] is None:
+                        vocabs[ci] = c.dictionary
+                    elif vocabs[ci] != c.dictionary:
+                        # remap codes into the accumulated vocabulary
+                        merged, remaps = unify_dictionaries([
+                            _host_col(c.type, vocabs[ci]),
+                            c])
+                        vocabs[ci] = merged
+                        # remap previously collected shards
+                        prev_map = remaps[0]
+                        datas[ci] = [
+                            _apply_remap(a, prev_map) for a in datas[ci]]
+                        d = _apply_remap(d, remaps[1])
+                pad = cap - d.shape[0]
+                if pad:
+                    d = np.pad(d, (0, pad))
+                    v = np.pad(v, (0, pad))
+                datas[ci].append(d)
+                valids[ci].append(v)
+            m = np.asarray(m)
+            if cap - m.shape[0]:
+                m = np.pad(m, (0, cap - m.shape[0]))
+            masks.append(m)
         if fl is not None:
             loads = [int(m.sum()) for m in masks]
             nbytes = (sum(a.nbytes for lst in datas for a in lst)
@@ -1423,9 +1423,8 @@ class DistributedExecutor(_Executor):
                 1, replicated_in=(0,) if replicated else (),
                 flight_kind=None, stage="join")
             _drain_inputs(prepared)
-            with _sync_record("join-multiplicity"):
-                bound = int(np.asarray(
-                    jax.device_get(mult_fn(prepared))).max())
+            bound = int(np.asarray(_sync_record(
+                "join-multiplicity", mult_fn, prepared)).max())
             if bound <= self.SKEW_MATCH_LIMIT:
                 # the bound survives re-assignment: a key's rows move
                 # between shards ATOMICALLY (bucket granularity), so a
@@ -1514,12 +1513,11 @@ class DistributedExecutor(_Executor):
                 maxk = maxk_static
             elif count_fn is not None:
                 _drain_inputs(probe, build_side, prepared)
-                with _sync_record("join-match-count"):
-                    maxk = bucket_capacity(
-                        max(int(np.asarray(jax.device_get(
-                            count_fn(probe, build_side,
-                                     prepared))).max()), 1),
-                        minimum=1)
+                maxk = bucket_capacity(
+                    max(int(np.asarray(_sync_record(
+                        "join-match-count", count_fn, probe,
+                        build_side, prepared)).max()), 1),
+                    minimum=1)
             fn = join_fns.get(maxk)
             if fn is None:
                 if residual_outer:
@@ -1691,9 +1689,8 @@ class DistributedExecutor(_Executor):
                 build_sorted(f, fkeys))[None].astype(jnp.int64), 1,
             replicated_in=(0,), flight_kind=None, stage="semi")
         _drain_inputs(build_rep)
-        with _sync_record("semi-multiplicity"):
-            bound = int(np.asarray(
-                jax.device_get(mult_fn(build_rep))).max())
+        bound = int(np.asarray(_sync_record(
+            "semi-multiplicity", mult_fn, build_rep)).max())
         res_maxk = (bucket_capacity(max(bound, 1), minimum=1)
                     if bound <= self.SKEW_MATCH_LIMIT else None)
         count_fn = (None if res_maxk is not None else self._smap(
@@ -1705,11 +1702,11 @@ class DistributedExecutor(_Executor):
                 maxk = res_maxk
             else:
                 _drain_inputs(b, build_rep)
-                with _sync_record("semi-match-count"):
-                    maxk = bucket_capacity(
-                        max(int(np.asarray(jax.device_get(
-                            count_fn(b, build_rep))).max()), 1),
-                        minimum=1)
+                maxk = bucket_capacity(
+                    max(int(np.asarray(_sync_record(
+                        "semi-match-count", count_fn, b,
+                        build_rep)).max()), 1),
+                    minimum=1)
             fn = fns.get(maxk)
             if fn is None:
                 def local_mark(p: Batch, f: Batch, _k=maxk) -> Batch:

@@ -543,8 +543,8 @@ class _Executor:
         import numpy as np
 
         from ..errors import QueryError
-        with device_sync("error-flags"):
-            codes = np.asarray(jnp.stack(self.error_flags))
+        codes = np.asarray(device_sync(
+            "error-flags", jnp.stack(self.error_flags)))
         self.error_flags = []
         code = int(codes.max())
         if code:
@@ -1409,8 +1409,9 @@ class _Executor:
         def drain_pend() -> List[Batch]:
             if not pend:
                 return []
-            with device_sync("fused-liveness", batches=len(pend)):
-                counts = np.asarray(jnp.stack([c for _, c in pend]))
+            counts = np.asarray(device_sync(
+                "fused-liveness", jnp.stack([c for _, c in pend]),
+                batches=len(pend)))
             outs, shrunk = [], False
             for (b, _), live in zip(pend, counts):
                 tgt = bucket_capacity(max(int(live), 1))
@@ -1873,9 +1874,9 @@ class _Executor:
         from ..ops.jitcache import build_summary_jit
         int_flags = tuple(isinstance(build.columns[k].type, _DYN_TYPES)
                           for k in keys)
-        with device_sync("build-summary"):
-            return np.asarray(
-                build_summary_jit(build, tuple(keys), int_flags))
+        return np.asarray(device_sync(
+            "build-summary",
+            build_summary_jit(build, tuple(keys), int_flags)))
 
     @staticmethod
     def _summary_bounds(summary, out_keys):
@@ -1975,8 +1976,8 @@ class _Executor:
         the chunked skew path (most probe batches never touch the hot
         key), so those fall back to the per-batch match_count_max sync."""
         from ..ops.jitcache import max_multiplicity_jit
-        with device_sync("build-multiplicity"):
-            m = int(max_multiplicity_jit(prepared))
+        m = int(device_sync("build-multiplicity",
+                            max_multiplicity_jit(prepared)))
         return m if m <= self.SKEW_MATCH_LIMIT else None
 
     def _probe_batches(self, node: JoinNode, probe: Batch, build: Batch,

@@ -41,7 +41,7 @@ from ..batch import Batch, Column, Schema
 from ..obs import profiler as _prof
 from ..obs.log import LOG
 from ..obs.metrics import REGISTRY
-from ..obs.trace import TRACER
+from ..obs.trace import TRACER, device_drained, note_launch
 from . import functions as F
 from . import array_functions  # noqa: F401  (registers array fns)
 from .functions import Val, cast_val, merge_err
@@ -460,8 +460,9 @@ class _ExprProgram:
         _EXPR_INVOCATIONS.inc()
         if TRACER.enabled:
             with TRACER.span("dispatch", program=self.program,
-                             expr=self.text[:200]):
-                return self._launch(batch)
+                             expr=self.text[:200],
+                             starved=device_drained()):
+                return note_launch(self._launch(batch))
         return self._launch(batch)
 
     def _launch(self, batch: Batch):

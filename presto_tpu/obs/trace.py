@@ -26,7 +26,6 @@ threads keyed by task/query).
 """
 from __future__ import annotations
 
-import contextlib
 import contextvars
 import itertools
 import json
@@ -36,6 +35,8 @@ import time
 import uuid
 from collections import deque
 from typing import Dict, Iterator, List, Optional
+
+import jax
 
 from .metrics import REGISTRY
 
@@ -54,19 +55,12 @@ def _now() -> float:
     return _EPOCH_WALL + (time.perf_counter() - _EPOCH_PERF)
 
 
-_TRACE_ANNOTATION = None
-
-
 def _annotation(name: str, attrs: Dict):
     """The profiler-side twin of a span: a ``TraceAnnotation`` named
     like it, its attributes as arguments. Only ever built while the
-    tracer is on (so this module imports no JAX until then)."""
-    global _TRACE_ANNOTATION
-    if _TRACE_ANNOTATION is None:
-        from jax.profiler import TraceAnnotation
-        _TRACE_ANNOTATION = TraceAnnotation
+    tracer is on."""
     # a TraceMe encodes its arguments as "#k=v,k=v#"
-    return _TRACE_ANNOTATION(
+    return jax.profiler.TraceAnnotation(
         name, **{k: str(v).replace(",", ";").replace("#", "")
                  for k, v in attrs.items()})
 
@@ -114,9 +108,9 @@ class Span:
     def annotate(self, **attrs) -> None:
         self.attrs.update(attrs)
 
-    def finish(self) -> None:
+    def finish(self, end: Optional[float] = None) -> None:
         if self.end is None:
-            self.end = _now()
+            self.end = _now() if end is None else end
             self._tracer._record(self)
 
     def to_dict(self) -> Dict:
@@ -169,7 +163,10 @@ class Tracer:
 
     # -- lifecycle -----------------------------------------------------------
     def enable(self, flag: bool = True) -> None:
+        global _LAST_LAUNCH
         self.enabled = flag
+        if not flag:
+            _LAST_LAUNCH = None
 
     def clear(self) -> None:
         with self._lock:
@@ -289,23 +286,95 @@ class Tracer:
 #: the process-wide tracer
 TRACER = Tracer()
 
-_SYNCS = REGISTRY.counter("device_sync_total")
-_SYNC_SECONDS = REGISTRY.counter("device_sync_seconds_total")
+#: one leaf of the output of the engine's most recent launch, kept only
+#: while the tracer is on: what ``device_drained`` asks. Process-wide:
+#: one client and one executing thread; a prefetch thread's launch is a
+#: launch like any other.
+_LAST_LAUNCH = None
 
 
-@contextlib.contextmanager
-def device_sync(what: str, **attrs):
-    """Bracket one place where the host reads a device value: always
-    counted (``device_sync_total``, and the host seconds blocked in
-    ``device_sync_seconds_total``: one ``perf_counter`` pair), and a
-    ``device-sync`` span while the tracer is on."""
+def note_launch(out):
+    """Remember the launch that returned ``out`` as the engine's most
+    recent one (the two launch sites call this inside their ``dispatch``
+    span, so only while the tracer is on). Returns ``out``.
+
+    Kept is a second handle on the last leaf's first shard, not the
+    leaf: ``is_ready()`` of an array that a later launch was DONATED
+    ends the process, of such a handle it raises."""
+    global _LAST_LAUNCH
+    leaves = jax.tree_util.tree_leaves(out)
+    # an entry called while another program is traced launches nothing
+    if leaves and not isinstance(leaves[-1], jax.core.Tracer):
+        _LAST_LAUNCH = leaves[-1].addressable_data(0)
+    return out
+
+
+def device_drained() -> Optional[bool]:
+    """Has the device finished everything the engine launched? It runs
+    its queue in order, so the last launch's output being ready says
+    the queue is empty: one flag read on the host, no device work.
+    None where that is unknown: the tracer is off, nothing was launched
+    since it went on, or the output was donated or deleted. Readers
+    count unknown as "not drained"."""
+    leaf = _LAST_LAUNCH
+    if leaf is None or not TRACER.enabled:
+        return None
+    try:
+        return bool(leaf.is_ready())
+    except RuntimeError:
+        return None
+
+
+_SYNC_COUNTERS: Dict[str, tuple] = {}
+
+
+def _sync_counters(what: str) -> tuple:
+    pair = _SYNC_COUNTERS.get(what)
+    if pair is None:
+        pair = _SYNC_COUNTERS[what] = (
+            REGISTRY.counter(f"device_sync_total.{what}"),
+            REGISTRY.counter(f"device_sync_seconds_total.{what}"))
+    return pair
+
+
+def device_sync(what: str, value, fetch: bool = True, **attrs):
+    """The one place the host reads a device value: ``value`` (arrays,
+    any pytree; its producer already launched) fetched with
+    ``jax.device_get`` and returned. ``fetch=False`` only waits for it
+    (the mesh's ``input-drain``) and returns None. Always counted by
+    kind: ``device_sync_total.<what>`` and the host seconds blocked in
+    ``device_sync_seconds_total.<what>`` (one ``perf_counter`` pair).
+
+    While the tracer is on the read is a ``device-sync`` span that
+    first waits for the value and then fetches it, so the span splits:
+    ``wait_s``, the seconds from its start until the value was ready
+    (the device was working), and the rest, the fetch. ``drained`` says
+    whether at that instant the engine's last launch had finished too
+    (``device_drained``): True, the device stands idle from here until
+    the next launch lands; False, work was queued behind the value and
+    the readback cost the device nothing. The wait blocks nothing that
+    the fetch would not have blocked."""
+    count, seconds = _sync_counters(what)
     t0 = time.perf_counter()
     try:
-        with TRACER.span("device-sync", what=what, **attrs) as span:
-            yield span
+        span = TRACER.span("device-sync", what=what, **attrs)
+        if span is NOOP_SPAN:
+            if fetch:
+                return jax.device_get(value)
+            jax.block_until_ready(value)
+            return None
+        with span:
+            jax.block_until_ready(value)
+            ready = _now()
+            span.annotate(wait_s=ready - span.start,
+                          drained=device_drained())
+            if fetch:
+                return jax.device_get(value)
+            span.finish(ready)
+            return None
     finally:
-        _SYNCS.inc()
-        _SYNC_SECONDS.inc(time.perf_counter() - t0)
+        count.inc()
+        seconds.inc(time.perf_counter() - t0)
 
 
 def current_span_ids() -> Dict:

@@ -25,7 +25,7 @@ import jax
 from .._devtools import lockcheck as _lockcheck
 from ..obs import profiler as _prof
 from ..obs.metrics import REGISTRY
-from ..obs.trace import TRACER
+from ..obs.trace import TRACER, device_drained, note_launch
 from .aggregation import (
     AggSpec, finish_states, global_aggregate, grouped_aggregate, merge_states)
 
@@ -201,8 +201,11 @@ class _TimedEntry:
             self.first = False
             self.record.capture_avals(self.fn, args)
         if TRACER.enabled:
-            with TRACER.span("dispatch", program=self.program):
-                return self._launch(args)
+            # starved: had the device nothing queued when this launch
+            # began? (obs/trace.device_drained; None where unknown)
+            with TRACER.span("dispatch", program=self.program,
+                             starved=device_drained()):
+                return note_launch(self._launch(args))
         return self._launch(args)
 
     def _launch(self, args):

@@ -123,6 +123,8 @@ def format_trace_summary(spans) -> str:
     device-sync work called out the way the reference's query stats
     separate blocked/compile time from operator wall."""
     agg = {}
+    syncs = {}      # what -> [count, wait s, fetch s, drains]
+    starved = 0
     for s in spans:
         name = s.get("name", "?")
         dur = (float(s.get("end", 0.0)) - float(s.get("start", 0.0)))
@@ -130,11 +132,32 @@ def format_trace_summary(spans) -> str:
         st[0] += 1
         st[1] += dur
         st[2] = max(st[2], dur)
+        attrs = s.get("attrs") or {}
+        if name == "device-sync" and "wait_s" in attrs:
+            k = syncs.setdefault(attrs.get("what", "?"), [0, 0.0, 0.0, 0])
+            k[0] += 1
+            k[1] += attrs["wait_s"]
+            k[2] += max(dur - attrs["wait_s"], 0.0)
+            k[3] += attrs.get("drained") is True
+        elif name == "dispatch":
+            starved += attrs.get("starved") is True
     lines = ["Trace (spans by name):"]
     for name in sorted(agg, key=lambda n: -agg[n][1]):
         n, total, peak = agg[name]
         lines.append(f"  {name:<32} x{n:<5} total "
                      f"{total * 1e3:,.1f}ms, max {peak * 1e3:,.1f}ms")
+        if name == "device-sync":
+            # the wait (the device was working) apart from the fetch,
+            # and the reads that left the device with nothing queued
+            for what in sorted(syncs, key=lambda w: -sum(syncs[w][1:3])):
+                k, wait, fetch, drains = syncs[what]
+                lines.append(f"    {what:<30} x{k:<5} wait "
+                             f"{wait * 1e3:,.1f}ms, fetch "
+                             f"{fetch * 1e3:,.1f}ms, {drains} drained "
+                             f"the device")
+        elif name == "dispatch":
+            lines.append(f"    {n} launches, {starved} found the device "
+                         f"drained")
     return "\n".join(lines)
 
 

@@ -22,8 +22,10 @@ until the shapes change:
   their value into the executable, so "random" is constant per shape
   bucket and replays differ from first runs.
 - ``unbracketed-sync`` — ``jax.device_get`` / ``.block_until_ready``
-  outside a ``device_sync(...)`` (``obs/trace.py``: the ``device-sync``
-  span and its counters) or profiler scope.
+  in engine code: a read goes through ``obs/trace.device_sync(what,
+  value)``, which makes both calls itself inside the ``device-sync``
+  span and its counters; a ``TRACER.span("device-sync")`` or profiler
+  scope is accepted too.
   Async dispatch makes an unbracketed sync a stall nobody can see in
   the trace viewer; the engine's rule since PR 1 is that every
   deliberate device round-trip is a span.
@@ -333,13 +335,10 @@ def _inside_timed_entry(node: ast.AST) -> bool:
 
 
 def _inside_sync_span(node: ast.AST) -> bool:
-    """Lexically under ``with device_sync(...)`` (or the span it opens,
-    ``TRACER.span("device-sync", ...)``), under exec/distributed's
-    ``_sync_record(...)`` (a wrapper that opens that exact span AND
-    feeds the mesh flight recorder's control_sync bucket — the
-    bracketing contract holds by construction), or any ``with`` whose
-    context manager comes from the profiler (obs.profiler brackets its
-    own syncs)."""
+    """Lexically under ``with TRACER.span("device-sync", ...)`` (the
+    span ``obs/trace.device_sync`` opens around its own two calls), or
+    any ``with`` whose context manager comes from the profiler
+    (obs.profiler brackets its own syncs)."""
     for anc in ancestors(node):
         if not isinstance(anc, ast.With):
             continue
@@ -348,8 +347,6 @@ def _inside_sync_span(node: ast.AST) -> bool:
             if not isinstance(ctx, ast.Call):
                 continue
             name = dotted(ctx.func) or ""
-            if name.split(".")[-1] in ("_sync_record", "device_sync"):
-                return True
             if name.endswith(".span") and ctx.args:
                 s = str_const(ctx.args[0])
                 if s and s.startswith("device-sync"):
