@@ -27,6 +27,7 @@ import os
 import tempfile
 from typing import Iterable, Iterator, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..batch import (
@@ -35,7 +36,7 @@ from ..batch import (
 )
 from ..memory import QueryMemoryPool, batch_device_bytes
 from ..obs.metrics import REGISTRY
-from ..obs.trace import TRACER
+from ..obs.trace import TRACER, device_sync
 from ..ops.aggregation import AggSpec
 from ..ops.jitcache import (
     compact_jit, finish_states_jit, grouped_aggregate_jit as grouped_aggregate,
@@ -378,6 +379,12 @@ _AGG_PARTIALS = REGISTRY.counter("agg_partials_total")
 _AGG_MERGES = REGISTRY.counter("agg_state_merges_total")
 _AGG_LANES_MERGED = REGISTRY.counter("agg_state_lanes_merged_total")
 _AGG_GROUPS = REGISTRY.counter("agg_state_groups_total")
+#: network merges by what the program did, one count a merged state
+#: whose live count is read (``_cut``): ``append``, one state ended
+#: before the other began; ``network``, the merge network ran
+_AGG_MERGE_SELECTED = {
+    how: REGISTRY.counter(f"agg_merge_selected_total.{how}")
+    for how in ("append", "network")}
 
 
 @dataclasses.dataclass
@@ -392,6 +399,9 @@ class _State:
     normalized: bool
     #: the live groups, where they have been read back
     groups: Optional[int] = None
+    #: ``merge_states``' flag (a device int32) until ``_cut`` reads it
+    #: with the live count
+    appended: Optional[jax.Array] = None
 
 
 class AggSpillBuffer:
@@ -410,9 +420,12 @@ class AggSpillBuffer:
     whatever the number of its batches, and no state is sorted again
     whole for every sixteen partials that arrive. Two normalized states
     of integer keys merge in ``ops.aggregation.merge_states`` (no sort,
-    no gather); any other pair in ``grouped_aggregate`` over their
-    concatenation, inside the program. A live count is read a few states
-    late (``LATE``), when the device has long computed it."""
+    no gather; where one ends before the other begins, as the states
+    over an input clustered by the keys do, the program appends it and
+    runs no network: ``agg_merge_selected_total``); any other pair in
+    ``grouped_aggregate`` over their concatenation, inside the program.
+    A live count is read a few states late (``LATE``), when the device
+    has long computed it."""
 
     #: states whose live count is not read yet: the oldest is read when
     #: one more arrives, so the host stays this far ahead of the device
@@ -523,7 +536,13 @@ class AggSpillBuffer:
             st = _State(b, True, not dense)
         groups = None
         if b.capacity > self.CUT_FLOOR:
-            groups = b.host_count("agg-state-groups")
+            if st.appended is None:
+                groups = b.host_count("agg-state-groups")
+            else:
+                groups, appended = (int(v) for v in device_sync(
+                    "agg-state-groups", (b.count(), st.appended)))
+                _AGG_MERGE_SELECTED[
+                    "append" if appended else "network"].inc()
             cap = bucket_capacity(max(groups, 1), minimum=self.CUT_FLOOR)
             if cap < b.capacity:
                 b = (prefix_jit(b, cap) if st.normalized
@@ -561,8 +580,9 @@ class AggSpillBuffer:
         with TRACER.span("agg-merge", lanes_in=lanes, groups_out=-1,
                          mode="network" if network else "sort"):
             if network:
-                return _State(merge_states_jit(a.batch, b.batch, n_keys,
-                                               self.aggs), True, True)
+                merged, appended = merge_states_jit(a.batch, b.batch, n_keys,
+                                                    self.aggs)
+                return _State(merged, True, True, appended=appended)
             dense = self._dense(a.batch, lanes)
             self._flag_bounds(a.batch, dense)
             self._flag_bounds(b.batch, dense)

@@ -1213,8 +1213,11 @@ def _assemble(batch: Batch, group_indices: Sequence[int],
 # every key once. Two such states merge in a bitonic merge network
 # (log2 of the lanes elementwise passes, the state columns riding along)
 # where ``lax.sort`` would sort them as if they were in no order and a
-# gather a column would follow (~11 ns a lane a column on the v5e); and
-# any state of unique keys finishes lane by lane.
+# gather a column would follow (~11 ns a lane a column on the v5e); two
+# whose key ranges do not overlap are their concatenation, and skip the
+# network too (2^23 lanes a side, three columns, on the v5e: 9 ms where
+# the network takes 239, ``tools/merge_probe.py``); and any state of
+# unique keys finishes lane by lane.
 
 def merge_network_ok(batch: Batch, n_keys: int,
                      aggs: Sequence[AggSpec]) -> bool:
@@ -1317,41 +1320,87 @@ class _RowReducers:
     """Every row a group of its own (one state of unique keys)."""
 
     count = staticmethod(lambda valid: valid.astype(jnp.int64))
-    sum = min = max = gather = staticmethod(lambda x: x)
+    sum = min = max = gather = front = staticmethod(lambda x: x)
 
 
 def merge_states(a: Batch, b: Batch, n_keys: int,
-                 aggs: Sequence[AggSpec]) -> Batch:
+                 aggs: Sequence[AggSpec]) -> Tuple[Batch, jnp.ndarray]:
     """Two NORMALIZED states of one layout and capacity (and equal
     dictionaries: ``merge_network_ok`` and the caller see to that) as
     one, normalized, of twice the capacity: ``grouped_aggregate(...,
     mode="merge")`` over their concatenation, without its sort and its
-    gathers."""
+    gathers. Beside the state, an int32 that says how: 1, one state
+    ended before the other began (the partials of an input clustered by
+    the keys) and the later stands behind the earlier, no lane compared
+    with another; 0, their ranges overlap or meet in one key and the
+    merge network ran. Seen on the device in the states' own first and
+    last keys; the rows are the same either way."""
     keys_idx = list(range(n_keys))
-    both = [jnp.concatenate([x, y]) for x, y in zip(
-        _group_key_ops(a, keys_idx) + [c.data for c in a.columns[n_keys:]],
-        _group_key_ops(b, keys_idx) + [c.data for c in b.columns[n_keys:]])]
-    s_keys, s_state = _bitonic_merge(both[:n_keys + 1], both[n_keys + 1:])
-    rank = s_keys[0]
-    s_mask = rank < _DEAD_RANK
-    boundary, _, _ = _boundary_groups(s_keys, s_mask)
-    red = _PairReducers(boundary, s_mask)
-    cap = rank.shape[0]
-    out_mask = jnp.arange(cap) < red.num_groups
-    front_rank = red.front(rank)
-    key_cols = []
-    for j, c in enumerate(a.columns[:n_keys]):
-        data = red.front(s_keys[1 + j])
-        if c.data.dtype == jnp.bool_:
-            data = data.astype(jnp.bool_)
-        valid = (front_rank & (1 << (n_keys - 1 - j))) == 0
-        key_cols.append(Column(c.type, data, valid & out_mask,
-                               c.dictionary))
-    n_state = len(s_state)
-    seg = _segment_aggs(
-        aggs, s_state, [s_mask] * n_state, s_mask, red, from_states=True,
-        col_dicts=[c.dictionary for c in a.columns[n_keys:]])
-    return _assemble(a, keys_idx, aggs, "merge", key_cols, seg, out_mask)
+    n_ops = n_keys + 1
+    cap = a.capacity
+    ops_a, ops_b = (
+        _group_key_ops(s, keys_idx) + [c.data for c in s.columns[n_keys:]]
+        for s in (a, b))
+    count_a, count_b = a.count(), b.count()
+
+    def ends(ops, count):
+        # the first and the last live key tuple; an empty side reads a
+        # dead lane's rank twice: it ends before nothing and everything
+        # ends before it
+        last = jnp.maximum(count - 1, 0)
+        return ([x[0] for x in ops[:n_ops]],
+                [jax.lax.dynamic_index_in_dim(x, last, keepdims=False)
+                 for x in ops[:n_ops]])
+    first_a, last_a = ends(ops_a, count_a)
+    first_b, last_b = ends(ops_b, count_b)
+    # strictly: a key that ends one state and begins the other is two
+    # rows of ONE group, which only the network combines
+    a_then_b = _lex_greater(first_b, last_a) | (count_b == 0)
+    b_then_a = _lex_greater(first_a, last_b)
+
+    def state(s_keys, s_state, s_mask, red, out_mask):
+        front_rank = red.front(s_keys[0])
+        key_cols = []
+        for j, c in enumerate(a.columns[:n_keys]):
+            data = red.front(s_keys[1 + j])
+            if c.data.dtype == jnp.bool_:
+                data = data.astype(jnp.bool_)
+            valid = (front_rank & (1 << (n_keys - 1 - j))) == 0
+            key_cols.append(Column(c.type, data, valid & out_mask,
+                                   c.dictionary))
+        seg = _segment_aggs(
+            aggs, s_state, [s_mask] * len(s_state), s_mask, red,
+            from_states=True,
+            col_dicts=[c.dictionary for c in a.columns[n_keys:]])
+        return _assemble(a, keys_idx, aggs, "merge", key_cols, seg, out_mask)
+
+    def network():
+        both = [jnp.concatenate([x, y]) for x, y in zip(ops_a, ops_b)]
+        s_keys, s_state = _bitonic_merge(both[:n_ops], both[n_ops:])
+        s_mask = s_keys[0] < _DEAD_RANK
+        boundary, _, _ = _boundary_groups(s_keys, s_mask)
+        red = _PairReducers(boundary, s_mask)
+        return state(s_keys, s_state, s_mask, red,
+                     jnp.arange(2 * cap) < red.num_groups)
+
+    def appended(earlier, later, count):
+        # the later state written at the lane where the earlier's live
+        # rows end: its own dead lanes and the padding stand behind
+        def behind(x, y, fill):
+            pad = jnp.full((cap,) + x.shape[1:], fill, x.dtype)
+            return jax.lax.dynamic_update_slice_in_dim(
+                jnp.concatenate([x, pad]), y, count, axis=0)
+        ops = [behind(x, y, _DEAD_RANK if i == 0 else 0)
+               for i, (x, y) in enumerate(zip(earlier, later))]
+        s_mask = ops[0] < _DEAD_RANK
+        return state(ops[:n_ops], ops[n_ops:], s_mask, _RowReducers, s_mask)
+
+    how = jnp.where(a_then_b, 1, jnp.where(b_then_a, 2, 0))
+    merged = jax.lax.switch(
+        how, [network,
+              lambda: appended(ops_a, ops_b, count_a),
+              lambda: appended(ops_b, ops_a, count_b)])
+    return merged, (how > 0).astype(jnp.int32)
 
 
 def finish_states(state: Batch, n_keys: int,

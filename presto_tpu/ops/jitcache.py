@@ -318,7 +318,9 @@ _merge_states = _entry_cache(
 
 def merge_states_jit(a, b, n_keys: int, aggs: Sequence[AggSpec]):
     """``ops.aggregation.merge_states``: two normalized states of one
-    capacity as one, without a sort or a gather."""
+    capacity as one, without a sort or a gather, and the device int32
+    that says whether one was appended to the other (1) or the network
+    ran (0)."""
     return _merge_states(n_keys, tuple(aggs))(a, b)
 
 

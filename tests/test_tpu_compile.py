@@ -157,8 +157,10 @@ def test_state_merge_compiles_for_v5e_without_a_sort_or_a_gather(one_chip):
     """``jit_op_grouped_aggregate_merge`` over TPC-H Q18's subquery
     state (an order key, a DOUBLE sum and its count) at 2^20 lanes a
     side: the merge network, the pair reducers and the compress network
-    are elementwise passes; the TPU compiler's program holds no sort,
-    no gather and no scatter."""
+    are elementwise passes, and the branch that appends one state to
+    the other where their key ranges are disjoint (ISSUE 38) is a
+    conditional around them, chosen on the device; the TPU compiler's
+    program holds no sort, no gather and no scatter."""
     import re
     from presto_tpu import types as T
     from presto_tpu.batch import Batch, Schema
@@ -177,6 +179,7 @@ def test_state_merge_compiles_for_v5e_without_a_sort_or_a_gather(one_chip):
     c = _merge_states(1, aggs).fn.lower(side, side).compile()
     text = c.as_text()
     assert "jit_op_grouped_aggregate_merge" in text
+    assert re.findall(r"\sconditional\(", text)
     assert not re.findall(r"\s(scatter|sort|gather)\(", text)
     assert c.memory_analysis() is not None
 
